@@ -37,7 +37,9 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from ..distributed.sharding import per_device
 from ..kernels import ref as kref
 from ..kernels import sophia_update as kblk
 
@@ -219,10 +221,6 @@ class EngineState(NamedTuple):
 # The engine
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 class OptimizerEngine:
     """One update path for reference and fused optimizers over flat shards.
 
@@ -271,9 +269,6 @@ class OptimizerEngine:
     def tracks_clip_fraction(self) -> bool:
         return self.family == "sophia"
 
-    def _interp(self) -> bool:
-        return _interpret_default() if self.interpret is None else self.interpret
-
     # -- layout -------------------------------------------------------------
 
     def layout(self, params: PyTree) -> ShardLayout:
@@ -294,12 +289,17 @@ class OptimizerEngine:
 
     def init(self, params: PyTree) -> EngineState:
         lay = self.layout(params)
-        zeros = tuple(jnp.zeros((s,), self.state_dtype)
-                      for s in lay.shard_sizes)
+
+        def zeros():
+            return tuple(jnp.zeros((s,), self.state_dtype)
+                         for s in lay.shard_sizes)
+
+        # m and h get buffers of their own: the train step donates the
+        # state, and a buffer donated twice is an error
         return EngineState(
             count=jnp.zeros((), jnp.int32),
-            m=zeros,
-            h=zeros if self.needs_curvature else (),
+            m=zeros(),
+            h=zeros() if self.needs_curvature else (),
             hess_count=jnp.zeros((), jnp.int32),
             clip_fraction=jnp.zeros((), jnp.float32),
         )
@@ -367,8 +367,19 @@ class OptimizerEngine:
         (p', m', h' or None, n_clipped or None)."""
         hp = self.hypers
         fused = self.backend == "pallas"
-        kw = dict(block=self.block, interpret=self._interp()) if fused else {}
         fam = self.family
+
+        def sweep(kernel, vecs, scalars, **args):
+            """A Pallas sweep, per device over its segment of the
+            FSDP-sharded flat shards under an activation mesh (XLA cannot
+            partition the kernel)."""
+            spec = P("batch")
+            fn = per_device(
+                lambda _, v, sc: kernel(*v, *sc, **args, block=self.block,
+                                        interpret=self.interpret),
+                (spec, P()), spec, vecs[0].shape[0] // self.block)
+            return fn(tuple(vecs), tuple(scalars))
+
         if fam == "sophia":
             args = dict(beta1=hp["beta1"], gamma=hp["gamma"], eps=hp["eps"],
                         weight_decay=hp["weight_decay"],
@@ -376,15 +387,16 @@ class OptimizerEngine:
             if e is not None:
                 args["beta2"] = hp["beta2"]
                 if fused:
-                    p2, m2, h2, nclip = kblk.sophia_refresh_fused_block(
-                        p, m, h, g, e, lr, flag, scale, **args, **kw)
+                    p2, m2, h2, nclip = sweep(
+                        kblk.sophia_refresh_fused_block, (p, m, h, g, e),
+                        (lr, flag, scale), **args)
                     return p2, m2, h2, jnp.sum(nclip)
                 p2, m2, h2, nclip = kref.sophia_step_refresh_ref(
                     p, m, h, g, e, lr=lr, flag=flag, scale=scale, **args)
                 return p2, m2, h2, nclip
             if fused:
-                p2, m2, nclip = kblk.sophia_fused_block(p, m, h, g, lr,
-                                                        **args, **kw)
+                p2, m2, nclip = sweep(kblk.sophia_fused_block,
+                                      (p, m, h, g), (lr,), **args)
                 return p2, m2, h, jnp.sum(nclip)
             p2, m2, nclip = kref.sophia_fused_ref(p, m, h, g, lr=lr, **args)
             return p2, m2, h, nclip
@@ -393,16 +405,17 @@ class OptimizerEngine:
                         weight_decay=hp["weight_decay"])
             if e is not None:
                 if fused:
-                    p2, m2, h2 = kblk.adahessian_refresh_fused_block(
-                        p, m, h, g, e, lr, flag, scale, c1, **args, **kw)
+                    p2, m2, h2 = sweep(
+                        kblk.adahessian_refresh_fused_block,
+                        (p, m, h, g, e), (lr, flag, scale, c1), **args)
                 else:
                     p2, m2, h2 = kref.adahessian_step_refresh_ref(
                         p, m, h, g, e, lr=lr, flag=flag, scale=scale,
                         step=c1, **args)
                 return p2, m2, h2, None
             if fused:
-                p2, m2 = kblk.adahessian_fused_block(p, m, h, g, lr, c1,
-                                                     **args, **kw)
+                p2, m2 = sweep(kblk.adahessian_fused_block, (p, m, h, g),
+                               (lr, c1), **args)
             else:
                 p2, m2 = kref.adahessian_fused_ref(p, m, h, g, lr=lr,
                                                    step=c1, **args)
@@ -411,8 +424,8 @@ class OptimizerEngine:
             args = dict(beta1=hp["beta1"], beta2=hp["beta2"], eps=hp["eps"],
                         weight_decay=hp["weight_decay"])
             if fused:
-                p2, m2, v2 = kblk.adamw_fused_block(p, m, h, g, lr, c1,
-                                                    **args, **kw)
+                p2, m2, v2 = sweep(kblk.adamw_fused_block, (p, m, h, g),
+                                   (lr, c1), **args)
             else:
                 p2, m2, v2 = kref.adamw_fused_ref(p, m, h, g, lr=lr, step=c1,
                                                   **args)
@@ -421,21 +434,24 @@ class OptimizerEngine:
             args = dict(beta1=hp["beta1"], beta2=hp["beta2"],
                         weight_decay=hp["weight_decay"])
             if fused:
-                p2, m2 = kblk.lion_fused_block(p, m, g, lr, **args, **kw)
+                p2, m2 = sweep(kblk.lion_fused_block, (p, m, g), (lr,),
+                               **args)
             else:
                 p2, m2 = kref.lion_fused_ref(p, m, g, lr=lr, **args)
             return p2, m2, None, None
         if fam == "signgd":
             args = dict(beta1=hp["beta1"], weight_decay=hp["weight_decay"])
             if fused:
-                p2, m2 = kblk.signgd_fused_block(p, m, g, lr, **args, **kw)
+                p2, m2 = sweep(kblk.signgd_fused_block, (p, m, g), (lr,),
+                               **args)
             else:
                 p2, m2 = kref.signgd_fused_ref(p, m, g, lr=lr, **args)
             return p2, m2, None, None
         if fam == "sgd":
             args = dict(momentum=hp.get("momentum", 0.0))
             if fused:
-                p2, m2 = kblk.sgd_fused_block(p, m, g, lr, **args, **kw)
+                p2, m2 = sweep(kblk.sgd_fused_block, (p, m, g), (lr,),
+                               **args)
             else:
                 p2, m2 = kref.sgd_fused_ref(p, m, g, lr=lr, **args)
             return p2, m2, None, None
@@ -509,7 +525,7 @@ class OptimizerEngine:
             if self.backend == "pallas":
                 new_h.append(kblk.hessian_ema_block(
                     h, e, beta2=beta2, scale=scale, square=square,
-                    block=self.block, interpret=self._interp()))
+                    block=self.block, interpret=self.interpret))
             else:
                 new_h.append(kref.hessian_ema_ref(h, e, beta2=beta2,
                                                   scale=scale, square=square))

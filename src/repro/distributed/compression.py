@@ -180,7 +180,6 @@ class GradCompressor:
             _, _, deq = _quantize(x, self.block, seed, offset=offset)
             return deq, x - deq
 
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         block, seg = self.block, n // ndev
@@ -206,8 +205,8 @@ class GradCompressor:
 
         spec = P(axes if len(axes) > 1 else axes[0])
         sd = jnp.uint32(0) if seed is None else seed  # placeholder operand
-        return shard_map(body, mesh=mesh, in_specs=(spec, spec, P()),
-                         out_specs=(P(), spec), check_rep=False)(g, e, sd)
+        return jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, P()),
+                             out_specs=(P(), spec), check_vma=False)(g, e, sd)
 
     # -- legacy params-pytree path (mesh-agnostic simulation) ----------------
 

@@ -21,10 +21,12 @@ Design:
 """
 from __future__ import annotations
 
+import functools
 import re
 from typing import Any, Optional, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
@@ -100,6 +102,41 @@ def constrain(x, *axes):
 
 def batch_axis(mesh: Mesh):
     return fsdp_axis(mesh)
+
+
+def per_device(fn, in_specs, out_specs, batch: int):
+    """Run ``fn`` per device under ``jax.shard_map`` over the installed
+    activation mesh; ``fn`` itself when none (or a one-device mesh) is.
+
+    XLA cannot partition a Pallas TPU (Mosaic) kernel, so a kernel entry
+    point called under a mesh maps itself over the batch.  The specs are
+    PartitionSpecs naming the logical axis ``"batch"``, which resolves to
+    the mesh's batch axis when ``batch`` divides its size and to
+    replication otherwise (every device then computes the whole call).
+    ``fn`` receives the index of its batch shard as a leading argument (0
+    when replicated)."""
+    mesh = _ACT_CTX["mesh"]
+    if mesh is None or mesh.size == 1:
+        return functools.partial(fn, 0)
+    ax = batch_axis(mesh)
+    axes = ax if isinstance(ax, tuple) else (ax,)
+    n = int(np.prod([mesh.shape[a] for a in axes]))
+    phys = ax if batch % n == 0 else None
+
+    def resolve(tree):
+        return jax.tree.map(
+            lambda sp: P(*[phys if a == "batch" else a for a in sp]), tree,
+            is_leaf=lambda x: isinstance(x, P))
+
+    def body(*args):
+        idx = jnp.int32(0)
+        if phys is not None:
+            for a in axes:
+                idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
+        return fn(idx, *args)
+
+    return jax.shard_map(body, mesh=mesh, in_specs=resolve(in_specs),
+                         out_specs=resolve(out_specs), check_vma=False)
 
 
 # ---------------------------------------------------------------------------

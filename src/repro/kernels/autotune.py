@@ -16,9 +16,9 @@ This module resolves it in three stages:
    forbid.  Each tiling carries its legal schedules ("fused" iff one grid
    axis is 1).
 2. **predict** — the analytic cost model (``predict_seconds``): per-pass
-   ``max(flops/PEAK_FLOPS, bytes/HBM_BW)`` on the roofline constants from
-   ``launch/roofline.py`` for a real backend; for interpret mode a
-   CPU model ``flops/CPU_FLOPS + cells * CELL_OVERHEAD_S`` (the
+   ``max(flops/peak_flops, bytes/hbm_bw)`` with the peaks of the chip the
+   process runs on (``launch/roofline.device_peaks``) for a real backend;
+   for interpret mode a CPU model ``flops/CPU_FLOPS + cells * CELL_OVERHEAD_S`` (the
    interpreter unrolls the grid, so cell count — not bandwidth — is the
    first-order term).  Candidates are ranked by predicted time.
 3. **measure (optional refinement)** — ``measure=True`` times
@@ -29,7 +29,7 @@ This module resolves it in three stages:
 
 The cache is keyed on ``(N, D, Vp, dtype, transpose_w, softcap?, norm,
 backend)`` and lives at ``$REPRO_FUSED_CE_CACHE`` (default
-``~/.cache/repro/fused_ce_autotune.json``), written atomically.  Lookup
+``<checkout>/.autotune/fused_ce_autotune.json``), written atomically.  Lookup
 (``get_tuned``) is pure host-side Python on static shapes — safe to call
 at trace time from inside ``jit``; measurement only ever runs eagerly
 (benchmarks, ``launch/train.py --retune``).
@@ -43,6 +43,8 @@ import threading
 import time
 
 import numpy as np
+
+from .. import CHECKOUT_DIR
 
 CACHE_VERSION = 1
 MEASURE_TOP_K = 4
@@ -79,11 +81,12 @@ class TunedCE:
     measured_ms: float | None = None
 
 
+AUTOTUNE_DIR = CHECKOUT_DIR / ".autotune"
+
+
 def cache_path() -> str:
-    return os.environ.get(
-        "REPRO_FUSED_CE_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "fused_ce_autotune.json"))
+    return os.environ.get("REPRO_FUSED_CE_CACHE",
+                          str(AUTOTUNE_DIR / "fused_ce_autotune.json"))
 
 
 def cache_key(N, D, Vp, *, dtype, transpose_w, softcap, norm,
@@ -160,8 +163,6 @@ def predict_seconds(N: int, D: int, Vp: int, bn: int, bv: int,
     schedule reads each operand exactly once.  Interpret: grid cells are
     unrolled by the interpreter, so cost = flops/CPU_FLOPS + cells *
     CELL_OVERHEAD_S (memory ignored — everything is host RAM)."""
-    from ..launch.roofline import HBM_BW, PEAK_FLOPS
-
     n_r, n_v = N // bn, Vp // bv
     mm = 2.0 * N * D * Vp                    # one full-projection matmul
     h_b = N * D * bytes_h
@@ -184,7 +185,15 @@ def predict_seconds(N: int, D: int, Vp: int, bn: int, bv: int,
     if interpret:
         flops = sum(f for f, _ in passes)
         return flops / CPU_FLOPS + cells * CELL_OVERHEAD_S
-    return sum(max(f / PEAK_FLOPS, b / HBM_BW) for f, b in passes)
+    return _roofline_seconds(passes)
+
+
+def _roofline_seconds(passes) -> float:
+    """Sum of per-pass ``max(flops / peak, bytes / bandwidth)`` on the
+    peaks of the chip this process runs on."""
+    from ..launch.roofline import device_peaks
+    pk = device_peaks()
+    return sum(max(f / pk.bf16_flops, b / pk.hbm_bw) for f, b in passes)
 
 
 # ---------------------------------------------------------------------------
@@ -388,10 +397,8 @@ class TunedAttn:
 
 
 def attn_cache_path() -> str:
-    return os.environ.get(
-        "REPRO_FLASH_ATTN_CACHE",
-        os.path.join(os.path.expanduser("~"), ".cache", "repro",
-                     "flash_attn_autotune.json"))
+    return os.environ.get("REPRO_FLASH_ATTN_CACHE",
+                          str(AUTOTUNE_DIR / "flash_attn_autotune.json"))
 
 
 def attn_cache_key(B, H, Hkv, Sq, Sk, hd, *, dtype, causal, softcap,
@@ -454,8 +461,6 @@ def attn_predict_seconds(B, H, Hkv, Sq, Sk, hd, bq, bk, schedule, *,
     computes the full grid, relying on masking.  Interpret mode charges
     per-cell dispatch overhead for every grid cell of all three kernels —
     pl.when saves arithmetic but not dispatch."""
-    from ..launch.roofline import HBM_BW, PEAK_FLOPS
-
     n_q, n_k = Sq // bq, Sk // bk
     band = _attn_band_cells(Sq, Sk, bq, bk, causal)
     full = float(n_q * n_k)
@@ -481,7 +486,7 @@ def attn_predict_seconds(B, H, Hkv, Sq, Sk, hd, bq, bk, schedule, *,
         (f_dq, 3 * q_pl + kv_stream + 2 * lse_b),
         (f_dkv, 4 * kv_pl + q_stream + 2 * lse_b),
     ]
-    return sum(max(f / PEAK_FLOPS, b / HBM_BW) for f, b in passes)
+    return _roofline_seconds(passes)
 
 
 def _load_attn_disk() -> None:

@@ -8,8 +8,11 @@ the online softmax entirely in VMEM:
 * **forward** — grid ``(B, H, Sq/BQ, Sk/BK)`` with the KV axis innermost
   ("arbitrary"): VMEM scratch carries the running max/denominator and an
   fp32 accumulator, initialized at ``j == 0`` and flushed at the last KV
-  block.  Emits the logsumexp ``(B, H, Sq)`` as a second output — the
-  backward residual.  GQA maps ``h -> h // G`` in the KV BlockSpecs.
+  block.  Emits the logsumexp ``(B, H, Sq, 1)`` as a second output — the
+  backward residual, carried with a trailing unit axis so its
+  ``(block_q, 1)`` blocks meet the TPU tiling rule (last two block dims a
+  multiple of (8, 128) or equal to the array's).  GQA maps
+  ``h -> h // G`` in the KV BlockSpecs.
 * **backward** (``custom_vjp``) — two kernels with the delta/lse recompute
   trick (``delta = rowsum(dO * O)`` precomputed outside): dQ iterates KV
   innermost with an fp32 ``(BQ, hd)`` accumulator; dK/dV iterate
@@ -51,7 +54,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
+from ..distributed.sharding import per_device
 from .fused_ce import KERNEL_CALLS, _interpret_default
 
 NEG_INF = -1e30
@@ -151,7 +156,7 @@ def _fwd_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     def _flush():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
 
 
 def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
@@ -182,7 +187,8 @@ def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
         out_specs=[
             pl.BlockSpec((1, 1, block_q, hd),
                          lambda b, h, i, j, w: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, i, j, w: (b, h, i)),
+            pl.BlockSpec((1, 1, block_q, 1),
+                         lambda b, h, i, j, w: (b, h, i, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max
@@ -200,7 +206,7 @@ def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
     )(win, q, k, v)
@@ -219,8 +225,8 @@ def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, i, j, win, *,
     k = k_ref[0, 0].astype(jnp.float32)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].reshape(block_q, 1)
-    delta = dl_ref[0, 0].reshape(block_q, 1)
+    lse = lse_ref[0, 0]                               # (block_q, 1)
+    delta = dl_ref[0, 0]
     s = _dotT(q, k) * scale
     if softcap is not None:
         t = jnp.tanh(s / softcap)
@@ -316,7 +322,8 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
 
     q_spec = pl.BlockSpec((1, 1, block_q, hd),
                           lambda b, h, i, j, w: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q), lambda b, h, i, j, w: (b, h, i))
+    row_spec = pl.BlockSpec((1, 1, block_q, 1),
+                            lambda b, h, i, j, w: (b, h, i, 0))
     kv_spec = pl.BlockSpec((1, 1, block_k, hd), kv_index)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -351,10 +358,10 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
         if skip:
             lo, hi = _q_band(j, w[0], n_q=n_q, **band)
             i = jnp.clip(i, lo, hi)
-        return (b, kv * group + t // n_q, i)
+        return (b, kv * group + t // n_q, i, 0)
 
     qg_spec = pl.BlockSpec((1, 1, block_q, hd), q_index)
-    rowg_spec = pl.BlockSpec((1, 1, block_q), row_index)
+    rowg_spec = pl.BlockSpec((1, 1, block_q, 1), row_index)
     kvb_spec = pl.BlockSpec((1, 1, block_k, hd),
                             lambda b, kv, j, t, w: (b, kv, j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -414,7 +421,8 @@ def _flash_fwd(q, k, v, win, causal, scale, softcap, q_offset, block_q,
 def _flash_bwd(causal, scale, softcap, q_offset, block_q, block_k, schedule,
                interpret, res, g):
     q, k, v, win, o, lse = res
-    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(
+        -1, keepdims=True)
     dq, dk, dv = _backward(
         q, k, v, win, g, lse, delta, causal=causal, scale=scale,
         softcap=softcap, q_offset=q_offset, block_q=block_q,
@@ -619,8 +627,14 @@ def flash_attention(q, k, v, *, causal=True, scale=None, window=None,
         jnp.asarray(WINDOW_NONE if window is None else window, jnp.int32),
         (1,))
     fn = _flash_jvp if use_jvp else _flash
-    return fn(q, k, v, win, causal, scale, softcap, int(q_offset),
-              block_q, block_k, schedule, bool(interpret))
+
+    def local(_, q, k, v, win):
+        return fn(q, k, v, win, causal, scale, softcap, int(q_offset),
+                  block_q, block_k, schedule, bool(interpret))
+
+    # under an activation mesh each device attends over its batch shard
+    spec = P("batch", None, None, None)
+    return per_device(local, (spec, spec, spec, P()), spec, B)(q, k, v, win)
 
 
 # ---------------------------------------------------------------------------

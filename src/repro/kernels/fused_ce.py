@@ -74,6 +74,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ..distributed.sharding import per_device
 
 DEFAULT_BN = 256    # rows (B*T positions) per tile (autotuner seed)
 DEFAULT_BV = 1024   # vocab columns per chunk (multiple of 128; seed)
@@ -122,7 +125,9 @@ def hash_gumbel(seed, rows, cols):
     """
     r = _mix32(rows.astype(_u32) ^ seed[0])
     x = _mix32(r ^ (cols.astype(_u32) * np.uint32(0x9E3779B9)) ^ seed[1])
-    u = (x >> np.uint32(8)).astype(_f32) * np.float32(1.0 / (1 << 24))
+    # 24-bit draws fit int32, whose float cast Mosaic lowers (uint32's not)
+    u = (jax.lax.bitcast_convert_type(x >> np.uint32(8), jnp.int32)
+         .astype(_f32) * np.float32(1.0 / (1 << 24)))
     u = jnp.clip(u, 1e-7, 1.0 - 1e-7)
     return -jnp.log(-jnp.log(u))
 
@@ -299,14 +304,13 @@ def _ce_fwd_kernel(np_ref, lab_ref, h_ref, w_ref, lse_out, ll_out,
     m_scr[...] = m_new[:, None]
     l_scr[...] = l_new[:, None]
 
-    ll_scr[...] += _label_logit_tile(s, lab_ref[...], j, bn, bv,
+    ll_scr[...] += _label_logit_tile(s, lab_ref[...][:, 0], j, bn, bv,
                                      interpret)[:, None]
 
     @pl.when(j == n_v - 1)
     def _flush():
-        lse_out[...] = (m_scr[...]
-                        + jnp.log(jnp.maximum(l_scr[...], 1e-37)))[:, 0]
-        ll_out[...] = ll_scr[...][:, 0]
+        lse_out[...] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-37))
+        ll_out[...] = ll_scr[...]
 
 
 def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
@@ -338,8 +342,10 @@ def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
     # online Gumbel-argmax: perturb this chunk, keep the running best,
     # remembering the winning column's RAW logit so the sampled-label NLL
     # needs no second pass
-    rows = i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, bv), 0)
-    g = hash_gumbel(seed_ref[...], rows, cols)
+    # global row index: the seed operand's third word is the first row's
+    rows = (i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, bv), 0)
+            ).astype(_u32) + seed_ref[2]
+    g = hash_gumbel((seed_ref[0], seed_ref[1]), rows, cols)
     zp = s + g if valid is None else jnp.where(valid, s + g, NEG_INF)
     zm, zi, zl = online_argmax_step(
         (zm_scr[...][:, 0], zi_scr[...][:, 0], zl_scr[...][:, 0]),
@@ -350,10 +356,9 @@ def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
 
     @pl.when(j == n_v - 1)
     def _flush():
-        lse_out[...] = (m_scr[...]
-                        + jnp.log(jnp.maximum(l_scr[...], 1e-37)))[:, 0]
-        ll_out[...] = zl_scr[...][:, 0]
-        yhat_out[...] = zi_scr[...][:, 0]
+        lse_out[...] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-37))
+        ll_out[...] = zl_scr[...]
+        yhat_out[...] = zi_scr[...]
 
 
 # ---------------------------------------------------------------------------
@@ -395,8 +400,8 @@ def _ce_bwd_dh_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dh_out,
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...], rs_ref[...],
-                      lse_ref[...], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
+    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
+                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
                       transpose_w=transpose_w, softcap=softcap,
                       interpret=interpret)
     w32 = w_ref[...].astype(_f32)
@@ -425,8 +430,8 @@ def _ce_bwd_dw_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dw_out,
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...], rs_ref[...],
-                      lse_ref[...], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
+    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
+                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
                       transpose_w=transpose_w, softcap=softcap,
                       interpret=interpret)
     h32 = hn.astype(_f32)
@@ -456,8 +461,8 @@ def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
     j = pl.program_id(1)
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...], rs_ref[...],
-                      lse_ref[...], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
+    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
+                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
                       transpose_w=transpose_w, softcap=softcap,
                       interpret=interpret)
     w32 = w_ref[...].astype(_f32)
@@ -492,12 +497,20 @@ def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
 
 
 def _specs(bn, bv, D, transpose_w):
+    """Grid (rows, chunks) BlockSpecs.  Per-row vectors (labels, row
+    scales, lse, label logits, samples) travel as ``(N, 1)`` columns: a
+    ``(bn, 1)`` block meets the TPU tiling rule, where a rank-1 ``(bn,)``
+    block would have to match XLA's 1-D layout tile."""
     h_spec = pl.BlockSpec((bn, D), lambda i, j: (i, 0))
     w_spec = (pl.BlockSpec((D, bv), lambda i, j: (0, j)) if transpose_w
               else pl.BlockSpec((bv, D), lambda i, j: (j, 0)))
-    vec_spec = pl.BlockSpec((bn,), lambda i, j: (i,))
+    vec_spec = pl.BlockSpec((bn, 1), lambda i, j: (i, 0))
     np_spec = pl.BlockSpec((2, D), lambda i, j: (0, 0))
     return h_spec, w_spec, vec_spec, np_spec
+
+
+def _col(x):
+    return x.reshape(-1, 1)
 
 
 def _vp_of(w, transpose_w):
@@ -518,16 +531,17 @@ def _ce_forward(h2, w, normp, labels, *, vocab, transpose_w, softcap, norm,
                              vp=n_v * bv, n_v=n_v, transpose_w=transpose_w,
                              softcap=softcap, norm=norm, eps=eps,
                              interpret=interpret)
-    return pl.pallas_call(
+    lse, ll = pl.pallas_call(
         kern,
         grid=(n_r, n_v),
         in_specs=[np_spec, vec_spec, h_spec, w_spec],
         out_specs=[vec_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((N,), _f32),
-                   jax.ShapeDtypeStruct((N,), _f32)],
+        out_shape=[jax.ShapeDtypeStruct((N, 1), _f32),
+                   jax.ShapeDtypeStruct((N, 1), _f32)],
         scratch_shapes=[pltpu.VMEM((bn, 1), _f32)] * 3,
         interpret=interpret,
-    )(_no_normp(D, normp), labels, h2, w)
+    )(_no_normp(D, normp), _col(labels), h2, w)
+    return lse[:, 0], ll[:, 0]
 
 
 def _ce_forward_sampled(h2, w, normp, seed, *, vocab, transpose_w, softcap,
@@ -536,19 +550,19 @@ def _ce_forward_sampled(h2, w, normp, seed, *, vocab, transpose_w, softcap,
     N, D = h2.shape
     n_r, n_v = N // bn, _vp_of(w, transpose_w) // bv
     h_spec, w_spec, vec_spec, np_spec = _specs(bn, bv, D, transpose_w)
-    seed_spec = pl.BlockSpec((2,), lambda i, j: (0,))
+    seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kern = functools.partial(_ce_fwd_sample_kernel, bn=bn, bv=bv,
                              vocab=vocab, vp=n_v * bv, n_v=n_v,
                              transpose_w=transpose_w, softcap=softcap,
                              norm=norm, eps=eps)
-    return pl.pallas_call(
+    outs = pl.pallas_call(
         kern,
         grid=(n_r, n_v),
         in_specs=[np_spec, seed_spec, h_spec, w_spec],
         out_specs=[vec_spec, vec_spec, vec_spec],
-        out_shape=[jax.ShapeDtypeStruct((N,), _f32),
-                   jax.ShapeDtypeStruct((N,), _f32),
-                   jax.ShapeDtypeStruct((N,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((N, 1), _f32),
+                   jax.ShapeDtypeStruct((N, 1), _f32),
+                   jax.ShapeDtypeStruct((N, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((bn, 1), _f32),
                         pltpu.VMEM((bn, 1), _f32),
                         pltpu.VMEM((bn, 1), _f32),
@@ -556,6 +570,7 @@ def _ce_forward_sampled(h2, w, normp, seed, *, vocab, transpose_w, softcap,
                         pltpu.VMEM((bn, 1), _f32)],
         interpret=interpret,
     )(_no_normp(D, normp), seed, h2, w)
+    return tuple(o[:, 0] for o in outs)
 
 
 def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
@@ -571,6 +586,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
     dh_dtype = _f32 if norm is not None else h2.dtype
     h_spec, w_spec, vec_spec, np_spec = _specs(bn, bv, D, transpose_w)
     normp = _no_normp(D, normp)
+    labels, rs, lse = _col(labels), _col(rs), _col(lse)
     dw_scr = pltpu.VMEM((D, bv) if transpose_w else (bv, D), _f32)
 
     if schedule == "fused":
@@ -612,7 +628,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
     hT_spec = pl.BlockSpec((bn, D), lambda j, i: (i, 0))
     wT_spec = (pl.BlockSpec((D, bv), lambda j, i: (0, j)) if transpose_w
                else pl.BlockSpec((bv, D), lambda j, i: (j, 0)))
-    vT_spec = pl.BlockSpec((bn,), lambda j, i: (i,))
+    vT_spec = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
     npT_spec = pl.BlockSpec((2, D), lambda j, i: (0, 0))
     kern_w = functools.partial(_ce_bwd_dw_kernel, bn=bn, bv=bv, vocab=vocab,
                                vp=Vp, n_r=n_r, transpose_w=transpose_w,
@@ -853,23 +869,37 @@ def _pick_block(n, want, quantum):
     return quantum, (-n) % quantum
 
 
-def _prep(hidden, labels_or_none, mask, block_n):
-    """Flatten leading dims and pad rows to a block multiple (padded rows
-    carry rowscale 0, so they contribute nothing to loss or gradients)."""
-    D = hidden.shape[-1]
-    h2 = hidden.reshape(-1, D)
-    N = h2.shape[0]
-    rs, n_valid = rowscale(N, mask)
-    bn, pad = _pick_block(N, block_n, 8)
+def _pad_rows(h2, block_n, *rows):
+    """Pad the flattened rows to a block multiple (padded rows carry
+    rowscale 0, so they contribute nothing to loss or gradients).  Returns
+    (h2, bn, *rows) with each ``rows`` vector padded alike."""
+    bn, pad = _pick_block(h2.shape[0], block_n, 8)
     if pad:
         h2 = jnp.pad(h2, ((0, pad), (0, 0)))
-        rs = jnp.pad(rs, (0, pad))
-    lab = None
-    if labels_or_none is not None:
-        lab = labels_or_none.reshape(-1).astype(jnp.int32)
-        if pad:
-            lab = jnp.pad(lab, (0, pad))
-    return h2, lab, rs, n_valid, bn
+        rows = tuple(jnp.pad(r, (0, pad)) for r in rows)
+    return (h2, bn) + tuple(rows)
+
+
+def _row_mapped(local, hidden, w, normp, aux, rs, *, aux_rows):
+    """Sum of ``local(row0, hidden_rows, w, normp, aux, rs_rows)`` over the
+    batch.  Under an activation mesh each device runs ``local`` on its own
+    batch shard (XLA cannot partition the kernels), ``row0`` being the
+    global index of the shard's first row; without one it is one call
+    with ``row0 = 0``.  ``rs`` is the global row scale, flattened;
+    ``aux`` is per row (labels, ``aux_rows``) or whole (a seed)."""
+    lead = hidden.shape[:-1]
+    rows_spec = P("batch", *([None] * (len(lead) - 1)))
+    h_spec = P("batch", *([None] * len(lead)))
+    aux_spec = rows_spec if aux_rows else P()
+
+    def body(idx, h, w_, normp_, aux_, rs_):
+        n_rows = rs_.size
+        return local(idx * n_rows, h.reshape(n_rows, h.shape[-1]), w_,
+                     normp_, aux_, rs_.reshape(-1)).reshape(1)
+
+    fn = per_device(body, (h_spec, P(), P(), aux_spec, rows_spec),
+                    P("batch"), lead[0] if lead else 1)
+    return fn(hidden, w, normp, aux, rs.reshape(lead)).sum()
 
 
 def _pick_bv(Vp, block_v, interpret=False):
@@ -934,19 +964,29 @@ def fused_lm_loss(hidden, w, labels, mask=None, *, vocab_size,
     fused backward sweeps.  With ``norm_kind`` ("rms"/"ln") ``hidden`` is
     PRE-final-norm and the norm applies inside the kernel (producer
     fusion); block sizes default to the autotuner's pick for this shape.
+    Under an activation mesh the sweep runs per batch shard.
     """
     softcap = float(softcap) if softcap else None
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = bool(_interpret_default() if interpret is None
+                     else interpret)
     norm, normp = _pack_norm(norm_kind, norm_scale, norm_bias,
                              hidden.shape[-1])
-    bn, bv, schedule = _resolve_blocks(
-        hidden, _vp_of(w, transpose_w), transpose_w=bool(transpose_w),
-        softcap=softcap, norm=norm, block_n=block_n, block_v=block_v,
-        schedule=schedule, interpret=bool(interpret))
-    h2, lab, rs, n_valid, bn = _prep(hidden, labels, mask, bn)
-    loss = _fused_nll(h2, w, _no_normp(h2.shape[1], normp), lab, rs,
-                      int(vocab_size), bool(transpose_w), softcap, norm,
-                      float(norm_eps), bn, bv, schedule, bool(interpret))
+    rs, n_valid = rowscale(labels.size, mask)
+    Vp = _vp_of(w, transpose_w)
+
+    def local(row0, h2, w_, normp_, lab, rs_):
+        del row0
+        bn, bv, sched = _resolve_blocks(
+            h2, Vp, transpose_w=bool(transpose_w), softcap=softcap,
+            norm=norm, block_n=block_n, block_v=block_v, schedule=schedule,
+            interpret=interpret)
+        h2, bn, lab, rs_ = _pad_rows(h2, bn, lab.reshape(-1), rs_)
+        return _fused_nll(h2, w_, normp_, lab, rs_, int(vocab_size),
+                          bool(transpose_w), softcap, norm, float(norm_eps),
+                          bn, bv, sched, interpret)
+
+    loss = _row_mapped(local, hidden, w, _no_normp(hidden.shape[-1], normp),
+                       labels.astype(jnp.int32), rs, aux_rows=True)
     return loss, n_valid
 
 
@@ -959,21 +999,30 @@ def fused_lm_loss_sampled(hidden, w, rng, mask=None, *, vocab_size,
     by online chunked Gumbel-argmax *inside* the forward kernel and returns
     the masked-mean NLL against it (``(loss, n_valid)``).  The gradient of
     ``loss`` is Algorithm 2's ``ghat`` contribution through this stage —
-    logits-free in both directions."""
+    logits-free in both directions.  The noise is keyed on global row
+    indices, so a batch-sharded run draws the same samples."""
     softcap = float(softcap) if softcap else None
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = bool(_interpret_default() if interpret is None
+                     else interpret)
     norm, normp = _pack_norm(norm_kind, norm_scale, norm_bias,
                              hidden.shape[-1])
-    bn, bv, schedule = _resolve_blocks(
-        hidden, _vp_of(w, transpose_w), transpose_w=bool(transpose_w),
-        softcap=softcap, norm=norm, block_n=block_n, block_v=block_v,
-        schedule=schedule, interpret=bool(interpret))
-    h2, _, rs, n_valid, bn = _prep(hidden, None, mask, bn)
-    seed = seed_from_key(rng)
-    loss = _fused_sampled_nll(h2, w, _no_normp(h2.shape[1], normp), seed, rs,
-                              int(vocab_size), bool(transpose_w), softcap,
-                              norm, float(norm_eps), bn, bv, schedule,
-                              bool(interpret))
+    rs, n_valid = rowscale(hidden.size // hidden.shape[-1], mask)
+    Vp = _vp_of(w, transpose_w)
+
+    def local(row0, h2, w_, normp_, seed, rs_):
+        bn, bv, sched = _resolve_blocks(
+            h2, Vp, transpose_w=bool(transpose_w), softcap=softcap,
+            norm=norm, block_n=block_n, block_v=block_v, schedule=schedule,
+            interpret=interpret)
+        h2, bn, rs_ = _pad_rows(h2, bn, rs_)
+        seed = jnp.concatenate([seed, jnp.asarray(row0, _u32).reshape(1)])
+        return _fused_sampled_nll(h2, w_, normp_, seed, rs_,
+                                  int(vocab_size), bool(transpose_w),
+                                  softcap, norm, float(norm_eps), bn, bv,
+                                  sched, interpret)
+
+    loss = _row_mapped(local, hidden, w, _no_normp(hidden.shape[-1], normp),
+                       seed_from_key(rng), rs, aux_rows=False)
     return loss, n_valid
 
 
@@ -986,15 +1035,23 @@ def fused_lm_loss_jvp(hidden, w, labels, mask=None, *, vocab_size,
     kernel-fused norm here (apply it in jnp first: the tangent must flow
     through the norm, which the chunked rule handles for free)."""
     softcap = float(softcap) if softcap else None
-    interpret = _interpret_default() if interpret is None else interpret
-    bn, bv, _ = _resolve_blocks(
-        hidden, _vp_of(w, transpose_w), transpose_w=bool(transpose_w),
-        softcap=softcap, norm=None, block_n=block_n, block_v=block_v,
-        schedule="split", interpret=bool(interpret))
-    h2, lab, rs, n_valid, bn = _prep(hidden, labels, mask, bn)
-    loss = _fused_nll_jvp(h2, w, lab, rs, int(vocab_size),
-                          bool(transpose_w), softcap, bn, bv,
-                          bool(interpret))
+    interpret = bool(_interpret_default() if interpret is None
+                     else interpret)
+    rs, n_valid = rowscale(labels.size, mask)
+    Vp = _vp_of(w, transpose_w)
+
+    def local(row0, h2, w_, normp_, lab, rs_):
+        del row0, normp_
+        bn, bv, _ = _resolve_blocks(
+            h2, Vp, transpose_w=bool(transpose_w), softcap=softcap,
+            norm=None, block_n=block_n, block_v=block_v, schedule="split",
+            interpret=interpret)
+        h2, bn, lab, rs_ = _pad_rows(h2, bn, lab.reshape(-1), rs_)
+        return _fused_nll_jvp(h2, w_, lab, rs_, int(vocab_size),
+                              bool(transpose_w), softcap, bn, bv, interpret)
+
+    loss = _row_mapped(local, hidden, w, _no_normp(hidden.shape[-1]),
+                       labels.astype(jnp.int32), rs, aux_rows=True)
     return loss, n_valid
 
 
@@ -1010,9 +1067,10 @@ def fused_lm_sample(hidden, w, rng, *, vocab_size, transpose_w=False,
         hidden, _vp_of(w, transpose_w), transpose_w=bool(transpose_w),
         softcap=softcap, norm=None, block_n=block_n, block_v=block_v,
         schedule="split", interpret=bool(interpret))
-    h2, _, _, _, bn = _prep(hidden, None, None, bn)
+    h2, bn = _pad_rows(hidden.reshape(-1, hidden.shape[-1]), bn)
+    seed = jnp.concatenate([seed_from_key(rng), jnp.zeros((1,), _u32)])
     _, _, yhat = _ce_forward_sampled(
-        h2, w, None, seed_from_key(rng), vocab=int(vocab_size),
+        h2, w, None, seed, vocab=int(vocab_size),
         transpose_w=bool(transpose_w), softcap=softcap, norm=None, eps=0.0,
         bn=bn, bv=bv, interpret=bool(interpret))
     n = 1

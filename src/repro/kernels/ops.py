@@ -25,10 +25,6 @@ from .sophia_update import (BLOCK, adamw_fused_block, hessian_ema_block,
 PyTree = Any
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _flat_pad(x, block):
     flat = x.reshape(-1).astype(jnp.float32)
     pad = (-flat.size) % block
@@ -44,7 +40,6 @@ def sophia_fused_apply(params: PyTree, m: PyTree, h: PyTree, grads: PyTree,
     """Fused Algorithm-3 apply over a whole parameter tree.
 
     Returns (new_params, new_m, clip_fraction)."""
-    interpret = _interpret_default() if interpret is None else interpret
     lr = jnp.asarray(lr, jnp.float32)
     total = 0
     clipped = []
@@ -79,7 +74,6 @@ def hessian_ema_apply(h: PyTree, est: PyTree, *, beta2: float,
                       scale: float = 1.0, block: int = BLOCK,
                       interpret: bool | None = None) -> PyTree:
     """Fused EMA refresh of the diagonal-Hessian state (line 9)."""
-    interpret = _interpret_default() if interpret is None else interpret
 
     def one(h_, e_):
         flat_h, _ = _flat_pad(h_, block)
@@ -95,7 +89,6 @@ def adamw_fused_apply(params: PyTree, m: PyTree, v: PyTree, grads: PyTree,
                       *, lr, step, beta1: float, beta2: float, eps: float,
                       weight_decay: float, block: int = BLOCK,
                       interpret: bool | None = None):
-    interpret = _interpret_default() if interpret is None else interpret
     lr = jnp.asarray(lr, jnp.float32)
     step = jnp.asarray(step, jnp.float32)
 

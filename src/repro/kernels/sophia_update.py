@@ -15,9 +15,16 @@ kernels compute in fp32 and preserve input dtypes on write, so bf16
 optimizer state (``state_dtype="bfloat16"`` at 400B scale) streams half the
 bytes without a separate cast pass.
 
+TPU layout rules the specs follow: scalar operands (lr, refresh flag, GNB
+scale, bias corrections) ride in SMEM as one small fp32 vector, and the
+per-block clip count is emitted as a lane-dense ``(1, 1, 128)`` int32 block
+(a rank-1 block of size 1 is not a legal TPU tiling); the wrappers return it
+as ``(grid,)``.  ``interpret=None`` resolves from the backend (interpret off
+a TPU, native compilation on one).
+
 Validated under ``interpret=True`` on CPU against kernels/ref.py across a
-shape x dtype sweep (tests/test_kernels.py, tests/test_engine.py); on a real
-TPU the same pallas_call compiles natively.
+shape x dtype sweep (tests/test_kernels.py, tests/test_engine.py) and
+compiled for a described v5e chip in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -26,6 +33,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .fused_ce import _interpret_default
 
 BLOCK = 128 * 1024  # elements per VMEM block (fp32: 512 KiB per operand)
 
@@ -36,49 +46,79 @@ def _grid_spec(block):
     return pl.BlockSpec((block,), lambda i: (i,))
 
 
-def _scalar_spec(n):
-    return pl.BlockSpec((n,), lambda i: (0,))
+# clip counts: one lane-dense (1, 1, 128) int32 block per grid step, the
+# count broadcast across its lanes (lane 0 is read back)
+_NCLIP_LANES = 128
+
+
+def _write_nclip(nclip_out, raw, clip_threshold):
+    n = jnp.sum((jnp.abs(raw) >= clip_threshold).astype(jnp.int32))
+    nclip_out[...] = jnp.full(nclip_out.shape, n, jnp.int32)
+
+
+def _sweep(kern, scalars, vecs, out_dtypes, *, block, nclip=False,
+           interpret=None):
+    """One grid sweep over flat shards (length % block == 0): ``scalars``
+    (fp32, SMEM) first, then the streamed ``vecs``.  Returns flat outputs of
+    ``out_dtypes`` and, with ``nclip``, the per-block clip counts
+    ``(grid,)`` int32."""
+    n = vecs[0].shape[0]
+    grid = n // block
+    spec = _grid_spec(block)
+    out_specs = [spec] * len(out_dtypes)
+    out_shape = [jax.ShapeDtypeStruct((n,), dt) for dt in out_dtypes]
+    if nclip:
+        out_specs.append(pl.BlockSpec((1, 1, _NCLIP_LANES),
+                                      lambda i: (i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((grid, 1, _NCLIP_LANES),
+                                              jnp.int32))
+    outs = pl.pallas_call(
+        kern,
+        grid=(grid,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [spec] * len(vecs),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        interpret=_interpret_default() if interpret is None else interpret,
+    )(jnp.stack([jnp.asarray(x, _f32) for x in scalars]), *vecs)
+    if nclip:
+        outs = list(outs[:-1]) + [outs[-1][:, 0, 0]]
+    return outs
+
+
+def _bias_corrections(beta1, beta2, step):
+    step = jnp.asarray(step, _f32)
+    return 1.0 - beta1 ** step, 1.0 - beta2 ** step
 
 
 # ---------------------------------------------------------------------------
 # Sophia (Algorithm 3 lines 6, 12, 13) + Hessian EMA (line 9)
 
 
-def _sophia_kernel(lr_ref, p_ref, m_ref, h_ref, g_ref,
+def _sophia_kernel(sc_ref, p_ref, m_ref, h_ref, g_ref,
                    p_out, m_out, nclip_out, *,
                    beta1, gamma, eps, weight_decay, clip_threshold):
-    lr = lr_ref[0]
+    lr = sc_ref[0]
     m = beta1 * m_ref[...].astype(_f32) + (1.0 - beta1) * g_ref[...].astype(_f32)
     raw = m / jnp.maximum(gamma * h_ref[...].astype(_f32), eps)
     u = jnp.clip(raw, -clip_threshold, clip_threshold)
     p_out[...] = (p_ref[...].astype(_f32) * (1.0 - lr * weight_decay)
                   - lr * u).astype(p_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
-    nclip_out[0] = jnp.sum((jnp.abs(raw) >= clip_threshold)
-                           .astype(jnp.int32))
+    _write_nclip(nclip_out, raw, clip_threshold)
 
 
 def sophia_fused_block(p, m, h, g, lr, *, beta1, gamma, eps, weight_decay,
-                       clip_threshold=1.0, block=BLOCK, interpret=True):
+                       clip_threshold=1.0, block=BLOCK, interpret=None):
     """Run the fused step on flat arrays (length % block == 0).
 
-    Dtypes are preserved: p' matches p, m' matches m (compute is fp32)."""
-    n = p.shape[0]
-    grid = n // block
+    Dtypes are preserved: p' matches p, m' matches m (compute is fp32).
+    Returns (p', m', nclip per block)."""
     kern = functools.partial(
         _sophia_kernel, beta1=beta1, gamma=gamma, eps=eps,
         weight_decay=weight_decay, clip_threshold=clip_threshold)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(1), spec, spec, spec, spec],
-        out_specs=[spec, spec, pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype),
-                   jax.ShapeDtypeStruct((grid,), jnp.int32)],
-        interpret=interpret,
-    )(jnp.asarray(lr, _f32).reshape(1), p, m, h, g)
+    return _sweep(kern, (lr,), (p, m, h, g), (p.dtype, m.dtype),
+                  block=block, nclip=True, interpret=interpret)
 
 
 def _hess_ema_kernel(sc_ref, h_ref, e_ref, h_out, *, beta2, square):
@@ -90,7 +130,7 @@ def _hess_ema_kernel(sc_ref, h_ref, e_ref, h_out, *, beta2, square):
 
 
 def hessian_ema_block(h, est, *, beta2, scale=1.0, square=False, block=BLOCK,
-                      interpret=True):
+                      interpret=None):
     """h' = beta2 h + (1-beta2) * scale * est on a flat array.
 
     ``scale`` folds the GNB batch factor B in (Algorithm 2 line 6) so the
@@ -98,18 +138,10 @@ def hessian_ema_block(h, est, *, beta2, scale=1.0, square=False, block=BLOCK,
     scalar (B depends on the step's valid-token mask).  ``square=True`` is
     the AdaHessian refresh: h' = b2 h + (1-b2) (scale * est)^2.
     """
-    n = h.shape[0]
-    grid = n // block
     kern = functools.partial(_hess_ema_kernel, beta2=beta2, square=square)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(1), spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((n,), h.dtype),
-        interpret=interpret,
-    )(jnp.asarray(scale, _f32).reshape(1), h, est)
+    (h2,) = _sweep(kern, (scale,), (h, est), (h.dtype,), block=block,
+                   interpret=interpret)
+    return h2
 
 
 def _sophia_refresh_kernel(sc_ref, p_ref, m_ref, h_ref, g_ref, e_ref,
@@ -130,14 +162,13 @@ def _sophia_refresh_kernel(sc_ref, p_ref, m_ref, h_ref, g_ref, e_ref,
     p_out[...] = (p_ref[...].astype(_f32) * (1.0 - lr * weight_decay)
                   - lr * u).astype(p_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
-    nclip_out[0] = jnp.sum((jnp.abs(raw) >= clip_threshold)
-                           .astype(jnp.int32))
+    _write_nclip(nclip_out, raw, clip_threshold)
 
 
 def sophia_refresh_fused_block(p, m, h, g, e, lr, flag, scale, *,
                                beta1, beta2, gamma, eps, weight_decay,
                                clip_threshold=1.0, block=BLOCK,
-                               interpret=True):
+                               interpret=None):
     """One grid sweep fusing the Hessian-EMA refresh into the Sophia step.
 
     ``flag`` (traced 0/1) selects whether h absorbs ``scale * e`` before the
@@ -146,25 +177,12 @@ def sophia_refresh_fused_block(p, m, h, g, e, lr, flag, scale, *,
     h read/write pass.  ``scale`` is the GNB batch factor B (traced).
 
     Returns (p', m', h', nclip per block)."""
-    n = p.shape[0]
-    grid = n // block
-    scalars = jnp.stack([jnp.asarray(lr, _f32), jnp.asarray(flag, _f32),
-                         jnp.asarray(scale, _f32)])
     kern = functools.partial(
         _sophia_refresh_kernel, beta1=beta1, beta2=beta2, gamma=gamma,
         eps=eps, weight_decay=weight_decay, clip_threshold=clip_threshold)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(3), spec, spec, spec, spec, spec],
-        out_specs=[spec, spec, spec, pl.BlockSpec((1,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype),
-                   jax.ShapeDtypeStruct((n,), h.dtype),
-                   jax.ShapeDtypeStruct((grid,), jnp.int32)],
-        interpret=interpret,
-    )(scalars, p, m, h, g, e)
+    return _sweep(kern, (lr, flag, scale), (p, m, h, g, e),
+                  (p.dtype, m.dtype, h.dtype), block=block, nclip=True,
+                  interpret=interpret)
 
 
 def _adahessian_refresh_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, e_ref,
@@ -186,29 +204,15 @@ def _adahessian_refresh_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, e_ref,
 
 def adahessian_refresh_fused_block(p, m, v, g, e, lr, flag, scale, step, *,
                                    beta1, beta2, eps, weight_decay,
-                                   block=BLOCK, interpret=True):
+                                   block=BLOCK, interpret=None):
     """AdaHessian step with the squared-estimate EMA fused in (flag-gated),
     the refresh analogue of :func:`sophia_refresh_fused_block`."""
-    n = p.shape[0]
-    grid = n // block
-    step = jnp.asarray(step, _f32)
-    bc1 = 1.0 - beta1 ** step
-    bc2 = 1.0 - beta2 ** step
-    scalars = jnp.stack([jnp.asarray(lr, _f32), jnp.asarray(flag, _f32),
-                         jnp.asarray(scale, _f32), bc1, bc2])
+    bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adahessian_refresh_kernel, beta1=beta1,
                              beta2=beta2, eps=eps, weight_decay=weight_decay)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(5), spec, spec, spec, spec, spec],
-        out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype),
-                   jax.ShapeDtypeStruct((n,), v.dtype)],
-        interpret=interpret,
-    )(scalars, p, m, v, g, e)
+    return _sweep(kern, (lr, flag, scale, bc1, bc2), (p, m, v, g, e),
+                  (p.dtype, m.dtype, v.dtype), block=block,
+                  interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -229,27 +233,14 @@ def _adamw_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, p_out, m_out, v_out, *,
 
 
 def adamw_fused_block(p, m, v, g, lr, step, *, beta1, beta2, eps,
-                      weight_decay, block=BLOCK, interpret=True):
+                      weight_decay, block=BLOCK, interpret=None):
     """Fused AdamW on flat arrays (baseline parity for Table 1)."""
-    n = p.shape[0]
-    grid = n // block
-    step = jnp.asarray(step, _f32)
-    bc1 = 1.0 - beta1 ** step
-    bc2 = 1.0 - beta2 ** step
-    scalars = jnp.stack([jnp.asarray(lr, _f32), bc1, bc2])
+    bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adamw_kernel, beta1=beta1, beta2=beta2,
                              eps=eps, weight_decay=weight_decay)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(3), spec, spec, spec, spec],
-        out_specs=[spec, spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype),
-                   jax.ShapeDtypeStruct((n,), v.dtype)],
-        interpret=interpret,
-    )(scalars, p, m, v, g)
+    return _sweep(kern, (lr, bc1, bc2), (p, m, v, g),
+                  (p.dtype, m.dtype, v.dtype), block=block,
+                  interpret=interpret)
 
 
 def _adahessian_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, p_out, m_out, *,
@@ -263,31 +254,18 @@ def _adahessian_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, p_out, m_out, *,
 
 
 def adahessian_fused_block(p, m, v, g, lr, step, *, beta1, beta2, eps,
-                           weight_decay, block=BLOCK, interpret=True):
+                           weight_decay, block=BLOCK, interpret=None):
     """AdaHessian step: Adam-shaped, v read-only (refreshed out-of-band)."""
-    n = p.shape[0]
-    grid = n // block
-    step = jnp.asarray(step, _f32)
-    bc1 = 1.0 - beta1 ** step
-    bc2 = 1.0 - beta2 ** step
-    scalars = jnp.stack([jnp.asarray(lr, _f32), bc1, bc2])
+    bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adahessian_kernel, beta1=beta1, beta2=beta2,
                              eps=eps, weight_decay=weight_decay)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(3), spec, spec, spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=interpret,
-    )(scalars, p, m, v, g)
+    return _sweep(kern, (lr, bc1, bc2), (p, m, v, g), (p.dtype, m.dtype),
+                  block=block, interpret=interpret)
 
 
-def _lion_kernel(lr_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
+def _lion_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
                  beta1, beta2, weight_decay):
-    lr = lr_ref[0]
+    lr = sc_ref[0]
     g = g_ref[...].astype(_f32)
     m = m_ref[...].astype(_f32)
     u = jnp.sign(beta1 * m + (1.0 - beta1) * g)
@@ -297,26 +275,16 @@ def _lion_kernel(lr_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
 
 
 def lion_fused_block(p, m, g, lr, *, beta1, beta2, weight_decay, block=BLOCK,
-                     interpret=True):
-    n = p.shape[0]
-    grid = n // block
+                     interpret=None):
     kern = functools.partial(_lion_kernel, beta1=beta1, beta2=beta2,
                              weight_decay=weight_decay)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(1), spec, spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=interpret,
-    )(jnp.asarray(lr, _f32).reshape(1), p, m, g)
+    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
+                  interpret=interpret)
 
 
-def _signgd_kernel(lr_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
+def _signgd_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
                    beta1, weight_decay):
-    lr = lr_ref[0]
+    lr = sc_ref[0]
     m = beta1 * m_ref[...].astype(_f32) + (1.0 - beta1) * g_ref[...].astype(_f32)
     p_out[...] = (p_ref[...].astype(_f32) * (1.0 - lr * weight_decay)
                   - lr * jnp.sign(m)).astype(p_out.dtype)
@@ -324,41 +292,21 @@ def _signgd_kernel(lr_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
 
 
 def signgd_fused_block(p, m, g, lr, *, beta1, weight_decay, block=BLOCK,
-                       interpret=True):
-    n = p.shape[0]
-    grid = n // block
+                       interpret=None):
     kern = functools.partial(_signgd_kernel, beta1=beta1,
                              weight_decay=weight_decay)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(1), spec, spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=interpret,
-    )(jnp.asarray(lr, _f32).reshape(1), p, m, g)
+    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
+                  interpret=interpret)
 
 
-def _sgd_kernel(lr_ref, p_ref, m_ref, g_ref, p_out, m_out, *, momentum):
-    lr = lr_ref[0]
+def _sgd_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *, momentum):
+    lr = sc_ref[0]
     m = momentum * m_ref[...].astype(_f32) + g_ref[...].astype(_f32)
     p_out[...] = (p_ref[...].astype(_f32) - lr * m).astype(p_out.dtype)
     m_out[...] = m.astype(m_out.dtype)
 
 
-def sgd_fused_block(p, m, g, lr, *, momentum, block=BLOCK, interpret=True):
-    n = p.shape[0]
-    grid = n // block
+def sgd_fused_block(p, m, g, lr, *, momentum, block=BLOCK, interpret=None):
     kern = functools.partial(_sgd_kernel, momentum=momentum)
-    spec = _grid_spec(block)
-    return pl.pallas_call(
-        kern,
-        grid=(grid,),
-        in_specs=[_scalar_spec(1), spec, spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[jax.ShapeDtypeStruct((n,), p.dtype),
-                   jax.ShapeDtypeStruct((n,), m.dtype)],
-        interpret=interpret,
-    )(jnp.asarray(lr, _f32).reshape(1), p, m, g)
+    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
+                  interpret=interpret)

@@ -1,6 +1,7 @@
-"""Production meshes, multi-host initialization, and scheduler flags.
+"""Runtime setup: production meshes, multi-host initialization, scheduler
+options and the persistent compilation cache.
 
-Defined as FUNCTIONS (never module-level constants) so importing this module
+Defined as FUNCTIONS (never module-level meshes) so importing this module
 never touches jax device state — the dry-run must set XLA_FLAGS before the
 first jax device query.
 """
@@ -9,52 +10,51 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
-try:  # jax >= 0.5 names explicit/auto axis types; older jax has only Auto
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version compat
-    AxisType = None
+from .. import CHECKOUT_DIR
+
+DEFAULT_COMPILE_CACHE = CHECKOUT_DIR / ".jax_cache"
 
 
-def _mesh_kwargs(n_axes: int) -> dict:
-    if AxisType is None:
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: JAX reads it itself and
+    no other directory is set here.  Otherwise the cache lives at the fixed
+    ``<checkout>/.jax_cache`` (gitignored): a path built from nothing
+    variable, so every run from one checkout finds the previous run's
+    executables."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_COMPILE_CACHE))
+    return str(DEFAULT_COMPILE_CACHE)
+
+
+# XLA TPU options that let the scheduler overlap the bucketed gradient
+# collectives (distributed/overlap.py) with backward compute.  Passed per
+# compilation (``jax.jit(..., compiler_options=...)``): the TPU compiler
+# rejects an option it does not know with an error at compile time, and
+# nothing leaks into the process environment.
+TPU_OVERLAP_OPTIONS = {
+    "xla_tpu_enable_latency_hiding_scheduler": True,
+    "xla_tpu_enable_async_collective_fusion": True,
+    "xla_tpu_enable_async_collective_fusion_fuse_all_gather": True,
+    "xla_tpu_overlap_compute_collective_tc": True,
+}
+
+
+def overlap_compiler_options(mesh) -> dict:
+    """Compiler options for a step compiled over ``mesh``: the TPU overlap
+    options when the mesh spans more than one TPU device, else none (one
+    device has no collectives to overlap, and the CPU thunk runtime already
+    runs independent per-bucket collective chains concurrently)."""
+    if mesh is None or mesh.devices.size < 2:
         return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
-
-
-def latency_hiding_flags(platform: str) -> tuple:
-    """XLA flags that let the scheduler overlap the bucketed gradient
-    collectives (distributed/overlap.py) with backward compute.
-
-    Keyed on platform because XLA treats *unknown* flags as fatal — a
-    ``--xla_tpu_*`` flag crashes a CPU-only build at first compile.  CPU
-    gets the empty set: the thunk runtime already executes independent
-    per-bucket collective chains concurrently with compute, no flag
-    needed."""
-    if platform == "tpu":
-        return (
-            "--xla_tpu_enable_latency_hiding_scheduler=true",
-            "--xla_tpu_enable_async_collective_fusion=true",
-            "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
-            "--xla_tpu_overlap_compute_collective_tc=true",
-        )
-    if platform == "gpu":
-        return ("--xla_gpu_enable_latency_hiding_scheduler=true",)
-    return ()
-
-
-def enable_latency_hiding(platform: str = "tpu") -> bool:
-    """Append :func:`latency_hiding_flags` to ``XLA_FLAGS`` in the
-    environment.  Must run before the first jax device query (same rule as
-    the dry-run); flags already present are not duplicated.  Returns True
-    if the environment changed."""
-    flags = [f for f in latency_hiding_flags(platform)
-             if f not in os.environ.get("XLA_FLAGS", "")]
-    if not flags:
-        return False
-    prior = os.environ.get("XLA_FLAGS", "")
-    os.environ["XLA_FLAGS"] = (prior + " " + " ".join(flags)).strip()
-    return True
+    if mesh.devices.flat[0].platform != "tpu":
+        return {}
+    return dict(TPU_OVERLAP_OPTIONS)
 
 
 def initialize_distributed(coordinator: str, num_processes: int,
@@ -69,10 +69,7 @@ def initialize_distributed(coordinator: str, num_processes: int,
     it pre-distributed — the exact bug this helper exists to prevent.  The
     2-process localhost tier (tests/test_multiprocess.py) runs this path."""
     if os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - older jax: option absent
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,
                                process_id=process_id)
@@ -96,7 +93,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_mesh_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes, devices=None):
@@ -110,4 +107,4 @@ def make_mesh(shape, axes, devices=None):
         from jax.sharding import Mesh
         return Mesh(np.asarray(devices).reshape(tuple(shape)), tuple(axes))
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_mesh_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -1,8 +1,10 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-    compute    = HLO_FLOPs_per_device / peak_FLOPs          (197 TF/s bf16)
-    memory     = HLO_bytes_per_device / HBM_bw              (819 GB/s)
-    collective = collective_bytes_per_device / link_bw      (~50 GB/s ICI)
+    compute    = HLO_FLOPs_per_device / peak_FLOPs
+    memory     = HLO_bytes_per_device / HBM_bw
+    collective = collective_bytes_per_device / link_bw
+
+with the peaks of :data:`CHIP_PEAKS` (the dry-run models ``TARGET_KIND``).
 
 ``cost_analysis`` reports per-device (post-SPMD) flops and bytes.
 Collective bytes are NOT in cost_analysis: we parse the compiled HLO text,
@@ -37,12 +39,44 @@ is folded into :func:`attention_stage_seconds` +
 from __future__ import annotations
 
 import re
-from typing import Dict
+from typing import Dict, NamedTuple
 
-# TPU v5e constants (per chip)
-PEAK_FLOPS = 197e12     # bf16
-HBM_BW = 819e9          # bytes/s
-ICI_BW = 50e9           # bytes/s per link
+
+class ChipPeaks(NamedTuple):
+    """Published per-chip peaks."""
+    bf16_flops: float       # FLOP/s
+    hbm_bw: float           # HBM bandwidth, bytes/s
+    ici_bw: float           # bytes/s per ICI link
+
+
+# Keyed by ``jax.Device.device_kind``.  Source: Google Cloud TPU
+# documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+# 1,600 Gbit/s of chip-to-chip interconnect (four links: 50 GB/s each).
+CHIP_PEAKS = {
+    "TPU v5 lite": ChipPeaks(bf16_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def device_peaks(device_kind: str | None = None) -> ChipPeaks:
+    """Peaks of ``device_kind`` (default: the first local device's).  A
+    device missing from :data:`CHIP_PEAKS` is an error, never a default."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add it to "
+            f"launch/roofline.CHIP_PEAKS with its source") from None
+
+
+# The chip the dry-run terms and byte-model overlays below are modeled for
+# (the production meshes in configs/shapes.py are v5e pods).
+TARGET_KIND = "TPU v5 lite"
+PEAK_FLOPS = CHIP_PEAKS[TARGET_KIND].bf16_flops
+HBM_BW = CHIP_PEAKS[TARGET_KIND].hbm_bw
+ICI_BW = CHIP_PEAKS[TARGET_KIND].ici_bw
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -26,6 +26,7 @@ from ..configs import ARCHS, get_config
 from ..models import get_model
 from ..models.layers import set_decode_attn_impl
 from ..serve import Request, ServeEngine
+from .mesh import enable_compile_cache
 
 ENC_SRC_LEN = 16  # synthetic frame-stream length for encdec requests
 
@@ -100,6 +101,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     set_decode_attn_impl(args.decode_kernel)
     cfg = get_config(args.arch, smoke=args.smoke)
     model = get_model(cfg)

@@ -27,7 +27,6 @@ persistent straggler degrades capacity instead of killing the run.
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
 import numpy as np
@@ -50,7 +49,8 @@ from ..train.elastic import (MeshDegraded, NodeLoss, PreemptionGuard,
                              StragglerDetector, is_distributed_failure,
                              run_resumable)
 from ..train.train_state import state_partition_specs
-from .mesh import enable_latency_hiding, initialize_distributed, make_mesh
+from .mesh import (enable_compile_cache, initialize_distributed,
+                   make_mesh, overlap_compiler_options)
 
 
 def build_mesh(devices=None):
@@ -109,7 +109,9 @@ def compile_train_step(cfg, tc, mesh, sample_batch, state_shape=None):
     where it used to hold a hot step *and* a refresh step.
 
     ``state_shape`` (an eval_shape of init_fn, mesh-independent) can be
-    passed in to avoid re-tracing the model abstractly."""
+    passed in to avoid re-tracing the model abstractly.  A multi-chip TPU
+    mesh also gets the collective-overlap scheduler options
+    (``mesh.overlap_compiler_options``)."""
     init_fn, train_step = make_train_fns(cfg, tc)
     # donate the TrainState: the flat params/m/h shards alias input->output,
     # halving optimizer-state peak memory (CPU has no donation; skip the
@@ -127,7 +129,8 @@ def compile_train_step(cfg, tc, mesh, sample_batch, state_shape=None):
     ssh = ns(sspecs)
     bsh = ns(batch_specs(sample_batch, mesh))
     return (jax.jit(train_step, in_shardings=(ssh, bsh, None),
-                    out_shardings=(ssh, None), donate_argnums=dn),
+                    out_shardings=(ssh, None), donate_argnums=dn,
+                    compiler_options=overlap_compiler_options(mesh)),
             init_fn, ssh, bsh)
 
 
@@ -206,10 +209,9 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    # MUST precede every jax device query: scheduler flags only apply at
-    # backend init, and distributed init after a device query deadlocks
-    enable_latency_hiding(
-        (os.environ.get("JAX_PLATFORMS") or "tpu").split(",")[0])
+    # MUST precede every jax device query: distributed init after a device
+    # query deadlocks
+    enable_compile_cache()
     if args.coordinator:
         initialize_distributed(args.coordinator, args.num_processes,
                                args.process_id)
@@ -343,6 +345,10 @@ def main(argv=None):
             flag = jnp.asarray(needs_hess and t % tc.hess_interval == 0)
             try:
                 state, metrics = ctx["sjit"](state, batch, flag)
+                # dispatch is asynchronous: wait, so the step time (and the
+                # straggler detector) see device time, and a failed
+                # execution raises inside this handler
+                jax.block_until_ready(metrics["loss"])
             except Exception as e:
                 if jax.process_count() > 1 and is_distributed_failure(e):
                     # a peer died: unrecoverable in-process — propagate as

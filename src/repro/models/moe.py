@@ -23,7 +23,6 @@ deepseek-moe-16b (64e top-6 + 2 shared, fine-grained).
 """
 from __future__ import annotations
 
-import functools
 from typing import Tuple
 
 import jax
@@ -165,29 +164,8 @@ def moe_ffn_gspmd(p, x, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
 # the tokens themselves (T_loc*K*cf*D / M bytes per device per direction).
 
 
-@functools.lru_cache(maxsize=1)
-def _shard_map_api():
-    """(shard_map, kwargs-to-disable-replication-checking), resolved once.
-
-    jax renamed check_rep -> check_vma when shard_map left experimental;
-    keying on the actual signature covers both generations."""
-    import inspect
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        params = inspect.signature(shard_map).parameters
-        no_check = ({"check_vma": False} if "check_vma" in params
-                    else {"check_rep": False})
-    except (TypeError, ValueError):  # wrapper with opaque signature
-        no_check = {}
-    return shard_map, no_check
-
-
 def moe_ffn_a2a(p, x, cfg: ModelConfig, mesh):
     from jax.sharding import PartitionSpec as P
-    shard_map, no_check = _shard_map_api()
 
     dt = x.dtype
     B, S, D = x.shape
@@ -273,11 +251,10 @@ def moe_ffn_a2a(p, x, cfg: ModelConfig, mesh):
     x_spec = P(b_ax if B % _bsize(mesh, b_ax) == 0 else None, None, None)
     ew = P("model", None, None)
     shared_spec = jax.tree.map(lambda _: P(None, None), shared)
-    fn = shard_map(
+    fn = jax.shard_map(
         _local, mesh=mesh,
         in_specs=(x_spec, P(None, None), ew, ew, ew, shared_spec),
-        out_specs=(x_spec, P()),
-        **no_check)
+        out_specs=(x_spec, P()), check_vma=False)
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"], shared)
 
 

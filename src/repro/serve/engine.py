@@ -53,6 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import ModelConfig, get_model
+from ..models.layers import get_decode_attn_impl
 from .decode import NO_EOS, make_decode_burst, sample_tokens
 from .prefix_cache import ROOT, PrefixCache
 
@@ -67,10 +68,14 @@ def _pct(xs, q: float) -> float:
 
 
 @functools.lru_cache(maxsize=32)
-def _compiled_fns(cfg: ModelConfig, steps_per_tick: int):
+def _compiled_fns(cfg: ModelConfig, steps_per_tick: int, decode_impl: str):
     """Jitted prefill/reset/burst shared by every engine on this config —
     a fresh ServeEngine (e.g. after a timed warmup run) must not recompile.
     ModelConfig is a frozen dataclass, so it keys the cache directly.
+    ``decode_impl`` (``layers.get_decode_attn_impl()`` when the engine is
+    built) is read at trace time, so it keys the programs too: an engine
+    built after switching the decode kernel must not reuse programs traced
+    with the other one.
 
     The slot-state argument is donated everywhere (the caller immediately
     rebinds it): the KV cache is the engine's dominant allocation, and
@@ -181,7 +186,8 @@ class ServeEngine:
         else:
             self.state = self.model.init_slots(cfg, n_slots, self.cache_len)
         (self._prefill_jit, self._reset_jit, self._burst_jit,
-         self._enc_jit) = _compiled_fns(self.cfg, steps_per_tick)
+         self._enc_jit) = _compiled_fns(self.cfg, steps_per_tick,
+                                        get_decode_attn_impl())
 
         # shared-prefix page pool: same pytree layout as the slot cache
         # with the slot axis replaced by pool pages of one page_len each

@@ -300,16 +300,37 @@ def test_attn_interpret_candidates_fit_cell_cap():
     assert ATTN["B"] * ATTN["H"] * cells <= INTERPRET_CELL_CAP
 
 
-def test_attn_predict_skip_beats_dense_when_causal():
+def _rank_as_v5e(monkeypatch):
+    """The real-backend ranking reads the peaks of the chip it runs on;
+    on this CPU host, steer it to the v5e entry."""
+    from repro.launch import roofline
+    monkeypatch.setattr(roofline, "device_peaks",
+                        lambda kind=None: roofline.CHIP_PEAKS["TPU v5 lite"])
+
+
+def test_attn_predict_skip_beats_dense_when_causal(monkeypatch):
     """The roofline cost charges only in-band tiles under "skip": on a
     multi-block causal grid it must price below "dense" (which streams the
     full rectangle), on the real backend where cells aren't emulated."""
+    _rank_as_v5e(monkeypatch)
     kw = dict(bytes_el=2, causal=True, interpret=False)
     skip = autotune.attn_predict_seconds(8, 12, 4, 2048, 2048, 128,
                                          256, 256, "skip", **kw)
     dense = autotune.attn_predict_seconds(8, 12, 4, 2048, 2048, 128,
                                           256, 256, "dense", **kw)
     assert skip < dense
+
+
+def test_real_backend_ranking_needs_known_chip():
+    """Peaks come from a table keyed by device_kind: an unknown chip is an
+    error, never silently ranked with another chip's numbers."""
+    from repro.launch.roofline import CHIP_PEAKS, device_peaks
+    assert device_peaks("TPU v5 lite") is CHIP_PEAKS["TPU v5 lite"]
+    with pytest.raises(ValueError, match="no published peaks"):
+        device_peaks("TPU v99")
+    with pytest.raises(ValueError, match="no published peaks"):
+        autotune.predict_seconds(512, 64, 1024, 128, 128, "split",
+                                 bytes_h=2, bytes_w=4, interpret=False)
 
 
 @pytest.mark.slow

@@ -27,15 +27,16 @@ def _rand(N, H, Hkv, C, hd, seed=0):
     return q, k, v
 
 
+# pages are multiples of 128 (the int8 scale row is a lane-dense block)
 @pytest.mark.parametrize("N,H,Hkv,C,hd,page", [
-    (3, 4, 2, 32, 16, 8),     # GQA
-    (2, 2, 1, 64, 8, 16),     # MQA
-    (4, 8, 8, 16, 32, 16),    # MHA, single page
-    (1, 4, 4, 48, 64, 8),     # non-power-of-two page count
+    (3, 4, 2, 256, 16, 128),  # GQA
+    (2, 2, 1, 512, 8, 256),   # MQA
+    (4, 8, 8, 128, 32, 128),  # MHA, single page
+    (1, 4, 4, 384, 64, 128),  # non-power-of-two page count
 ])
 def test_kernel_matches_oracle(N, H, Hkv, C, hd, page):
     q, k, v = _rand(N, H, Hkv, C, hd)
-    pos = (jnp.arange(N, dtype=jnp.int32) * 7 + 3) % C
+    pos = (jnp.arange(N, dtype=jnp.int32) * 97 + C // 2 + 3) % C
     got = decode_attention_pallas(q, k, v, pos, page_len=page)
     want = decode_attention_ref(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
@@ -43,31 +44,31 @@ def test_kernel_matches_oracle(N, H, Hkv, C, hd, page):
 
 def test_kernel_ring_wraparound():
     """Positions beyond C: the ring has wrapped; stale entries must mask."""
-    N, H, Hkv, C, hd = 2, 4, 2, 16, 16
+    N, H, Hkv, C, hd = 2, 4, 2, 256, 16
     q, k, v = _rand(N, H, Hkv, C, hd, seed=1)
     pos = jnp.array([C + 3, 5 * C + 11], jnp.int32)
-    got = decode_attention_pallas(q, k, v, pos, page_len=8)
+    got = decode_attention_pallas(q, k, v, pos, page_len=128)
     want = decode_attention_ref(q, k, v, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
 
 
 @pytest.mark.parametrize("window", [4, 12])
 def test_kernel_sliding_window(window):
-    N, H, Hkv, C, hd = 2, 4, 1, 32, 16
+    N, H, Hkv, C, hd = 2, 4, 1, 256, 16
     q, k, v = _rand(N, H, Hkv, C, hd, seed=2)
-    pos = jnp.array([9, 27], jnp.int32)
-    got = decode_attention_pallas(q, k, v, pos, page_len=8, window=window)
+    pos = jnp.array([133, 250], jnp.int32)   # window 12 at 133 spans pages
+    got = decode_attention_pallas(q, k, v, pos, page_len=128, window=window)
     want = decode_attention_ref(q, k, v, pos, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
 
 
 def test_kernel_softcap_and_traced_window():
-    N, H, Hkv, C, hd = 2, 4, 2, 32, 16
+    N, H, Hkv, C, hd = 2, 4, 2, 256, 16
     q, k, v = _rand(N, H, Hkv, C, hd, seed=3)
-    pos = jnp.array([6, 30], jnp.int32)
+    pos = jnp.array([6, 135], jnp.int32)
     got = jax.jit(lambda *a: decode_attention_pallas(
-        *a[:-1], window=a[-1], page_len=8, softcap=50.0))(q, k, v, pos,
-                                                          jnp.int32(10))
+        *a[:-1], window=a[-1], page_len=128, softcap=50.0))(q, k, v, pos,
+                                                            jnp.int32(10))
     want = decode_attention_ref(q, k, v, pos, window=10, softcap=50.0)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL)
 
@@ -75,10 +76,10 @@ def test_kernel_softcap_and_traced_window():
 def test_unwritten_slots_fully_masked():
     """A slot at position 0 attends only to its own just-written token even
     when the rest of the ring holds garbage."""
-    N, H, Hkv, C, hd = 2, 2, 2, 16, 8
+    N, H, Hkv, C, hd = 2, 2, 2, 256, 8
     q, k, v = _rand(N, H, Hkv, C, hd, seed=4)
     pos = jnp.array([0, 0], jnp.int32)
-    got = decode_attention_pallas(q, k, v, pos, page_len=8)
+    got = decode_attention_pallas(q, k, v, pos, page_len=128)
     # only index 0 is valid -> output is exactly v[:, 0]
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(v[:, 0].astype(got.dtype)),
@@ -90,15 +91,15 @@ def test_kernel_int8_matches_dequantized_ref(dt):
     """Kernel vs oracle on the SAME int8 cache + scales: both dequantize
     page-by-page with one rounding into the compute dtype, so the bound
     stays as tight as the bf16 case.  Positions cross >= 2 page
-    boundaries (page_len=8, pos up to 41)."""
+    boundaries (page_len=128, pos up to 330)."""
     from repro.quant import quantize_kv
 
-    N, H, Hkv, C, hd = 3, 4, 2, 48, 16
+    N, H, Hkv, C, hd = 3, 4, 2, 384, 16
     q, k, v = _rand(N, H, Hkv, C, hd, seed=5)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    pos = jnp.array([17, 41, 30], jnp.int32)
-    got = decode_attention_pallas(q.astype(dt), kq, vq, pos, page_len=8,
+    pos = jnp.array([170, 330, 250], jnp.int32)
+    got = decode_attention_pallas(q.astype(dt), kq, vq, pos, page_len=128,
                                   k_scale=ks, v_scale=vs)
     want = decode_attention_ref(q.astype(dt), kq, vq, pos, k_scale=ks,
                                 v_scale=vs)
@@ -114,12 +115,12 @@ def test_kernel_int8_parity_vs_unquantized_oracle(dt):
     acceptance bound), again crossing multiple page boundaries."""
     from repro.quant import quantize_kv
 
-    N, H, Hkv, C, hd = 2, 4, 2, 64, 32
+    N, H, Hkv, C, hd = 2, 4, 2, 256, 32
     q, k, v = _rand(N, H, Hkv, C, hd, seed=6)
     kq, ks = quantize_kv(k)
     vq, vs = quantize_kv(v)
-    pos = jnp.array([63, 37], jnp.int32)
-    got = decode_attention_pallas(q.astype(dt), kq, vq, pos, page_len=16,
+    pos = jnp.array([255, 150], jnp.int32)
+    got = decode_attention_pallas(q.astype(dt), kq, vq, pos, page_len=128,
                                   k_scale=ks, v_scale=vs)
     want = decode_attention_ref(q.astype(dt), k.astype(dt), v.astype(dt),
                                 pos)

@@ -234,3 +234,18 @@ def test_layout_manifest_is_json_ready():
     assert "shards" in man and man["block"] == 256
     assert man["n_params"] == sum(s["used"] for s in man["shards"])
     assert json.loads(txt) == man
+
+
+@pytest.mark.parametrize("optimizer,hypers", [
+    ("sophia_g", SOPHIA_HYPERS),
+    ("adamw", dict(beta1=0.9, beta2=0.95, eps=1e-8, weight_decay=0.1)),
+])
+def test_init_state_buffers_are_distinct(optimizer, hypers):
+    """The train step donates its state; on a TPU a buffer that backs two
+    leaves (m and h once shared one zeros tuple) is donated twice and the
+    step refuses to run.  Every state leaf owns its buffer."""
+    p = _params(jax.random.PRNGKey(0))
+    p["half"] = jnp.arange(37, dtype=jnp.bfloat16)
+    state = OptimizerEngine(optimizer, hypers=hypers).init(p)
+    ptrs = [x.unsafe_buffer_pointer() for x in jax.tree.leaves(state)]
+    assert len(ptrs) == len(set(ptrs))

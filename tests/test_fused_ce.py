@@ -56,9 +56,26 @@ def test_fused_matches_ref_loss_and_grads(dtype, tied, softcap):
         softcap=softcap)
     np.testing.assert_allclose(float(loss), float(loss_r), atol=TOL)
     assert dh.dtype == hidden.dtype and dw.dtype == w.dtype
-    np.testing.assert_allclose(np.asarray(dh, np.float32),
-                               np.asarray(dh_r, np.float32), atol=TOL)
+    dh32, dh_r32 = np.asarray(dh, np.float32), np.asarray(dh_r, np.float32)
+    if dtype == jnp.bfloat16:
+        # Kernel and oracle each round an fp32 d_hidden into bf16 once, but
+        # sum it in a different order; fp32 values a few 1e-7 apart can
+        # straddle a bf16 rounding boundary and land one bf16 ulp apart
+        # (1.5e-5 at |dh| ~ 2e-3, above TOL).  So bf16 outputs are held to
+        # one bf16 ulp of the reference, never looser than TOL.
+        tol = np.maximum(TOL, _bf16_ulp(dh_r32))
+        assert np.all(np.abs(dh32 - dh_r32) <= tol), \
+            np.max(np.abs(dh32 - dh_r32) - tol)
+    else:
+        np.testing.assert_allclose(dh32, dh_r32, atol=TOL)
     np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_r), atol=TOL)
+
+
+def _bf16_ulp(x):
+    """One bf16 ulp (8-bit significand) at each element of fp32 ``x``."""
+    a = np.abs(x)
+    e = np.floor(np.log2(np.where(a > 0, a, 1.0)))
+    return np.where(a > 0, 2.0 ** (e - 7), 0.0)
 
 
 def test_bf16_weights_accumulate_dw_in_fp32():
@@ -368,3 +385,63 @@ def test_hbm_bytes_model_v_independence():
     assert f2 - f1 == w_delta
     assert u2 - u1 > 5 * N * (256_000 - 32_000) * 4 * 0.99
     assert f1 < u1 and f2 < u2
+
+
+def test_per_device_kernels_match_unsharded():
+    """Under an activation mesh every kernel entry point runs per device on
+    its batch shard (XLA cannot partition a Mosaic kernel).  The sharded
+    loss, its gradients and the in-sweep GNB samples (noise keyed on
+    *global* row indices) must match the unsharded call; so must flash
+    attention and its gradients.  Runs in a subprocess with 4 host
+    devices on a 2x2 (data, model) mesh."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    prog = textwrap.dedent(f"""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import sys
+        sys.path.insert(0, {json.dumps(src)})
+        import jax, jax.numpy as jnp, numpy as np
+        from repro.distributed.sharding import set_activation_mesh
+        from repro.kernels.flash_attention import flash_attention
+        from repro.kernels.fused_ce import (fused_lm_loss,
+                                            fused_lm_loss_sampled)
+        from repro.launch.mesh import make_mesh
+
+        ks = jax.random.split(jax.random.PRNGKey(0), 5)
+        h = jax.random.normal(ks[0], (4, 12, 32))
+        w = jax.random.normal(ks[1], (256, 32)) * 0.2
+        lab = jax.random.randint(ks[2], (4, 12), 0, 200)
+        mask = (jax.random.uniform(ks[3], (4, 12)) > 0.3).astype(jnp.float32)
+        q = jax.random.normal(ks[4], (4, 2, 32, 16))
+        kw = dict(vocab_size=200, block_n=16, block_v=128)
+
+        def losses(h, w, q):
+            a = fused_lm_loss(h, w, lab, mask, **kw)[0]
+            b = fused_lm_loss_sampled(h, w, jax.random.PRNGKey(7), mask,
+                                      **kw)[0]
+            c = flash_attention(q, q, q).sum()
+            return a + 2.0 * b + 3.0 * c, (a, b, c)
+
+        f = jax.jit(jax.value_and_grad(losses, argnums=(0, 1, 2),
+                                       has_aux=True))
+        (_, ref), gref = f(h, w, q)
+        set_activation_mesh(make_mesh((2, 2), ("data", "model")))
+        f = jax.jit(jax.value_and_grad(losses, argnums=(0, 1, 2),
+                                       has_aux=True))
+        (_, got), g = f(h, w, q)
+        for x, y in zip(got + g, ref + gref):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       atol=3e-6, rtol=3e-6)
+        print("PER_DEVICE_OK")
+    """)
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, timeout=600, env=env)
+    assert "PER_DEVICE_OK" in r.stdout, (r.stdout[-800:], r.stderr[-3000:])

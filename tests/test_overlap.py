@@ -285,10 +285,60 @@ def test_run_resumable_reraises_node_loss():
 
 
 def test_latency_hiding_flags_platform_keyed():
-    from repro.launch.mesh import latency_hiding_flags
+    """The overlap scheduler options are keyed on the mesh: only a TPU mesh
+    of more than one device gets them (as per-compile options, which the
+    TPU compiler validates; tests/test_tpu_compile.py compiles with them)."""
+    import types
 
-    assert latency_hiding_flags("cpu") == ()
-    assert all(f.startswith("--xla_tpu") or f.startswith("--xla_")
-               for f in latency_hiding_flags("tpu"))
-    assert latency_hiding_flags("tpu")
-    assert latency_hiding_flags("gpu")
+    import numpy as np
+
+    from repro.launch.mesh import TPU_OVERLAP_OPTIONS, overlap_compiler_options
+
+    def fake_mesh(platform, n):
+        dev = types.SimpleNamespace(platform=platform)
+        return types.SimpleNamespace(devices=np.asarray([dev] * n))
+
+    assert overlap_compiler_options(None) == {}
+    assert overlap_compiler_options(fake_mesh("cpu", 8)) == {}
+    assert overlap_compiler_options(fake_mesh("tpu", 1)) == {}
+    opts = overlap_compiler_options(fake_mesh("tpu", 4))
+    assert opts == TPU_OVERLAP_OPTIONS
+    assert opts and all(k.startswith("xla_tpu_") for k in opts)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """launch/mesh.enable_compile_cache: $JAX_COMPILATION_CACHE_DIR, when
+    set, holds the cache and the in-checkout default stays empty; unset,
+    the cache lands in the fixed default directory.  Runs in a subprocess
+    (the cache is process-global once a compile has used it)."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    env_dir, default_dir = tmp_path / "env", tmp_path / "default"
+    env_dir.mkdir()
+    default_dir.mkdir()
+    prog = textwrap.dedent(f"""
+        from pathlib import Path
+        import jax, jax.numpy as jnp
+        from repro.launch import mesh
+        mesh.DEFAULT_COMPILE_CACHE = Path({str(default_dir)!r})
+        print(mesh.enable_compile_cache())
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.jit(lambda x: jnp.sin(x) * 2)(jnp.ones(8)).block_until_ready()
+    """)
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, r.stderr[-2000:]
+    used, unused = (env_dir, default_dir) if from_env else (default_dir,
+                                                            env_dir)
+    assert r.stdout.splitlines()[0] == str(used)
+    assert any(used.iterdir())
+    assert not any(unused.iterdir())

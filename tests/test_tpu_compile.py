@@ -1,0 +1,255 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e chip.
+
+Interpret mode (every other kernel test) cannot show that a kernel meets
+the TPU's tiling and VMEM rules; the TPU compiler, which is installed even
+where no chip is attached, can.  Each test compiles one kernel family at
+``gpt2-small`` widths (d=768, 12 heads of 64, vocab 50304, seq 1024,
+batch 8) with the block sizes the autotuners pick for a v5e, and asserts
+the compiled program holds the Mosaic kernel (``tpu_custom_call``).
+Nothing runs: these are compiles, not chip runs.
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process may load the TPU library), and the persistent compilation
+cache is off while these compiles run (a TPU executable written here could
+not be read back without a chip).
+"""
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from repro.configs import get_config
+from repro.kernels import decode_attention, flash_attention, fused_ce
+from repro.kernels import sophia_update
+from repro.launch import roofline
+
+CFG = get_config("gpt2-small")
+B, S = 8, 1024
+H, HD = CFG.n_heads, CFG.hd
+D, VP = CFG.d_model, CFG.padded_vocab
+SOPHIA = dict(beta1=0.96, gamma=0.05, eps=1e-12, weight_decay=0.2,
+              clip_threshold=1.0)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(topo, monkeypatch):
+    """One described v5e chip; the autotuners rank blocks with its peaks
+    (on this host the first local device is the CPU)."""
+    kind = topo.devices[0].device_kind
+    monkeypatch.setattr(roofline, "device_peaks",
+                        lambda k=None: roofline.CHIP_PEAKS[kind])
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    return compiled, text
+
+
+def _n_params():
+    from repro.models import get_model
+    shapes = jax.eval_shape(lambda k: get_model(CFG).init_params(CFG, k),
+                            jax.random.PRNGKey(0))
+    return sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
+
+
+@pytest.mark.parametrize("refresh", [False, True])
+def test_sophia_update_sweep(chip, refresh):
+    block = sophia_update.BLOCK
+    n = -(-_n_params() // block) * block        # the engine's flat shard
+    vec = _spec((n,), jnp.float32, chip)
+    sc = _spec((), jnp.float32, chip)
+    if refresh:
+        def fn(p, m, h, g, e, lr, flag, scale):
+            return sophia_update.sophia_refresh_fused_block(
+                p, m, h, g, e, lr, flag, scale, beta2=0.99, interpret=False,
+                **SOPHIA)
+        compiled, _ = _compile(fn, *[vec] * 5, sc, sc, sc)
+    else:
+        def fn(p, m, h, g, lr):
+            return sophia_update.sophia_fused_block(p, m, h, g, lr,
+                                                    interpret=False, **SOPHIA)
+        compiled, _ = _compile(fn, *[vec] * 4, sc)
+    nclip = compiled.out_info[-1]
+    assert nclip.shape == (n // block,) and nclip.dtype == jnp.int32
+
+
+def _qkv(chip):
+    return [_spec((B, H, S, HD), jnp.bfloat16, chip)] * 3
+
+
+def test_flash_forward(chip):
+    def fn(q, k, v):
+        return flash_attention.flash_attention(q, k, v, interpret=False)
+    _, text = _compile(fn, *_qkv(chip))
+    assert text.count("tpu_custom_call") >= 1
+
+
+def test_flash_backward_dq_dkv(chip):
+    def loss(q, k, v):
+        o = flash_attention.flash_attention(q, k, v, interpret=False)
+        return o.astype(jnp.float32).sum()
+    fused_ce.reset_kernel_calls()
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(chip))
+    calls = fused_ce.kernel_calls()
+    assert calls["attn_fwd"] and calls["attn_bwd_dq"] and \
+        calls["attn_bwd_dkv"]
+
+
+def _ce_args(chip):
+    return (_spec((B, S, D), jnp.bfloat16, chip),       # pre-norm hidden
+            _spec((VP, D), jnp.float32, chip),          # tied embedding
+            _spec((D,), jnp.float32, chip),             # ln scale
+            _spec((D,), jnp.float32, chip))             # ln bias
+
+
+def _ce_kw():
+    return dict(vocab_size=CFG.vocab_size, norm_kind="ln", interpret=False)
+
+
+def test_fused_ce_forward(chip):
+    def fn(h, w, ns, nb, lab):
+        return fused_ce.fused_lm_loss(h, w, lab, norm_scale=ns, norm_bias=nb,
+                                      **_ce_kw())[0]
+    fused_ce.reset_kernel_calls()
+    _compile(fn, *_ce_args(chip), _spec((B, S), jnp.int32, chip))
+    assert fused_ce.kernel_calls()["fwd"]
+
+
+def test_fused_ce_forward_sample(chip):
+    def fn(h, w, ns, nb, key):
+        return fused_ce.fused_lm_loss_sampled(
+            h, w, key, norm_scale=ns, norm_bias=nb, **_ce_kw())[0]
+    fused_ce.reset_kernel_calls()
+    _compile(fn, *_ce_args(chip), _spec((2,), jnp.uint32, chip))
+    assert fused_ce.kernel_calls()["fwd_sample"]
+
+
+def test_fused_ce_backward(chip):
+    def loss(h, w, ns, nb, lab):
+        return fused_ce.fused_lm_loss(h, w, lab, norm_scale=ns, norm_bias=nb,
+                                      **_ce_kw())[0]
+    fused_ce.reset_kernel_calls()
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3)), *_ce_args(chip),
+             _spec((B, S), jnp.int32, chip))
+    calls = fused_ce.kernel_calls()
+    assert any(v for k, v in calls.items() if k.startswith("bwd_"))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_decode_attention(chip, kv_dtype):
+    N, C = 4, 512
+    q = _spec((N, H, HD), jnp.bfloat16, chip)
+    pos = _spec((N,), jnp.int32, chip)
+    if kv_dtype == "int8":
+        kv = _spec((N, C, H, HD), jnp.int8, chip)
+        sc = _spec((N, C), jnp.float32, chip)
+
+        def fn(q, k, v, p, ks, vs):
+            return decode_attention.decode_attention_pallas(
+                q, k, v, p, k_scale=ks, v_scale=vs, interpret=False)
+        _compile(fn, q, kv, kv, pos, sc, sc)
+    else:
+        kv = _spec((N, C, H, HD), jnp.bfloat16, chip)
+
+        def fn(q, k, v, p):
+            return decode_attention.decode_attention_pallas(
+                q, k, v, p, interpret=False)
+        _compile(fn, q, kv, kv, pos)
+
+
+def test_sharded_train_step(topo, monkeypatch):
+    """The training step on a described 2x2 mesh with every Pallas kernel
+    on and the int8 gradient all-reduce: XLA cannot partition a Mosaic
+    kernel, so each must run per device (distributed.sharding.per_device);
+    the multi-chip scheduler options must be ones the TPU compiler knows.
+    The small config keeps the compile short; widths do not change the
+    partitioning."""
+    from repro.distributed.sharding import set_activation_mesh
+    from repro.launch.mesh import overlap_compiler_options
+    from repro.launch.train import build_mesh, compile_train_step
+    from repro.train import TrainerConfig, make_train_fns
+
+    kind = topo.devices[0].device_kind
+    monkeypatch.setattr(roofline, "device_peaks",
+                        lambda k=None: roofline.CHIP_PEAKS[kind])
+    # code that asks for the backend sees this host's CPU: steer the
+    # kernels to compile natively
+    for mod in (fused_ce, flash_attention, decode_attention, sophia_update):
+        monkeypatch.setattr(mod, "_interpret_default", lambda: False)
+    cfg = get_config("gpt2-small", smoke=True)
+    tc = TrainerConfig(optimizer="sophia_g", estimator="gnb", total_steps=4,
+                       warmup_steps=2, hess_interval=2, hess_subbatch=4,
+                       fused_kernel=True, compress_grads=True)
+    mesh = build_mesh(topo.devices)
+    assert overlap_compiler_options(mesh)
+    batch = {"tokens": np.zeros((4, 64), np.int32),
+             "labels": np.zeros((4, 64), np.int32)}
+    state_shape = jax.eval_shape(make_train_fns(cfg, tc)[0],
+                                 jax.random.PRNGKey(0))
+
+    def shaped(tree, shardings):
+        return jax.tree.map(lambda s, sh: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sh), tree, shardings)
+
+    flag = jax.ShapeDtypeStruct((), jnp.bool_,
+                                sharding=NamedSharding(mesh, P()))
+    fused_ce.reset_kernel_calls()
+    try:
+        step, _, ssh, bsh = compile_train_step(cfg, tc, mesh, batch,
+                                               state_shape=state_shape)
+        text = step.lower(shaped(state_shape, ssh),
+                          shaped(jax.eval_shape(lambda b: b, batch), bsh),
+                          flag).compile().as_text()
+    finally:
+        set_activation_mesh(None)
+    assert "tpu_custom_call" in text
+    calls = fused_ce.kernel_calls()
+    for k in ("attn_fwd", "attn_bwd_dq", "attn_bwd_dkv", "fwd",
+              "fwd_sample"):
+        assert calls.get(k), (k, calls)
+
+
+def test_overlap_options_known_to_compiler(topo):
+    """Every scheduler option the launcher passes on a multi-chip TPU mesh
+    is one the installed TPU compiler accepts (an unknown one is a compile
+    error)."""
+    from repro.launch.mesh import overlap_compiler_options
+    mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("data", "model"))
+    opts = overlap_compiler_options(mesh)
+    assert opts
+    x = jax.ShapeDtypeStruct((1024, 512), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data")))
+    compiled = jax.jit(lambda x: (x @ x.T).sum(0),
+                       compiler_options=opts).lower(x).compile()
+    assert "all-reduce" in compiled.as_text() or \
+        "reduce-scatter" in compiled.as_text()
