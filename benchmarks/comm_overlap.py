@@ -7,8 +7,8 @@ complementary views land in BENCH_comm.json:
 **Measured (wall clock, this host).**  Per cell: median step time without
 compression, monolithic, and bucketed, plus the differential
 ``measured step - no-comm step`` per variant, the trainer's in-graph
-telemetry, and a per-bucket TIMELINE from one instrumented step.  Heads
-up when reading these on a CI host: simulated devices share the host's
+telemetry from a few instrumented steps.  Heads up when reading these on
+a CI host: simulated devices share the host's
 cores (often ONE — recorded as ``host_cores``), every collective is a
 serializing shared-memory rendezvous with zero wire time, so bucketing
 can only ever ADD wall time there.  The raw numbers are kept honest, not
@@ -28,8 +28,7 @@ same measured-compute + modeled-wire split as the repo's roofline tier.
 
 Cells (each a subprocess so ``XLA_FLAGS`` device forcing is per-cell):
 
-  * devices — 2 / 4 / 8 simulated devices, three variants per cell; the
-    8-device cell also records the per-bucket timeline;
+  * devices — 2 / 4 / 8 simulated devices, three variants per cell;
   * processes — the same bucketed step as 1 process x 2 devices vs
     2 real ``jax.distributed`` processes (gloo) x 1 device each, with
     step-loss parity between the two.
@@ -57,9 +56,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 OUT_PATH = os.path.join(HERE, "BENCH_comm.json")
 
 #: 7 buckets over the GPT2_TINY 917504-element shard — big enough that a
-#: bucket's collective is not launch-dominated, small enough for a legible
-#: per-bucket timeline.  (The auto roofline chooser targets real ICI
-#: bandwidth and picks monolithic for a model this small.)
+#: bucket's collective is not launch-dominated.  (The auto roofline chooser
+#: targets real ICI bandwidth and picks monolithic for a model this
+#: small.)
 BUCKET_ELEMS = 128 * 1024
 
 STEPS = 12          # per variant; first 2 are compile+warmup, median of rest
@@ -171,20 +170,12 @@ def cell_devices(args):
                     "exposed_mono_frac": em / (t_nocomp + em),
                     "exposed_buck_frac": eb / (t_nocomp + eb)}
 
-    # in-graph telemetry + per-bucket timeline on a short bucketed run
-    from repro.distributed import overlap
+    # in-graph telemetry on a short bucketed run
     run = _train_setup(args.bucket_elems, compress=True, telemetry=True)
-    _, _, tele = run(3)
-    overlap.timeline_enable(True)
-    _, _, tele2 = run(1)
-    timeline = overlap.decode_timeline()
-    overlap.timeline_enable(False)
-    tele += tele2
+    _, _, tele = run(4)
     out["telemetry"] = {
         k: statistics.median(r[k] for r in tele)
         for k in ("comm_seconds", "step_seconds", "exposed_comm_fraction")}
-    if args.timeline:
-        out["timeline"] = timeline
     return out
 
 
@@ -233,11 +224,9 @@ def _spawn_cell(extra, force_devices=0, timeout=1200):
     return _parse_result(p.stdout, p.stderr)
 
 
-def _run_devices_cell(ndev, bucket_elems, steps, timeline=False):
+def _run_devices_cell(ndev, bucket_elems, steps):
     extra = ["--cell", "devices", "--ndev", str(ndev),
              "--bucket-elems", str(bucket_elems), "--steps", str(steps)]
-    if timeline:
-        extra.append("--timeline")
     return _spawn_cell(extra, force_devices=ndev)
 
 
@@ -273,8 +262,7 @@ def measure(full=True):
               "bucket_elems": BUCKET_ELEMS, "steps_per_variant": STEPS,
               "host_cores": os.cpu_count(), "cells": {}}
     for ndev in ((2, 4, 8) if full else (8,)):
-        cell = _run_devices_cell(ndev, BUCKET_ELEMS, STEPS,
-                                 timeline=(ndev == 8))
+        cell = _run_devices_cell(ndev, BUCKET_ELEMS, STEPS)
         result["cells"][str(ndev)] = cell
         m = cell["model"]
         print(f"comm_overlap.devices{ndev},{cell['t_buck_s'] * 1e6:.1f},"
@@ -322,7 +310,6 @@ def main():
     ap.add_argument("--ndev", type=int, default=8)
     ap.add_argument("--bucket-elems", type=int, default=BUCKET_ELEMS)
     ap.add_argument("--steps", type=int, default=STEPS)
-    ap.add_argument("--timeline", action="store_true")
     ap.add_argument("--port", default=None)
     ap.add_argument("--nproc", type=int, default=1)
     ap.add_argument("--rank", type=int, default=0)

@@ -306,6 +306,7 @@ class OptimizerEngine:
 
     # -- the step -----------------------------------------------------------
 
+    @jax.named_scope("optimizer.ravel")
     def ravel_grads(self, params: PyTree,
                     grads: PyTree) -> Tuple[jnp.ndarray, ...]:
         """Grads pytree -> fp32 flat shards in this engine's layout — the
@@ -322,6 +323,7 @@ class OptimizerEngine:
         return self.step_shards(state, params, self.ravel_grads(params, grads),
                                 lr)
 
+    @jax.named_scope("optimizer.update")
     def step_shards(self, state: EngineState, params: PyTree,
                     g_sh: Tuple[jnp.ndarray, ...], lr) -> tuple:
         """:meth:`step` with the gradients already raveled to flat fp32
@@ -470,6 +472,7 @@ class OptimizerEngine:
             return tuple(e.astype(jnp.float32) for e in est)
         return ravel_shards(lay, est, dtype=jnp.float32)
 
+    @jax.named_scope("optimizer.update")
     def step_with_refresh(self, state: EngineState, params: PyTree,
                           g_sh: Tuple[jnp.ndarray, ...], lr, est, scale,
                           do_refresh) -> tuple:

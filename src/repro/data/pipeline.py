@@ -17,10 +17,17 @@ global batch (process_index-strided), matching jax.make_array_from_callback.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Iterator, Optional
 
 import jax
 import numpy as np
+
+
+#: a host span around each batch, recorded when a profile is being taken
+#: (``jax.profiler``), on the clock of the device trace
+_batch_span = functools.partial(jax.profiler.annotate_function,
+                                name="data.batch_at")
 
 
 @dataclasses.dataclass
@@ -49,6 +56,7 @@ class SyntheticLM:
         self.next_tok = rng.integers(0, cfg.vocab_size,
                                      size=(cfg.vocab_size,), dtype=np.int64)
 
+    @_batch_span
     def batch_at(self, step: int) -> dict:
         cfg = self.cfg
         rng = np.random.default_rng((cfg.seed, step))
@@ -73,6 +81,7 @@ class MemmapTokens:
         self.cfg = cfg
         self.data = np.memmap(cfg.path, dtype=dtype, mode="r")
 
+    @_batch_span
     def batch_at(self, step: int) -> dict:
         cfg = self.cfg
         rng = np.random.default_rng((cfg.seed, step))

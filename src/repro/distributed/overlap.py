@@ -63,13 +63,14 @@ enforced purely by dataflow (the stamp consumes a probe of its
 predecessor, the successor consumes the stamp), which is the only ordering
 that is safe under multi-device jit.  ``time.perf_counter`` deltas exceed
 f32 precision, so stamps are (2,) f32 ``[whole_seconds, fraction]`` pairs;
-``delta_seconds`` recombines them.  A process-local ``TIMELINE`` records
-(tag, t) pairs for the per-bucket timeline in ``BENCH_comm.json``.
+``delta_seconds`` recombines them.  On the chip, each bucket's collective
+also sits in a ``comm_shard{i}_bucket{j}`` named scope, so a device
+trace shows the per-bucket timeline.
 """
 from __future__ import annotations
 
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -80,8 +81,7 @@ from ..core.engine import bucket_slices
 from ..quant import _GOLDEN, _as_seed
 
 __all__ = ["allreduce_shards_bucketed", "plan_buckets", "stamp",
-           "delta_seconds", "timeline_enable", "timeline_snapshot",
-           "decode_timeline"]
+           "delta_seconds"]
 
 
 # ---------------------------------------------------------------------------
@@ -125,51 +125,13 @@ def plan_buckets(shard_sizes: Sequence[int], ndev: int, *, block: int = 256,
 # ---------------------------------------------------------------------------
 # host-timestamp telemetry (dataflow-ordered, multi-device safe)
 
-#: process-local (tag, perf_counter_seconds) pairs, appended by stamps when
-#: timeline recording is enabled.  Tags decode via :func:`decode_timeline`.
-TIMELINE: List[Tuple[int, float]] = []
-_TIMELINE_ON = False
-
-_TAG_SHARD = 10000  # tag = shard * 10000 + bucket * 2 + phase(0=pre, 1=post)
-
-
-def timeline_enable(on: bool = True) -> None:
-    """Toggle TIMELINE recording (clears any prior records)."""
-    global _TIMELINE_ON
-    _TIMELINE_ON = bool(on)
-    TIMELINE.clear()
-
-
-def timeline_snapshot() -> List[Tuple[int, float]]:
-    return list(TIMELINE)
-
-
-def decode_timeline(records=None) -> List[Dict[str, Any]]:
-    """TIMELINE records as dicts, times relative to the first record."""
-    records = timeline_snapshot() if records is None else list(records)
-    if not records:
-        return []
-    t0 = min(t for _, t in records)
-    out = []
-    for tag, t in records:
-        shard, rest = divmod(int(tag), _TAG_SHARD)
-        bucket, phase = divmod(rest, 2)
-        out.append({"shard": shard, "bucket": bucket,
-                    "phase": "post" if phase else "pre",
-                    "t_rel_s": t - t0})
-    out.sort(key=lambda r: r["t_rel_s"])
-    return out
-
-
-def _host_stamp(tag, _probe):
+def _host_stamp(_probe):
     t = time.perf_counter()
-    if _TIMELINE_ON:
-        TIMELINE.append((int(tag), float(t)))
     whole = float(int(t))
     return np.asarray([whole, t - whole], np.float32)
 
 
-def stamp(dep: jnp.ndarray, tag: int = 0):
+def stamp(dep: jnp.ndarray):
     """Host timestamp ordered by dataflow: fires after ``dep`` exists.
 
     Returns ``(t, dep')`` where ``t`` is a (2,) f32 ``[whole, frac]``
@@ -182,7 +144,7 @@ def stamp(dep: jnp.ndarray, tag: int = 0):
     probe = (jnp.reshape(dep, (-1,))[0].astype(jnp.float32)
              if dep.size else jnp.float32(0))
     t = io_callback(_host_stamp, jax.ShapeDtypeStruct((2,), jnp.float32),
-                    jnp.int32(tag), probe, ordered=False)
+                    probe, ordered=False)
     dep = dep + (t[0] * 0).astype(dep.dtype)
     return t, dep
 
@@ -209,8 +171,7 @@ def allreduce_shards_bucketed(compressor, g_shards, state, rng, *,
     (buckets run out of program order under the latency-hiding scheduler,
     so min/max over stamps, not first/last) — and ``comm_t0`` is the
     absolute (2,) f32 reference stamp the window is measured from, for
-    correlating with step-level stamps.  Per-bucket stamps additionally
-    land in TIMELINE when recording is on.
+    correlating with step-level stamps.
     """
     if mesh is None:
         from .sharding import activation_mesh
@@ -254,14 +215,14 @@ def allreduce_shards_bucketed(compressor, g_shards, state, rng, *,
                 e_b = e if len(plan) == 1 else e[start:stop]
                 off, stride = start, None
             if telemetry:
-                t0, g_b = stamp(g_b, _TAG_SHARD * i + 2 * j)
+                t0, g_b = stamp(g_b)
                 pre_stamps.append(t0)
             with jax.named_scope(f"comm_shard{i}_bucket{j}"):
                 deq, err = compressor._allreduce_one(g_b, e_b, sseed, mesh,
                                                      axis, offset=off,
                                                      stride=stride)
             if telemetry:
-                t1, deq = stamp(deq, _TAG_SHARD * i + 2 * j + 1)
+                t1, deq = stamp(deq)
                 post_stamps.append(t1)
             if interleave:
                 deq = deq.reshape(ndev, -1)

@@ -69,6 +69,13 @@ INTERPRET_CELL_CAP = 64        # max unrolled grid cells under interpret
 # window may be a traced scalar from the prefetch ref)
 
 
+#: the kernels' names in a compiled program (the Mosaic module's
+#: ``sym_name``, which a profile reader matches); pinned here so that
+#: renaming a kernel function does not rename its ops
+KERNEL_NAMES = FWD_NAME, DQ_NAME, DKV_NAME = (
+    "_fwd_kernel", "_dq_kernel", "_dkv_kernel")
+
+
 def _kv_band(i, win, *, causal, q_offset, block_q, block_k, n_k):
     """Inclusive [lo, hi] range of KV blocks attended by q-block ``i``."""
     if causal:
@@ -209,6 +216,7 @@ def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
             jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=FWD_NAME,
     )(win, q, k, v)
 
 
@@ -341,6 +349,7 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
         kern, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name=DQ_NAME,
     )(win, q, k, v, do, lse, delta)
 
     # --- dK/dV: grid (B, Hkv, n_k, G * n_q), (group, q-block) innermost ---
@@ -383,6 +392,7 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
+        name=DKV_NAME,
     )(win, q, k, v, do, lse, delta)
     return dq, dk, dv
 

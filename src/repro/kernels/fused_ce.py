@@ -496,6 +496,15 @@ def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
 # pallas_call wrappers
 
 
+#: the kernels' names in a compiled program (the Mosaic module's
+#: ``sym_name``, which a profile reader matches); pinned here so that
+#: renaming a kernel function does not rename its ops
+KERNEL_NAMES = (FWD_NAME, FWD_SAMPLE_NAME, BWD_FUSED_NAME, BWD_DH_NAME,
+                BWD_DW_NAME) = ("_ce_fwd_kernel", "_ce_fwd_sample_kernel",
+                                "_ce_bwd_fused_kernel", "_ce_bwd_dh_kernel",
+                                "_ce_bwd_dw_kernel")
+
+
 def _specs(bn, bv, D, transpose_w):
     """Grid (rows, chunks) BlockSpecs.  Per-row vectors (labels, row
     scales, lse, label logits, samples) travel as ``(N, 1)`` columns: a
@@ -540,6 +549,7 @@ def _ce_forward(h2, w, normp, labels, *, vocab, transpose_w, softcap, norm,
                    jax.ShapeDtypeStruct((N, 1), _f32)],
         scratch_shapes=[pltpu.VMEM((bn, 1), _f32)] * 3,
         interpret=interpret,
+        name=FWD_NAME,
     )(_no_normp(D, normp), _col(labels), h2, w)
     return lse[:, 0], ll[:, 0]
 
@@ -569,6 +579,7 @@ def _ce_forward_sampled(h2, w, normp, seed, *, vocab, transpose_w, softcap,
                         pltpu.VMEM((bn, 1), jnp.int32),
                         pltpu.VMEM((bn, 1), _f32)],
         interpret=interpret,
+        name=FWD_SAMPLE_NAME,
     )(_no_normp(D, normp), seed, h2, w)
     return tuple(o[:, 0] for o in outs)
 
@@ -605,6 +616,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
                        jax.ShapeDtypeStruct(w.shape, w.dtype)],
             scratch_shapes=[pltpu.VMEM((bn, D), _f32), dw_scr],
             interpret=interpret,
+            name=BWD_FUSED_NAME,
         )(normp, labels, rs, lse, h2, w)
         return dh, dw
 
@@ -622,6 +634,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
         out_shape=jax.ShapeDtypeStruct((N, D), dh_dtype),
         scratch_shapes=[pltpu.VMEM((bn, D), _f32)],
         interpret=interpret,
+        name=BWD_DH_NAME,
     )(normp, labels, rs, lse, h2, w)
 
     # rows innermost so each dW chunk block accumulates while resident
@@ -642,6 +655,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
         scratch_shapes=[dw_scr],
         interpret=interpret,
+        name=BWD_DW_NAME,
     )(normp, labels, rs, lse, h2, w)
     return dh, dw
 

@@ -41,6 +41,14 @@ BLOCK = 128 * 1024  # elements per VMEM block (fp32: 512 KiB per operand)
 
 _f32 = jnp.float32
 
+#: the sweep kernels' names in a compiled program (the Mosaic module's
+#: ``sym_name``, which a profile reader matches); pinned here so that
+#: renaming a kernel function does not rename its ops
+KERNEL_NAMES = (
+    "_sophia_kernel", "_hess_ema_kernel", "_sophia_refresh_kernel",
+    "_adahessian_refresh_kernel", "_adamw_kernel", "_adahessian_kernel",
+    "_lion_kernel", "_signgd_kernel", "_sgd_kernel")
+
 
 def _grid_spec(block):
     return pl.BlockSpec((block,), lambda i: (i,))
@@ -56,12 +64,14 @@ def _write_nclip(nclip_out, raw, clip_threshold):
     nclip_out[...] = jnp.full(nclip_out.shape, n, jnp.int32)
 
 
-def _sweep(kern, scalars, vecs, out_dtypes, *, block, nclip=False,
+def _sweep(kern, name, scalars, vecs, out_dtypes, *, block, nclip=False,
            interpret=None):
-    """One grid sweep over flat shards (length % block == 0): ``scalars``
-    (fp32, SMEM) first, then the streamed ``vecs``.  Returns flat outputs of
+    """One grid sweep over flat shards (length % block == 0) of the kernel
+    ``kern``, named ``name`` (one of ``KERNEL_NAMES``): ``scalars`` (fp32,
+    SMEM) first, then the streamed ``vecs``.  Returns flat outputs of
     ``out_dtypes`` and, with ``nclip``, the per-block clip counts
     ``(grid,)`` int32."""
+    assert name in KERNEL_NAMES, name
     n = vecs[0].shape[0]
     grid = n // block
     spec = _grid_spec(block)
@@ -80,6 +90,7 @@ def _sweep(kern, scalars, vecs, out_dtypes, *, block, nclip=False,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=_interpret_default() if interpret is None else interpret,
+        name=name,
     )(jnp.stack([jnp.asarray(x, _f32) for x in scalars]), *vecs)
     if nclip:
         outs = list(outs[:-1]) + [outs[-1][:, 0, 0]]
@@ -117,8 +128,9 @@ def sophia_fused_block(p, m, h, g, lr, *, beta1, gamma, eps, weight_decay,
     kern = functools.partial(
         _sophia_kernel, beta1=beta1, gamma=gamma, eps=eps,
         weight_decay=weight_decay, clip_threshold=clip_threshold)
-    return _sweep(kern, (lr,), (p, m, h, g), (p.dtype, m.dtype),
-                  block=block, nclip=True, interpret=interpret)
+    return _sweep(kern, "_sophia_kernel", (lr,), (p, m, h, g),
+                  (p.dtype, m.dtype), block=block, nclip=True,
+                  interpret=interpret)
 
 
 def _hess_ema_kernel(sc_ref, h_ref, e_ref, h_out, *, beta2, square):
@@ -139,8 +151,8 @@ def hessian_ema_block(h, est, *, beta2, scale=1.0, square=False, block=BLOCK,
     the AdaHessian refresh: h' = b2 h + (1-b2) (scale * est)^2.
     """
     kern = functools.partial(_hess_ema_kernel, beta2=beta2, square=square)
-    (h2,) = _sweep(kern, (scale,), (h, est), (h.dtype,), block=block,
-                   interpret=interpret)
+    (h2,) = _sweep(kern, "_hess_ema_kernel", (scale,), (h, est), (h.dtype,),
+                   block=block, interpret=interpret)
     return h2
 
 
@@ -180,9 +192,9 @@ def sophia_refresh_fused_block(p, m, h, g, e, lr, flag, scale, *,
     kern = functools.partial(
         _sophia_refresh_kernel, beta1=beta1, beta2=beta2, gamma=gamma,
         eps=eps, weight_decay=weight_decay, clip_threshold=clip_threshold)
-    return _sweep(kern, (lr, flag, scale), (p, m, h, g, e),
-                  (p.dtype, m.dtype, h.dtype), block=block, nclip=True,
-                  interpret=interpret)
+    return _sweep(kern, "_sophia_refresh_kernel", (lr, flag, scale),
+                  (p, m, h, g, e), (p.dtype, m.dtype, h.dtype), block=block,
+                  nclip=True, interpret=interpret)
 
 
 def _adahessian_refresh_kernel(sc_ref, p_ref, m_ref, v_ref, g_ref, e_ref,
@@ -210,7 +222,8 @@ def adahessian_refresh_fused_block(p, m, v, g, e, lr, flag, scale, step, *,
     bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adahessian_refresh_kernel, beta1=beta1,
                              beta2=beta2, eps=eps, weight_decay=weight_decay)
-    return _sweep(kern, (lr, flag, scale, bc1, bc2), (p, m, v, g, e),
+    return _sweep(kern, "_adahessian_refresh_kernel",
+                  (lr, flag, scale, bc1, bc2), (p, m, v, g, e),
                   (p.dtype, m.dtype, v.dtype), block=block,
                   interpret=interpret)
 
@@ -238,7 +251,7 @@ def adamw_fused_block(p, m, v, g, lr, step, *, beta1, beta2, eps,
     bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adamw_kernel, beta1=beta1, beta2=beta2,
                              eps=eps, weight_decay=weight_decay)
-    return _sweep(kern, (lr, bc1, bc2), (p, m, v, g),
+    return _sweep(kern, "_adamw_kernel", (lr, bc1, bc2), (p, m, v, g),
                   (p.dtype, m.dtype, v.dtype), block=block,
                   interpret=interpret)
 
@@ -259,8 +272,8 @@ def adahessian_fused_block(p, m, v, g, lr, step, *, beta1, beta2, eps,
     bc1, bc2 = _bias_corrections(beta1, beta2, step)
     kern = functools.partial(_adahessian_kernel, beta1=beta1, beta2=beta2,
                              eps=eps, weight_decay=weight_decay)
-    return _sweep(kern, (lr, bc1, bc2), (p, m, v, g), (p.dtype, m.dtype),
-                  block=block, interpret=interpret)
+    return _sweep(kern, "_adahessian_kernel", (lr, bc1, bc2), (p, m, v, g),
+                  (p.dtype, m.dtype), block=block, interpret=interpret)
 
 
 def _lion_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
@@ -278,8 +291,8 @@ def lion_fused_block(p, m, g, lr, *, beta1, beta2, weight_decay, block=BLOCK,
                      interpret=None):
     kern = functools.partial(_lion_kernel, beta1=beta1, beta2=beta2,
                              weight_decay=weight_decay)
-    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
-                  interpret=interpret)
+    return _sweep(kern, "_lion_kernel", (lr,), (p, m, g), (p.dtype, m.dtype),
+                  block=block, interpret=interpret)
 
 
 def _signgd_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *,
@@ -295,8 +308,8 @@ def signgd_fused_block(p, m, g, lr, *, beta1, weight_decay, block=BLOCK,
                        interpret=None):
     kern = functools.partial(_signgd_kernel, beta1=beta1,
                              weight_decay=weight_decay)
-    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
-                  interpret=interpret)
+    return _sweep(kern, "_signgd_kernel", (lr,), (p, m, g), (p.dtype, m.dtype),
+                  block=block, interpret=interpret)
 
 
 def _sgd_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *, momentum):
@@ -308,5 +321,5 @@ def _sgd_kernel(sc_ref, p_ref, m_ref, g_ref, p_out, m_out, *, momentum):
 
 def sgd_fused_block(p, m, g, lr, *, momentum, block=BLOCK, interpret=None):
     kern = functools.partial(_sgd_kernel, momentum=momentum)
-    return _sweep(kern, (lr,), (p, m, g), (p.dtype, m.dtype), block=block,
-                  interpret=interpret)
+    return _sweep(kern, "_sgd_kernel", (lr,), (p, m, g), (p.dtype, m.dtype),
+                  block=block, interpret=interpret)

@@ -23,6 +23,14 @@ auto-resume, preemption-safe exit, and an elastic retry loop: every attempt
 rebuilds the mesh from the *surviving* device set and re-shards the latest
 checkpoint onto it (checkpoint -> shrink mesh -> resume), so node loss or a
 persistent straggler degrades capacity instead of killing the run.
+
+Profiling: ``--profile-dir DIR --profile-steps A:B`` writes a
+``jax.profiler`` trace of steps A..B-1.  Each step is a ``train`` step
+event holding the host spans ``data.batch_at``, ``train.put_batch``,
+``train.dispatch`` and ``train.wait``, on the clock of the device ops, which
+carry the step's layer scopes (``model.*``, ``loss.lm``, ``train.grad_clip``,
+``optimizer.*``, ``sophia.estimate``) in their HLO metadata.  A log line
+names any step that compiled again ("recompiled at step t").
 """
 from __future__ import annotations
 
@@ -134,6 +142,60 @@ def compile_train_step(cfg, tc, mesh, sample_batch, state_shape=None):
             init_fn, ssh, bsh)
 
 
+def _step_range(text):
+    """``A:B`` -> the steps ``range(A, B)``."""
+    a, b = (int(x) for x in text.split(":"))
+    if not 0 <= a < b:
+        raise argparse.ArgumentTypeError(f"need 0 <= A < B, got {text!r}")
+    return range(a, b)
+
+
+class _Profiler:
+    """A ``jax.profiler`` trace of the steps in ``steps`` into ``log_dir``
+    (host spans and device ops; no Python tracer, which would record every
+    call of the host's loops and slow them)."""
+
+    def __init__(self, log_dir, steps):
+        self.log_dir, self.steps, self.on = log_dir, steps, False
+
+    def before(self, t):
+        if self.log_dir and t == self.steps.start:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(self.log_dir, profiler_options=opts)
+            self.on = True
+
+    def after(self, t):
+        if self.on and t + 1 >= self.steps.stop:
+            self.stop()
+
+    def stop(self):
+        if self.on:
+            jax.profiler.stop_trace()
+            self.on = False
+
+
+class _CompileCounter:
+    """Counts the backend compiles of this process (``jax.monitoring``), so
+    that the loop can name a step that compiled again."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n = 0
+
+    def __call__(self, name, secs, **kw):
+        if name == self.EVENT:
+            self.n += 1
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt2-small", choices=list(ARCHS))
@@ -207,6 +269,14 @@ def main(argv=None):
                          "flags, checkpoint, halve the device set, and "
                          "resume on the smaller mesh (0 = off)")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--profile-dir", default=None,
+                    help="write a jax.profiler trace (.xplane.pb: device "
+                         "ops named by the step's layers, host spans per "
+                         "step) of --profile-steps into this directory")
+    ap.add_argument("--profile-steps", type=_step_range,
+                    default=range(2, 5), metavar="A:B",
+                    help="the steps A..B-1 to profile (default 2:5, after "
+                         "the first step's compile)")
     args = ap.parse_args(argv)
 
     # MUST precede every jax device query: distributed init after a device
@@ -332,33 +402,58 @@ def main(argv=None):
     # silently skipped its refresh cadence)
     needs_hess = engine.hessian_aware
 
+    def dispatch_and_wait(state, batch, flag, t):
+        try:
+            with jax.profiler.TraceAnnotation("train.dispatch"):
+                state, metrics = ctx["sjit"](state, batch, flag)
+            # dispatch is asynchronous: wait, so the step time (and the
+            # straggler detector) see device time, and a failed
+            # execution raises inside this handler
+            with jax.profiler.TraceAnnotation("train.wait"):
+                jax.block_until_ready(metrics["loss"])
+        except Exception as e:
+            if jax.process_count() > 1 and is_distributed_failure(e):
+                # a peer died: unrecoverable in-process — propagate as
+                # NodeLoss so run_resumable exits instead of retrying
+                # into a hang; the relauncher resumes the survivors
+                # from the last manifest
+                raise NodeLoss(
+                    f"distributed failure at step {t}: {e}") from e
+            raise
+        return state, metrics
+
     def run(state, start):
+        profiler = _Profiler(args.profile_dir, args.profile_steps)
+        try:
+            with _CompileCounter() as compiles:
+                return loop(state, start, profiler, compiles)
+        finally:
+            profiler.stop()
+
+    def loop(state, start, profiler, compiles):
         straggler = StragglerDetector()
         t_start = time.time()
+        recompiled = []
         for t in range(start, args.steps):
-            t0 = time.time()
-            # every process computes the identical global batch (stateless
-            # deterministic source) and contributes its addressable slices
-            batch = _put_tree(
-                {k: jnp.asarray(v) for k, v in src.batch_at(t).items()},
-                ctx["bsh"])
-            flag = jnp.asarray(needs_hess and t % tc.hess_interval == 0)
-            try:
-                state, metrics = ctx["sjit"](state, batch, flag)
-                # dispatch is asynchronous: wait, so the step time (and the
-                # straggler detector) see device time, and a failed
-                # execution raises inside this handler
-                jax.block_until_ready(metrics["loss"])
-            except Exception as e:
-                if jax.process_count() > 1 and is_distributed_failure(e):
-                    # a peer died: unrecoverable in-process — propagate as
-                    # NodeLoss so run_resumable exits instead of retrying
-                    # into a hang; the relauncher resumes the survivors
-                    # from the last manifest
-                    raise NodeLoss(
-                        f"distributed failure at step {t}: {e}") from e
-                raise
+            profiler.before(t)
+            n_compiles = compiles.n
+            with jax.profiler.StepTraceAnnotation("train", step_num=t):
+                t0 = time.time()
+                # every process computes the identical global batch
+                # (stateless deterministic source) and contributes its
+                # addressable slices
+                host = src.batch_at(t)
+                with jax.profiler.TraceAnnotation("train.put_batch"):
+                    batch = _put_tree({k: jnp.asarray(v)
+                                       for k, v in host.items()},
+                                      ctx["bsh"])
+                    flag = jnp.asarray(needs_hess
+                                       and t % tc.hess_interval == 0)
+                state, metrics = dispatch_and_wait(state, batch, flag, t)
             dt = time.time() - t0
+            profiler.after(t)
+            if t > start and compiles.n > n_compiles:
+                recompiled.append(t)
             if straggler.observe(dt):
                 if p0:
                     print(f"[straggler] step {t} took {dt:.2f}s "
@@ -383,9 +478,13 @@ def main(argv=None):
                     cs = float(metrics["comm_seconds"]) * 1e3
                     cf = float(metrics["exposed_comm_fraction"]) * 100
                     comm = f" comm {cs:.1f}ms ({cf:.0f}% of step)"
+                again = (f" recompiled at step "
+                         f"{', '.join(map(str, recompiled))}"
+                         if recompiled else "")
+                recompiled.clear()
                 print(f"step {t:6d} loss {loss:.4f} "
                       f"gnorm {float(metrics['grad_norm']):.3f} "
-                      f"{dt * 1e3:.0f}ms{comm}", flush=True)
+                      f"{dt * 1e3:.0f}ms{comm}{again}", flush=True)
             if args.ckpt_dir and (t + 1) % args.ckpt_every == 0:
                 ckpt.save(args.ckpt_dir, t + 1, state, async_=True,
                           extra=layout_meta)

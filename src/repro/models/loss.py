@@ -145,6 +145,7 @@ def _chunked_sweep(cfg: ModelConfig, hidden, w, transpose_w, labels=None,
     return m + jnp.log(jnp.maximum(l, 1e-37)), ll, zi
 
 
+@jax.named_scope("loss.lm")
 def lm_loss(cfg: ModelConfig, params, hidden, labels, mask=None, *,
             impl=None, pre_norm=None):
     """Masked-mean LM cross-entropy from final hidden states.
@@ -177,6 +178,7 @@ def lm_loss(cfg: ModelConfig, params, hidden, labels, mask=None, *,
     return jnp.sum(rs * (lse - ll)), n_valid
 
 
+@jax.named_scope("loss.lm")
 def lm_loss_sampled(cfg: ModelConfig, params, hidden, rng, mask=None, *,
                     impl=None, pre_norm=None):
     """GNB sampled-label CE (Algorithm 2 lines 3-5) from hidden states:

@@ -126,11 +126,20 @@ def layer_scales(cfg: ModelConfig) -> jnp.ndarray:
 # forward
 
 
+# Each layer of the train step sits in a ``jax.named_scope`` (``model.*``
+# here, ``loss.lm``, ``train.grad_clip``, ``optimizer.*`` and
+# ``sophia.estimate`` around it), which XLA keeps in the ``op_name`` of every
+# instruction traced under it, backward and recomputed copies included: a
+# profile attributes each device op to its layer by these names.
+
+
+@jax.named_scope("model.attn")
 def _attn_dispatch(p, x, cfg, positions, window, scale, attn_impl):
     return train_attention(p, x, cfg, positions, window=window,
                            layer_scale=scale, impl=attn_impl)
 
 
+@jax.named_scope("model.block")
 def _dense_block(p, x, cfg, positions, window, scale, attn_impl):
     h = _norm(p["ln1"], x, cfg)
     a = _attn_dispatch(p["attn"], h, cfg, positions, window, scale, attn_impl)
@@ -138,13 +147,15 @@ def _dense_block(p, x, cfg, positions, window, scale, attn_impl):
         a = _norm(p["ln1_post"], a, cfg)
     x = x + a
     h = _norm(p["ln2"], x, cfg)
-    f = mlp(p["mlp"], h, cfg)
+    with jax.named_scope("model.mlp"):
+        f = mlp(p["mlp"], h, cfg)
     if cfg.post_norms:
         f = _norm(p["ln2_post"], f, cfg)
     from ..distributed.sharding import constrain, residual_axes
     return constrain(x + f, *residual_axes())
 
 
+@jax.named_scope("model.block")
 def _moe_block(p, x, cfg, positions, window, scale, attn_impl):
     h = _norm(p["ln1"], x, cfg)
     a = _attn_dispatch(p["attn"], h, cfg, positions, window, scale, attn_impl)
@@ -152,13 +163,15 @@ def _moe_block(p, x, cfg, positions, window, scale, attn_impl):
         a = _norm(p["ln1_post"], a, cfg)
     x = x + a
     h = _norm(p["ln2"], x, cfg)
-    f, aux = moe_ffn(p["moe"], h, cfg)
+    with jax.named_scope("model.mlp"):
+        f, aux = moe_ffn(p["moe"], h, cfg)
     if cfg.post_norms:
         f = _norm(p["ln2_post"], f, cfg)
     from ..distributed.sharding import constrain, residual_axes
     return constrain(x + f, *residual_axes()), aux
 
 
+@jax.named_scope("model.embed")
 def _embed_inputs(cfg, params, tokens, positions, patch_embeds):
     B, S = tokens.shape
     if positions is None:
@@ -217,6 +230,15 @@ def forward_hidden(cfg: ModelConfig, params, tokens, *, positions=None,
             body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
 
     n = n_scan_groups(cfg)
+    with jax.named_scope("model.layers"):
+        x, aux = _layer_scan(body, x, params, windows, scales, n, remat)
+    if final_norm:
+        x = _norm(params["final_norm"], x, cfg)
+    return x, aux
+
+
+def _layer_scan(body, x, params, windows, scales, n, remat):
+    """The scan of ``body`` over the stacked layers: -> (x, aux)."""
     if remat == "scan2" and n >= 4:
         # nested-scan remat: the OUTER scan checkpoints every g-th carry
         # (long-lived residuals shrink g x); the INNER body is checkpointed
@@ -236,8 +258,6 @@ def forward_hidden(cfg: ModelConfig, params, tokens, *, positions=None,
     else:
         (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
                                    (params["layers"], windows, scales))
-    if final_norm:
-        x = _norm(params["final_norm"], x, cfg)
     return x, aux
 
 

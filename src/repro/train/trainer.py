@@ -303,12 +303,13 @@ def make_train_fns(cfg: ModelConfig, tc: TrainerConfig):
             # back in, so forward compute provably follows the stamp
             from ..distributed import overlap as _ov
             leaves, treedef = jax.tree.flatten(batch)
-            t_step0, l0 = _ov.stamp(leaves[0], 0)
+            t_step0, l0 = _ov.stamp(leaves[0])
             batch = jax.tree.unflatten(treedef, [l0] + leaves[1:])
         loss, metrics, grads = _accum_grads(loss_fn, state.params, batch,
                                             tc.grad_accum)
         metrics = {"loss": loss, **metrics}
-        grads, clip_state = clipper.update(grads, state.clip_state)
+        with jax.named_scope("train.grad_clip"):
+            grads, clip_state = clipper.update(grads, state.clip_state)
         g_sh = engine.ravel_grads(state.params, grads)
         comp_state = state.comp_state
         comm_tele = None
@@ -335,9 +336,10 @@ def make_train_fns(cfg: ModelConfig, tc: TrainerConfig):
             # refresh flag is constant True, so the kernel's select folds
             # away and the fused sweep still touches h exactly once
             def _refresh_step():
-                est_sh, scale = _estimate_flat(
-                    state.params, batch, _fold_rng(state, RNG_TAG_HESS),
-                    _fold_rng(state, RNG_TAG_HESS_COMPRESS))
+                with jax.named_scope("sophia.estimate"):
+                    est_sh, scale = _estimate_flat(
+                        state.params, batch, _fold_rng(state, RNG_TAG_HESS),
+                        _fold_rng(state, RNG_TAG_HESS_COMPRESS))
                 return engine.step_with_refresh(
                     state.opt_state, state.params, g_sh, lr, est_sh,
                     jnp.asarray(scale, jnp.float32), True)
@@ -365,7 +367,7 @@ def make_train_fns(cfg: ModelConfig, tc: TrainerConfig):
             # an upper bound on exposed comm, exact when nothing overlaps;
             # the differential measurement lives in benchmarks/comm_overlap
             from ..distributed import overlap as _ov
-            t_step1, _ = _ov.stamp(jax.tree.leaves(params)[0], 1)
+            t_step1, _ = _ov.stamp(jax.tree.leaves(params)[0])
             step_s = _ov.delta_seconds(t_step0, t_step1)
             comm_s = (comm_tele["comm_seconds"] if comm_tele is not None
                       else jnp.float32(0))

@@ -20,8 +20,7 @@ from hypothesis_compat import given, settings, st
 from repro.core.engine import bucket_slices, build_layout
 from repro.distributed.compression import FlatCompressionState, GradCompressor
 from repro.distributed.overlap import (allreduce_shards_bucketed,
-                                       decode_timeline, delta_seconds,
-                                       plan_buckets, stamp, timeline_enable)
+                                       delta_seconds, plan_buckets, stamp)
 from repro.launch.roofline import choose_bucket_elems, ring_collective_seconds
 
 
@@ -177,23 +176,23 @@ def test_layout_bucket_slices_method():
 
 
 def test_stamp_orders_by_dataflow_and_measures():
-    timeline_enable(True)
-    try:
-        def fn(x):
-            t0, x = stamp(x, 0)
-            y = x * 2.0
-            t1, y = stamp(y, 1)
-            return y, delta_seconds(t0, t1)
+    def fn(x):
+        t0, x = stamp(x)
+        y = x * 2.0
+        t1, y = stamp(y)
+        return y, t0, t1, delta_seconds(t0, t1)
 
-        y, dt = jax.jit(fn)(jnp.arange(8.0))
-        jax.block_until_ready(y)
-        np.testing.assert_array_equal(np.asarray(y), np.arange(8.0) * 2)
-        assert float(dt) >= 0.0
-        recs = decode_timeline()
-        assert [r["bucket"] for r in recs] == [0, 0]  # tags 0 then 1
-        assert recs[0]["phase"] == "pre" and recs[1]["phase"] == "post"
-    finally:
-        timeline_enable(False)
+    y, t0, t1, dt = jax.jit(fn)(jnp.arange(8.0))
+    jax.block_until_ready(y)
+    np.testing.assert_array_equal(np.asarray(y), np.arange(8.0) * 2)
+    # the stamp on the product fires after the one on its input: its
+    # [whole, fraction] pair is the later time, by the returned delta
+    t0, t1 = np.asarray(t0, np.float64), np.asarray(t1, np.float64)
+    assert t0.shape == t1.shape == (2,)
+    assert 0.0 <= t0[1] < 1.0 and 0.0 <= t1[1] < 1.0
+    assert t1.sum() >= t0.sum()
+    assert float(dt) >= 0.0
+    assert float(dt) == pytest.approx(t1.sum() - t0.sum(), abs=1e-4)
 
 
 def test_allreduce_telemetry_returns_window_and_keeps_values():

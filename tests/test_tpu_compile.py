@@ -253,3 +253,84 @@ def test_overlap_options_known_to_compiler(topo):
                        compiler_options=opts).lower(x).compile()
     assert "all-reduce" in compiled.as_text() or \
         "reduce-scatter" in compiled.as_text()
+
+
+def _flash_programs(chip):
+    qkv = [_spec((1, 2, 256, 64), jnp.bfloat16, chip)] * 3
+
+    def loss(q, k, v):
+        o = flash_attention.flash_attention(q, k, v, interpret=False)
+        return o.astype(jnp.float32).sum()
+    yield jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv)
+
+
+def _fused_ce_programs(chip):
+    h = _spec((2, 128, 128), jnp.bfloat16, chip)
+    w = _spec((512, 128), jnp.float32, chip)
+    lab = _spec((2, 128), jnp.int32, chip)
+    kw = dict(vocab_size=500, block_v=256, interpret=False)
+    for schedule, block_n in (("fused", 256), ("split", 128)):
+        def loss(h, w, lab, schedule=schedule, block_n=block_n):
+            return fused_ce.fused_lm_loss(h, w, lab, schedule=schedule,
+                                          block_n=block_n, **kw)[0]
+        yield jax.jit(jax.grad(loss, argnums=(0, 1))).lower(h, w, lab)
+
+    def sampled(h, w, key):
+        return fused_ce.fused_lm_loss_sampled(h, w, key, block_n=128,
+                                              **kw)[0]
+    yield jax.jit(sampled).lower(h, w, _spec((2,), jnp.uint32, chip))
+
+
+def _sophia_update_programs(chip):
+    n = sophia_update.BLOCK
+    v = _spec((n,), jnp.float32, chip)
+    s = _spec((), jnp.float32, chip)
+    hp = dict(beta1=0.9, beta2=0.99, eps=1e-8, weight_decay=0.1)
+    su = sophia_update
+    calls = [
+        (lambda p, m, h, g, lr: su.sophia_fused_block(
+            p, m, h, g, lr, interpret=False, **SOPHIA), (v, v, v, v, s)),
+        (lambda h, e, sc: su.hessian_ema_block(
+            h, e, beta2=0.99, scale=sc, interpret=False), (v, v, s)),
+        (lambda p, m, h, g, e, lr, f, sc: su.sophia_refresh_fused_block(
+            p, m, h, g, e, lr, f, sc, beta2=0.99, interpret=False, **SOPHIA),
+         (v,) * 5 + (s,) * 3),
+        (lambda p, m, h, g, e, lr, f, sc, t:
+         su.adahessian_refresh_fused_block(p, m, h, g, e, lr, f, sc, t,
+                                           interpret=False, **hp),
+         (v,) * 5 + (s,) * 4),
+        (lambda p, m, h, g, lr, t: su.adamw_fused_block(
+            p, m, h, g, lr, t, interpret=False, **hp), (v,) * 4 + (s,) * 2),
+        (lambda p, m, h, g, lr, t: su.adahessian_fused_block(
+            p, m, h, g, lr, t, interpret=False, **hp), (v,) * 4 + (s,) * 2),
+        (lambda p, m, g, lr: su.lion_fused_block(
+            p, m, g, lr, beta1=0.9, beta2=0.99, weight_decay=0.1,
+            interpret=False), (v,) * 3 + (s,)),
+        (lambda p, m, g, lr: su.signgd_fused_block(
+            p, m, g, lr, beta1=0.9, weight_decay=0.1, interpret=False),
+         (v,) * 3 + (s,)),
+        (lambda p, m, g, lr: su.sgd_fused_block(
+            p, m, g, lr, momentum=0.9, interpret=False), (v,) * 3 + (s,)),
+    ]
+    for fn, args in calls:
+        yield jax.jit(fn).lower(*args)
+
+
+@pytest.mark.parametrize("module,programs,readers", [
+    (flash_attention, _flash_programs, ("flash_attention_roofline",)),
+    (fused_ce, _fused_ce_programs, ("fused_ce_roofline",)),
+    (sophia_update, _sophia_update_programs, ("optimizer_update_roofline",)),
+], ids=["flash_attention", "fused_ce", "sophia_update"])
+def test_kernel_names_are_pinned(chip, module, programs, readers):
+    """Every kernel of a kernel module compiles to a Mosaic module named
+    as its ``KERNEL_NAMES`` say (what the benchmark's trace reduction
+    reads, ``bench/trace.kernel_names``), and each roofline reader of the
+    benchmark looks for names among them."""
+    from bench import common, trace
+    names = set()
+    for lowered in programs(chip):
+        names |= set(trace.kernel_names(lowered.compile().as_text())
+                     .values())
+    assert names == set(module.KERNEL_NAMES)
+    for reader in readers:
+        assert set(common.metric_reader(reader).KERNELS) <= names, reader
