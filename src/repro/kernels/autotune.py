@@ -371,15 +371,15 @@ def tune_shape(N: int, D: int, Vp: int, *, dtype="float32",
 # flash-attention tuning (kernels/flash_attention.py)
 #
 # Same three-stage design as the CE tuner: divisor candidates filtered by a
-# VMEM working-set budget, the analytic roofline (with an exact causal
-# block-band count, since the "skip" schedule prunes out-of-band cells),
-# optional measured refinement, and a separate persistent cache (only
-# measured winners are written).  Keys deliberately exclude the sliding
+# VMEM working-set budget, the analytic roofline plus a per-grid-step cost
+# (both from the kernels' own ``attn_grid_plan``: its exact causal band of
+# tiles and its grid), optional measured refinement, and a separate
+# persistent cache (only measured winners are written).  Keys deliberately exclude the sliding
 # window: it is traced at call time (transformer.layer_windows), so one
 # tuning decision per (shape, causal, softcap, dtype, backend) serves every
 # window the layer stack produces.
 
-ATTN_CACHE_VERSION = 1
+ATTN_CACHE_VERSION = 2            # 2: the band walk inside the kernel
 _MEM_ATTN: dict = {}
 _ATTN_DISK_LOADED = False
 
@@ -409,25 +409,40 @@ def attn_cache_key(B, H, Hkv, Sq, Sk, hd, *, dtype, causal, softcap,
             f"cap{softcap if softcap else 0}-{backend}")
 
 
+#: fixed cost of one Pallas grid step on a TPU, about 0.35 us (the Pallas
+#: TPU pipelining docs: each grid iteration carries that overhead, whatever
+#: its DMAs and compute)
+ATTN_STEP_S = 0.35e-6
+_ATTN_KERNELS = ("fwd", "dq", "dkv")
+
+
+def _attn_plans(B, H, Hkv, Sq, Sk, hd, bq, bk, schedule, *, bytes_el,
+                causal):
+    from .flash_attention import attn_grid_plan
+    return [attn_grid_plan(kern, B, H, Hkv, Sq, Sk, hd, block_q=bq,
+                           block_k=bk, itemsize=bytes_el, causal=causal,
+                           schedule=schedule) for kern in _ATTN_KERNELS]
+
+
 def attn_candidate_blocks(Sq: int, Sk: int, hd: int, *, bytes_el: int,
-                          interpret: bool) -> list:
+                          interpret: bool, group: int = 1) -> list:
     """All legal (bq, bk, schedule) triples: divisor blocks, VMEM
-    working-set filtered (real backend) or tile-size capped (interpret)."""
-    qq = 8 if Sq % 8 == 0 else 1
-    qk = 8 if Sk % 8 == 0 else 1
+    working-set filtered (real backend) or tile-size capped (interpret).
+
+    Blocks are multiples of ``flash_attention.block_quantum``, and on a
+    real TPU the working set counts each kernel's resident operand as the
+    kernel sizes it (``attn_grid_plan``): K and V in the forward and dq,
+    the KV head's ``group`` query heads of Q, dO, lse and delta in dkv."""
+    from .flash_attention import block_quantum
     cands = []
-    for bq in _divisors(Sq, qq, Sq):
-        for bk in _divisors(Sk, qk, Sk):
+    for bq in _divisors(Sq, block_quantum(Sq, interpret, q=True), Sq):
+        for bk in _divisors(Sk, block_quantum(Sk, interpret, q=False), Sk):
             if interpret:
                 if bq * bk > INTERPRET_TILE_ELEMS:
                     continue
-            else:
-                # double-buffered q/k/v tiles + fp32 score tile + the
-                # larger of the fwd/bwd fp32 accumulators
-                ws = 2 * bytes_el * (bq + 2 * bk) * hd \
-                    + 4 * (bq * bk + (bq + 2 * bk) * hd)
-                if ws > VMEM_BUDGET_BYTES:
-                    continue
+            elif _attn_vmem_bytes(Sq, Sk, hd, bq, bk, bytes_el=bytes_el,
+                                  group=group) > VMEM_BUDGET_BYTES:
+                continue
             cands.append((bq, bk, "dense"))
             cands.append((bq, bk, "skip"))
     if interpret:
@@ -442,51 +457,62 @@ def attn_candidate_blocks(Sq: int, Sk: int, hd: int, *, bytes_el: int,
     return cands
 
 
-def _attn_band_cells(Sq, Sk, bq, bk, causal) -> float:
-    """In-band (i, j) grid cells per (batch, head) for the causal band
-    (window unknown at tune time -> not narrowed)."""
-    n_q, n_k = Sq // bq, Sk // bk
-    if not causal:
-        return float(n_q * n_k)
-    i = np.arange(n_q)
-    hi = np.minimum(n_k - 1, ((i + 1) * bq - 1) // bk)
-    return float(np.sum(hi + 1))
+def _attn_vmem_bytes(Sq, Sk, hd, bq, bk, *, bytes_el, group) -> int:
+    """The larger kernel's VMEM: its resident operand, its double-buffered
+    tiles, fp32 scratch (a (n, 1) column pads to 128 lanes) and about four
+    fp32 score-sized temporaries."""
+    from .flash_attention import LANES, _lane_pad
+    hp = _lane_pad(hd)
+    fwd, _, dkv = _attn_plans(1, group, 1, Sq, Sk, hd, bq, bk, "skip",
+                              bytes_el=bytes_el, causal=True)
+    scores = 4 * 4 * bq * bk
+    ws_fwd = (2 * fwd.block_major * 2 * hp * bytes_el      # K, V
+              + 2 * 3 * bq * hp * bytes_el                 # q, dO, dq
+              + 4 * bq * (hp + 2 * LANES) + scores)
+    ws_dkv = (2 * dkv.block_major * group * (2 * hp * bytes_el + 64)
+              + 2 * 4 * bk * hp * bytes_el                 # k, v, dk, dv
+              + 4 * 2 * bk * hp + scores)
+    return max(ws_fwd, ws_dkv)
 
 
 def attn_predict_seconds(B, H, Hkv, Sq, Sk, hd, bq, bk, schedule, *,
                          bytes_el, causal, interpret) -> float:
     """Analytic cost of one fused fwd + bwd (dQ + dKV) at this tiling.
 
-    "skip" computes (and DMAs) only in-band cells; "dense" streams and
-    computes the full grid, relying on masking.  Interpret mode charges
+    The band walk computes only in-band tiles under "skip" and every tile
+    under "dense"; each kernel pays ``ATTN_STEP_S`` per grid step of its
+    plan.  Bytes: q-like planes and the resident K/V (fwd, dq) or Q/dO
+    (dkv) once per head while one chunk holds the head, else re-read per
+    in-band tile; ``hd`` padded to the 128 lanes it occupies in the
+    kernel's layout; lse/delta as lane-dense rows.  Interpret mode charges
     per-cell dispatch overhead for every grid cell of all three kernels —
-    pl.when saves arithmetic but not dispatch."""
-    n_q, n_k = Sq // bq, Sk // bk
-    band = _attn_band_cells(Sq, Sk, bq, bk, causal)
-    full = float(n_q * n_k)
-    cells = band if schedule == "skip" else full
-    tile = float(bq * bk * hd)
-    f_fwd = B * H * 4.0 * tile * cells
-    f_dq = B * H * 6.0 * tile * cells
-    f_dkv = B * H * 8.0 * tile * cells
+    the in-kernel walk saves arithmetic but not dispatch."""
+    plans = _attn_plans(B, H, Hkv, Sq, Sk, hd, bq, bk, schedule,
+                        bytes_el=bytes_el, causal=causal)
+    tiles = plans[0].tiles_full + plans[0].tiles_masked
+    area = float(bq * bk * hd) * tiles
+    f_fwd, f_dq, f_dkv = 4.0 * area, 6.0 * area, 8.0 * area
+    steps = sum(p.steps for p in plans)
 
     if interpret:
         flops = f_fwd + f_dq + f_dkv
-        grid_cells = 3 * B * H * n_q * n_k
-        return flops / CPU_FLOPS + grid_cells * CELL_OVERHEAD_S
+        return flops / CPU_FLOPS + steps * CELL_OVERHEAD_S
 
-    be = bytes_el
-    q_pl = B * H * Sq * hd * be            # one (B, H, Sq, hd) plane
-    kv_pl = B * Hkv * Sk * hd * be         # one (B, Hkv, Sk, hd) plane
-    lse_b = 4 * B * H * Sq
-    kv_stream = 2 * be * B * H * cells * bk * hd       # k+v per in-band cell
-    q_stream = 2 * be * B * H * cells * bq * hd        # q+do per in-band cell
+    from .flash_attention import _lane_pad
+    be, hp = bytes_el, _lane_pad(hd)
+    q_pl = B * H * Sq * hp * be            # one (B, H, Sq, hd) plane
+    kv_pl = B * Hkv * Sk * hp * be         # one (B, Hkv, Sk, hd) plane
+    row = 4 * B * H * Sq                   # one lse / delta row plane
+    fwd, _, dkv = plans
+    kv_read = 2 * kv_pl if fwd.grid[3] == 1 else 2 * be * hp * bk * tiles
+    q_read = 2 * q_pl + 2 * row if dkv.grid[3] == 1 else \
+        (2 * be * hp + 2 * 4) * bq * tiles
     passes = [
-        (f_fwd, 2 * q_pl + kv_stream + lse_b),
-        (f_dq, 3 * q_pl + kv_stream + 2 * lse_b),
-        (f_dkv, 4 * kv_pl + q_stream + 2 * lse_b),
+        (f_fwd, 2 * q_pl + kv_read + row),
+        (f_dq, 3 * q_pl + kv_read + 2 * row),
+        (f_dkv, 4 * kv_pl + q_read),
     ]
-    return _roofline_seconds(passes)
+    return _roofline_seconds(passes) + steps * ATTN_STEP_S
 
 
 def _load_attn_disk() -> None:
@@ -572,7 +598,7 @@ def get_tuned_attn(B, H, Hkv, Sq, Sk, hd, *, dtype, causal, softcap,
 
     bytes_el = _dtype_bytes(dtype)
     cands = attn_candidate_blocks(Sq, Sk, hd, bytes_el=bytes_el,
-                                  interpret=interpret)
+                                  interpret=interpret, group=H // Hkv)
     if interpret:
         # prefer candidates the interpret grid clamp wouldn't rewrite
         from .flash_attention import INTERPRET_CELL_CAP

@@ -3,22 +3,30 @@
 The training hot path used to run ``models/layers.py:full_attention``,
 which materializes an fp32 ``(B, Hkv, G, S, S)`` score tensor every layer.
 This module replaces it with a production Pallas kernel family that keeps
-the online softmax entirely in VMEM:
+the online softmax entirely in VMEM and walks the causal band *inside* the
+kernel, so an out-of-band tile costs neither a grid step nor a DMA:
 
-* **forward** — grid ``(B, H, Sq/BQ, Sk/BK)`` with the KV axis innermost
-  ("arbitrary"): VMEM scratch carries the running max/denominator and an
-  fp32 accumulator, initialized at ``j == 0`` and flushed at the last KV
-  block.  Emits the logsumexp ``(B, H, Sq, 1)`` as a second output — the
-  backward residual, carried with a trailing unit axis so its
-  ``(block_q, 1)`` blocks meet the TPU tiling rule (last two block dims a
-  multiple of (8, 128) or equal to the array's).  GQA maps
-  ``h -> h // G`` in the KV BlockSpecs.
+* **forward** — grid ``(B, H, Sq/BQ, n_c)``.  The K and V of the query
+  head's KV head are one VMEM-resident block of ``BKM`` rows, fetched once
+  per ``(b, h)``: the whole head (``n_c == 1``) unless double-buffered,
+  lane-padded K and V would pass ``RESIDENT_VMEM_BYTES``, in which case
+  ``BKM`` is the largest divisor of Sk that fits and the ``n_c`` chunks stay
+  on the grid.  Each grid step walks its q-block's band of ``BK``-row KV
+  sub-tiles with ``lax.fori_loop``: the sub-tiles that straddle the diagonal
+  or the window edge build the iota mask, the rest run unmasked.  VMEM
+  scratch carries the running max/denominator and an fp32 accumulator
+  across chunks.  Emits the logsumexp as a lane-dense ``(B, H, 1, Sq)``
+  row, the backward residual (a ``(B, H, Sq, 1)`` column pads its unit
+  lane axis to 128 in HBM).  GQA maps ``h -> h // G`` in the KV BlockSpecs.
 * **backward** (``custom_vjp``) — two kernels with the delta/lse recompute
-  trick (``delta = rowsum(dO * O)`` precomputed outside): dQ iterates KV
-  innermost with an fp32 ``(BQ, hd)`` accumulator; dK/dV iterate
-  ``(group, q-block)`` pairs innermost over ``(B, Hkv, Sk/BK)`` so the
-  GQA group-sum lands in one fp32 ``(BK, hd)`` scratch — no
-  ``(B, H, Sk, hd)`` intermediate.
+  trick (``delta = rowsum(dO * O)`` precomputed outside, a lane-dense row
+  like lse): dQ has the forward's grid and band walk with an fp32
+  ``(BQ, hd)`` accumulator; dK/dV run over ``(B, Hkv, Sk/BK, n_c)`` with
+  the G query heads' Q, dO, lse and delta of the KV head resident (chunked
+  like K/V in the forward), walking ``(group, q-block)`` pairs of the band
+  into one fp32 ``(BK, hd)`` scratch — no ``(B, H, Sk, hd)`` intermediate.
+  Its scores are computed transposed (``K Q^T``), so the lse/delta rows
+  broadcast as they are.
 * **custom_jvp twin** (``use_jvp=True``) — same Pallas forward for the
   primal; the tangent is a chunked fp32 jnp sweep, *linear* in the input
   tangents, so JAX can both push Hutchinson's forward-over-reverse HVP
@@ -27,17 +35,23 @@ the online softmax entirely in VMEM:
 Masking covers causal, sliding-window and the gemma2 logit softcap.  The
 window rides in as a scalar-prefetch operand (sentinel ``1 << 30`` = no
 window) so the *traced* per-layer windows from ``transformer.layer_windows``
-work, and — because ``PrefetchScalarGridSpec`` index maps receive the
-scalar ref — the ``schedule="skip"`` variant clamps the streamed block
-index into the live band: fully-masked ``j > i`` (causal) and
-out-of-window grid cells neither DMA fresh tiles nor compute.
-``schedule="dense"`` streams every block and relies on masking alone.
+work: the band walk reads it, and where ``n_c > 1`` so do the chunk index
+maps, which clamp into the band so that out-of-band chunks are not fetched.
+``schedule="skip"`` walks the band; ``schedule="dense"`` walks every
+sub-tile and masks each (the reference the parity tests hold "skip" to).
+``attn_grid_plan`` gives each kernel call's grid, resident rows and full /
+masked sub-tile counts: the wrappers build their grids from it and add it
+to ``KERNEL_CALLS`` (``attn_grid_steps``, ``attn_tiles_full``,
+``attn_tiles_masked``), and the autotuner prices candidates with it.
 
 Block sizes and the schedule come from ``kernels/autotune.py``
-(``get_tuned_attn``) unless given explicitly; ``interpret=None`` resolves
-to "not on a real TPU" (the repo convention, ``fused_ce._interpret_default``)
-and interpret-mode grids are auto-clamped to <= ``INTERPRET_CELL_CAP``
-cells so CPU CI never unrolls huge grids.
+(``get_tuned_attn``) unless given explicitly.  On a TPU a q-block is a
+multiple of 128 or the whole sequence (``block_quantum``: it offsets the
+lse/delta rows along lanes); an explicit one is rounded down to such a
+divisor, or up to the smallest.  ``interpret=None`` resolves to "not on a
+real TPU" (the repo convention, ``fused_ce._interpret_default``) and
+interpret-mode grids are auto-clamped to <= ``INTERPRET_CELL_CAP`` cells so
+CPU CI never unrolls huge grids.
 
 Parity: ``kernels/ref.py`` closed-form oracles mirror every fp32 rounding
 point (<= 3e-6, tests/test_flash_attention.py); ``KERNEL_CALLS`` counts
@@ -46,6 +60,7 @@ trace time so tests can assert nothing silently fell back.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 
@@ -62,11 +77,10 @@ from .fused_ce import KERNEL_CALLS, _interpret_default
 NEG_INF = -1e30
 WINDOW_NONE = 1 << 30          # sentinel window: larger than any context
 INTERPRET_CELL_CAP = 64        # max unrolled grid cells under interpret
-
-
-# ---------------------------------------------------------------------------
-# block bands (shared by index maps and kernels; jnp int arithmetic so the
-# window may be a traced scalar from the prefetch ref)
+LANES = 128                    # a TPU vreg's lane count: minor dims pad to it
+#: VMEM for a kernel's resident operand, double-buffered and lane-padded:
+#: K and V in the forward and dq, the KV head's Q, dO, lse and delta in dkv
+RESIDENT_VMEM_BYTES = 8 << 20
 
 
 #: the kernels' names in a compiled program (the Mosaic module's
@@ -76,34 +90,112 @@ KERNEL_NAMES = FWD_NAME, DQ_NAME, DKV_NAME = (
     "_fwd_kernel", "_dq_kernel", "_dkv_kernel")
 
 
-def _kv_band(i, win, *, causal, q_offset, block_q, block_k, n_k):
+# ---------------------------------------------------------------------------
+# block bands (shared by index maps, kernels and the grid plan; integer
+# arithmetic in ``xp`` — jnp so the window may be a traced scalar from the
+# prefetch ref, numpy for the host-side plan)
+
+
+def _kv_band(i, win, *, causal, q_offset, block_q, block_k, n_k, xp=jnp):
     """Inclusive [lo, hi] range of KV blocks attended by q-block ``i``."""
     if causal:
-        hi = jnp.minimum(n_k - 1,
-                         ((i + 1) * block_q - 1 + q_offset) // block_k)
+        hi = xp.minimum(n_k - 1,
+                        ((i + 1) * block_q - 1 + q_offset) // block_k)
     else:
         hi = n_k - 1
-    lo = jnp.maximum(0, (i * block_q + q_offset - win + 1) // block_k)
+    lo = xp.maximum(0, (i * block_q + q_offset - win + 1) // block_k)
     return lo, hi
 
 
-def _q_band(j, win, *, causal, q_offset, block_q, block_k, n_q):
+def _q_band(j, win, *, causal, q_offset, block_q, block_k, n_q, xp=jnp):
     """Inclusive [lo, hi] range of q blocks attending KV block ``j``."""
     if causal:
-        lo = jnp.maximum(0, (j * block_k - q_offset) // block_q)
+        lo = xp.maximum(0, (j * block_k - q_offset) // block_q)
     else:
         lo = 0
-    hi = jnp.minimum(n_q - 1,
-                     ((j + 1) * block_k - 2 + win - q_offset) // block_q)
+    hi = xp.minimum(n_q - 1,
+                    ((j + 1) * block_k - 2 + win - q_offset) // block_q)
     return lo, hi
 
 
-def _tile_mask(i, j, win, *, causal, q_offset, block_q, block_k):
-    """(BQ, BK) bool attend-mask for grid cell (i, j), global positions."""
+def _kv_full(i, win, *, causal, q_offset, block_q, block_k, n_k):
+    """Inclusive range of KV blocks that every row of q-block ``i`` attends
+    in full (empty when lo > hi): past the window edge, up to the diagonal."""
+    lo = -((win - q_offset - (i + 1) * block_q) // block_k)
+    hi = (q_offset + i * block_q + 1) // block_k - 1 if causal else n_k - 1
+    return lo, hi
+
+
+def _q_full(j, win, *, causal, q_offset, block_q, block_k, n_q):
+    """Inclusive range of q blocks whose every row attends all of KV block
+    ``j`` (empty when lo > hi)."""
+    lo = -((q_offset + 1 - (j + 1) * block_k) // block_q) if causal else 0
+    hi = (j * block_k + win - q_offset) // block_q - 1
+    return lo, hi
+
+
+def _walk(band, full, c, per_chunk, schedule, xp=jnp):
+    """Chunk ``c``'s sub-tiles ``[c * per_chunk, (c + 1) * per_chunk)`` as
+    ``(start, stop, masked)`` ranges: the in-band ones below the full range
+    masked, those inside it unmasked, those above it masked.  "dense" walks
+    the whole chunk, masked."""
+    c0 = c * per_chunk
+    c1 = c0 + per_chunk
+    if schedule != "skip":
+        return ((c0, c1, True),)
+    lo = xp.maximum(band[0], c0)
+    hi = xp.maximum(xp.minimum(band[1] + 1, c1), lo)
+    a = xp.clip(full[0], lo, hi)
+    f = xp.clip(full[1] + 1, a, hi)
+    return ((lo, a, True), (a, f, False), (f, hi, True))
+
+
+def _run(ranges, tile):
+    """``tile(t, masked)`` for every sub-tile ``t`` of the walk, in order."""
+    for start, stop, masked in ranges:
+        def body(t, carry, masked=masked):
+            tile(t, masked)
+            return carry
+        jax.lax.fori_loop(start, stop, body, 0)
+
+
+def _clamp_chunk(c, band, per_chunk, n_c):
+    """Chunk index clamped into the band's chunks (and the grid): a chunk
+    outside the band re-uses the resident block instead of a fresh DMA."""
+    c = jnp.clip(c, band[0] // per_chunk, band[1] // per_chunk)
+    return jnp.clip(c, 0, n_c - 1)
+
+
+def _kv_spec(plan, hd, group, n_k, band, skip):
+    """The fwd and dq kernels' K/V BlockSpec: the KV head's resident rows,
+    with a chunked head's chunk index clamped into q-block ``i``'s band."""
+    n_c, per_chunk = plan.grid[3], plan.block_major // band["block_k"]
+
+    def index(b, h, i, c, w):
+        if skip and n_c > 1:
+            c = _clamp_chunk(c, _kv_band(i, w[0], n_k=n_k, **band),
+                             per_chunk, n_c)
+        return (b, h // group, c, 0)
+
+    return pl.BlockSpec((1, 1, plan.block_major, hd), index)
+
+
+def _sub(t, block, n):
+    """The ``t``-th of ``n`` ``block``-long slices of a resident axis."""
+    if n == 1:
+        return pl.ds(0, block)
+    return pl.ds(pl.multiple_of(t * block, block), block)
+
+
+def _tile_mask(i, j, win, *, causal, q_offset, block_q, block_k,
+               transpose=False):
+    """(BQ, BK) bool attend-mask for tile (i, j), global positions;
+    ``transpose`` gives it (BK, BQ), keys along the rows."""
+    shape, qd = ((block_k, block_q), 1) if transpose else \
+        ((block_q, block_k), 0)
     qpos = q_offset + i * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    kpos = j * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
+        jnp.int32, shape, qd)
+    kpos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - qd)
     m = kpos > qpos - win
     if causal:
         m = m & (kpos <= qpos)
@@ -116,87 +208,182 @@ def _dotT(a, b):
                                preferred_element_type=jnp.float32)
 
 
+def _col_to_row(x):
+    """(n, 1) -> (1, n), through a lane-wide transpose."""
+    return jnp.broadcast_to(x, (x.shape[0], LANES)).T[:1]
+
+
+def _row_to_col(x):
+    """(1, n) -> (n, 1), through a lane-wide transpose."""
+    return jnp.broadcast_to(x, (LANES, x.shape[1])).T[:, :1]
+
+
+# ---------------------------------------------------------------------------
+# grid plan (host-side, static shapes)
+
+
+def _lane_pad(n: int) -> int:
+    return -(-n // LANES) * LANES
+
+
+def block_quantum(S: int, interpret: bool, *, q: bool) -> int:
+    """Blocks of a length-S axis are multiples of this.  On a TPU a q-block
+    offsets the lse/delta rows along lanes, so it is a multiple of 128 or
+    all of S; a KV block offsets rows only."""
+    if not interpret and S % LANES == 0:
+        return LANES
+    if not interpret and q:
+        return S
+    return 8 if S % 8 == 0 else 1
+
+
+def _resident_rows(S: int, block: int, row_bytes: int) -> int:
+    """The largest multiple of ``block`` dividing S whose rows, at
+    ``row_bytes`` each and double-buffered, fit ``RESIDENT_VMEM_BYTES``;
+    ``block`` itself when none does."""
+    best = block
+    for m in range(block, S + 1, block):
+        if S % m == 0 and 2 * m * row_bytes <= RESIDENT_VMEM_BYTES:
+            best = m
+    return best
+
+
+@dataclasses.dataclass(frozen=True)
+class GridPlan:
+    """One flash kernel call: its Pallas ``grid``, the rows of the resident
+    operand per chunk (``block_major``, a multiple of the walked sub-tile),
+    and the sub-tiles of the causal band it computes unmasked
+    (``tiles_full``) and masked (``tiles_masked``), over all heads."""
+    grid: tuple
+    block_major: int
+    tiles_full: int
+    tiles_masked: int
+
+    @property
+    def steps(self) -> int:
+        return math.prod(self.grid)
+
+
+def attn_grid_plan(kernel, B, H, Hkv, Sq, Sk, hd, *, block_q, block_k,
+                   itemsize, causal=True, q_offset=0,
+                   schedule="skip") -> GridPlan:
+    """The grid of one ``kernel`` ("fwd", "dq" or "dkv") call.
+
+    fwd and dq keep K and V resident (``Sk`` rows), dkv the KV head's G
+    query heads of Q and dO plus their lse and delta rows (``Sq`` rows).
+    Tile counts are per call, and the same for the three kernels; they
+    count the causal band, which a sliding window (traced, unknown here)
+    narrows further at run time."""
+    group = H // Hkv
+    n_q, n_k = Sq // block_q, Sk // block_k
+    pair = 2 * _lane_pad(hd) * itemsize       # a row of two operands
+    if kernel == "dkv":
+        # + lse and delta rows, a (1, n) f32 block padding 1 -> 8 sublanes
+        bm = _resident_rows(Sq, block_q, group * (pair + 2 * 8 * 4))
+        grid = (B, Hkv, n_k, Sq // bm)
+    else:
+        bm = _resident_rows(Sk, block_k, pair)
+        grid = (B, H, n_q, Sk // bm)
+    i = np.arange(n_q)
+    band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
+                block_k=block_k)
+    ranges = _walk(_kv_band(i, WINDOW_NONE, n_k=n_k, xp=np, **band),
+                   _kv_full(i, WINDOW_NONE, n_k=n_k, **band), 0, n_k,
+                   schedule, xp=np)
+    tiles = {False: 0, True: 0}                # unmasked, masked
+    for start, stop, masked in ranges:
+        tiles[masked] += int(np.sum(np.broadcast_to(stop - start, i.shape)))
+    return GridPlan(grid, bm, B * H * tiles[False], B * H * tiles[True])
+
+
+def _plan(kernel, q, k, block_q, block_k, causal, q_offset, schedule):
+    """The call's plan, added to the trace-time counters."""
+    plan = attn_grid_plan(kernel, *q.shape[:2], k.shape[1], q.shape[2],
+                          k.shape[2], q.shape[3], block_q=block_q,
+                          block_k=block_k, itemsize=q.dtype.itemsize,
+                          causal=causal, q_offset=q_offset,
+                          schedule=schedule)
+    KERNEL_CALLS["attn_grid_steps"] += plan.steps
+    KERNEL_CALLS["attn_tiles_full"] += plan.tiles_full
+    KERNEL_CALLS["attn_tiles_masked"] += plan.tiles_masked
+    return plan
+
+
 # ---------------------------------------------------------------------------
 # forward kernel
 
 
 def _fwd_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                 acc_scr, *, scale, causal, softcap, q_offset, block_q,
-                block_k, n_k, schedule):
-    i, j = pl.program_id(2), pl.program_id(3)
+                block_k, n_q, n_k, n_c, schedule):
+    i, c = pl.program_id(2), pl.program_id(3)
+    per_chunk = k_ref.shape[2] // block_k
+    win = win_ref[0]
+    band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
+                block_k=block_k)
 
-    @pl.when(j == 0)
+    @pl.when(c == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr[...], NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr[...])
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
-    def _step():
+    def tile(j, masked):
+        rows = _sub(j - c * per_chunk, block_k, per_chunk)
         q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, rows, :].astype(jnp.float32)
+        v = v_ref[0, 0, rows, :].astype(jnp.float32)
         s = _dotT(q, k) * scale
         if softcap is not None:
             s = softcap * jnp.tanh(s / softcap)
-        mask = _tile_mask(i, j, win_ref[0], causal=causal, q_offset=q_offset,
-                          block_q=block_q, block_k=block_k)
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            mask = _tile_mask(i, j, win, **band)
+            s = jnp.where(mask, s, NEG_INF)
         m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        # where-guard: a fully-masked tile has m_new == NEG_INF and
-        # exp(s - m_new) == 1 — the mask zeroes it instead
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            # a fully-masked row has m_new == NEG_INF and exp(s - m_new)
+            # == 1 — the mask zeroes it instead
+            p = jnp.where(mask, p, 0.0)
         l_scr[...] = l_scr[...] * alpha + p.sum(axis=-1, keepdims=True)
         m_scr[...] = m_new
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
 
-    if schedule == "skip":
-        lo, hi = _kv_band(i, win_ref[0], causal=causal, q_offset=q_offset,
-                          block_q=block_q, block_k=block_k, n_k=n_k)
-        pl.when((j >= lo) & (j <= hi))(_step)
-    else:
-        _step()
+    _run(_walk(_kv_band(i, win, n_k=n_k, **band),
+               _kv_full(i, win, n_k=n_k, **band), c, per_chunk, schedule),
+         tile)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(c == n_c - 1)
     def _flush():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+        lse_ref[0, 0, :, _sub(i, block_q, n_q)] = _col_to_row(
+            m_scr[...] + jnp.log(l))
 
 
 def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
              block_k, schedule, interpret):
-    """Raw fwd pallas_call -> (o, lse); no autodiff wiring."""
+    """Raw fwd pallas_call -> (o, lse (B, H, 1, Sq)); no autodiff wiring."""
     B, H, Sq, hd = q.shape
-    _, Hkv, Sk, _ = k.shape
-    group = H // Hkv
-    n_q, n_k = Sq // block_q, Sk // block_k
-    skip = schedule == "skip"
-
-    def kv_index(b, h, i, j, w):
-        if skip:
-            lo, hi = _kv_band(i, w[0], causal=causal, q_offset=q_offset,
-                              block_q=block_q, block_k=block_k, n_k=n_k)
-            j = jnp.clip(j, lo, hi)
-        return (b, h // group, j, 0)
-
+    group = H // k.shape[1]
+    n_q, n_k = Sq // block_q, k.shape[2] // block_k
+    plan = _plan("fwd", q, k, block_q, block_k, causal, q_offset, schedule)
+    n_c = plan.grid[3]
+    band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
+                block_k=block_k)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda b, h, i, c, w: (b, h, i, 0))
+    kv_spec = _kv_spec(plan, hd, group, n_k, band, schedule == "skip")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, H, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd),
-                         lambda b, h, i, j, w: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), kv_index),
-            pl.BlockSpec((1, 1, block_k, hd), kv_index),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, hd),
-                         lambda b, h, i, j, w: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1),
-                         lambda b, h, i, j, w: (b, h, i, 0)),
-        ],
+        grid=plan.grid,
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec,
+                   pl.BlockSpec((1, 1, 1, Sq),
+                                lambda b, h, i, c, w: (b, h, 0, 0))],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max
             pltpu.VMEM((block_q, 1), jnp.float32),     # running denominator
@@ -205,15 +392,15 @@ def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
     )
     kern = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, softcap=softcap,
-        q_offset=q_offset, block_q=block_q, block_k=block_k, n_k=n_k,
-        schedule=schedule)
+        q_offset=q_offset, block_q=block_q, block_k=block_k, n_q=n_q,
+        n_k=n_k, n_c=n_c, schedule=schedule)
     KERNEL_CALLS["attn_fwd"] += 1
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32),
         ],
         interpret=interpret,
         name=FWD_NAME,
@@ -225,87 +412,101 @@ def _forward(q, k, v, win, *, causal, scale, softcap, q_offset, block_q,
 # stored probabilities)
 
 
-def _bwd_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, i, j, win, *,
-              scale, causal, softcap, q_offset, block_q, block_k):
-    """Shared per-tile recompute: (p, ds, do32) with ds already
-    softcap-chained; all fp32."""
-    q = q_ref[0, 0].astype(jnp.float32)
-    k = k_ref[0, 0].astype(jnp.float32)
-    v = v_ref[0, 0].astype(jnp.float32)
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                               # (block_q, 1)
-    delta = dl_ref[0, 0]
-    s = _dotT(q, k) * scale
+def _bwd_p_ds(s, dp, lse, delta, mask, softcap):
+    """Shared per-tile recompute from raw scores ``s`` and ``dp = dO V^T``
+    (either orientation; lse/delta broadcast against them): (p, ds) with ds
+    already softcap-chained; all fp32."""
     if softcap is not None:
         t = jnp.tanh(s / softcap)
         z, dcap = softcap * t, 1.0 - t * t
     else:
         z, dcap = s, None
-    mask = _tile_mask(i, j, win, causal=causal, q_offset=q_offset,
-                      block_q=block_q, block_k=block_k)
-    p = jnp.where(mask, jnp.exp(z - lse), 0.0)
-    ds = p * (_dotT(do, v) - delta)
+    p = jnp.exp(z - lse)
+    if mask is not None:
+        p = jnp.where(mask, p, 0.0)
+    ds = p * (dp - delta)
     if dcap is not None:
         ds = ds * dcap
-    return q, k, do, p, ds
+    return p, ds
 
 
 def _dq_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
-               dq_ref, dq_scr, *, scale, causal, softcap, q_offset, block_q,
-               block_k, n_k, schedule):
-    i, j = pl.program_id(2), pl.program_id(3)
+               dq_ref, dq_scr, lse_scr, dl_scr, *, scale, causal, softcap,
+               q_offset, block_q, block_k, n_q, n_k, n_c, schedule):
+    i, c = pl.program_id(2), pl.program_id(3)
+    per_chunk = k_ref.shape[2] // block_k
+    win = win_ref[0]
+    band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
+                block_k=block_k)
 
-    @pl.when(j == 0)
+    @pl.when(c == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr[...])
+        rows = _sub(i, block_q, n_q)
+        lse_scr[...] = _row_to_col(lse_ref[0, 0, :, rows])
+        dl_scr[...] = _row_to_col(dl_ref[0, 0, :, rows])
 
-    def _step():
-        _, k, _, _, ds = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, i, j, win_ref[0],
-            scale=scale, causal=causal, softcap=softcap, q_offset=q_offset,
-            block_q=block_q, block_k=block_k)
+    def tile(j, masked):
+        rows = _sub(j - c * per_chunk, block_k, per_chunk)
+        q = q_ref[0, 0].astype(jnp.float32)
+        do = do_ref[0, 0].astype(jnp.float32)
+        k = k_ref[0, 0, rows, :].astype(jnp.float32)
+        v = v_ref[0, 0, rows, :].astype(jnp.float32)
+        mask = _tile_mask(i, j, win, **band) if masked else None
+        _, ds = _bwd_p_ds(_dotT(q, k) * scale, _dotT(do, v), lse_scr[...],
+                          dl_scr[...], mask, softcap)
         dq_scr[...] += jax.lax.dot(
             ds, k, preferred_element_type=jnp.float32) * scale
 
-    if schedule == "skip":
-        lo, hi = _kv_band(i, win_ref[0], causal=causal, q_offset=q_offset,
-                          block_q=block_q, block_k=block_k, n_k=n_k)
-        pl.when((j >= lo) & (j <= hi))(_step)
-    else:
-        _step()
+    _run(_walk(_kv_band(i, win, n_k=n_k, **band),
+               _kv_full(i, win, n_k=n_k, **band), c, per_chunk, schedule),
+         tile)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(c == n_c - 1)
     def _flush():
         dq_ref[0, 0] = dq_scr[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(win_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, softcap,
-                q_offset, block_q, block_k, n_q, n_inner, schedule):
-    j, t = pl.program_id(2), pl.program_id(3)
-    i = t % n_q                         # q-block; t // n_q is the GQA group
+                q_offset, block_q, block_k, n_q, n_c, schedule):
+    j, c = pl.program_id(2), pl.program_id(3)
+    group, per_chunk = q_ref.shape[1], q_ref.shape[2] // block_q
+    win = win_ref[0]
+    band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
+                block_k=block_k)
 
-    @pl.when(t == 0)
+    @pl.when(c == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr[...])
         dv_scr[...] = jnp.zeros_like(dv_scr[...])
 
-    def _step():
-        q, _, do, p, ds = _bwd_tile(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, i, j, win_ref[0],
-            scale=scale, causal=causal, softcap=softcap, q_offset=q_offset,
-            block_q=block_q, block_k=block_k)
-        dv_scr[...] += _dotT(p.T, do.T)
-        dk_scr[...] += _dotT(ds.T, q.T) * scale
+    def tile(g, i, masked):
+        rows = _sub(i - c * per_chunk, block_q, per_chunk)
+        q = q_ref[0, g, rows, :].astype(jnp.float32)
+        do = do_ref[0, g, rows, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        mask = _tile_mask(i, j, win, transpose=True, **band) if masked \
+            else None
+        # (BK, BQ): scores transposed, so lse/delta rows broadcast as read
+        p, ds = _bwd_p_ds(_dotT(k, q) * scale, _dotT(v, do),
+                          lse_ref[0, g, :, rows], dl_ref[0, g, :, rows],
+                          mask, softcap)
+        dv_scr[...] += jax.lax.dot(p, do, preferred_element_type=jnp.float32)
+        dk_scr[...] += jax.lax.dot(
+            ds, q, preferred_element_type=jnp.float32) * scale
 
-    if schedule == "skip":
-        lo, hi = _q_band(j, win_ref[0], causal=causal, q_offset=q_offset,
-                         block_q=block_q, block_k=block_k, n_q=n_q)
-        pl.when((i >= lo) & (i <= hi))(_step)
-    else:
-        _step()
+    ranges = _walk(_q_band(j, win, n_q=n_q, **band),
+                   _q_full(j, win, n_q=n_q, **band), c, per_chunk, schedule)
 
-    @pl.when(t == n_inner - 1)
+    def per_group(g, carry):
+        _run(ranges, functools.partial(tile, g))
+        return carry
+
+    jax.lax.fori_loop(0, group, per_group, 0)
+
+    @pl.when(c == n_c - 1)
     def _flush():
         dk_ref[0, 0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_scr[...].astype(dv_ref.dtype)
@@ -320,30 +521,27 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
     skip = schedule == "skip"
     band = dict(causal=causal, q_offset=q_offset, block_q=block_q,
                 block_k=block_k)
+    static = dict(scale=scale, causal=causal, softcap=softcap,
+                  q_offset=q_offset, block_q=block_q, block_k=block_k,
+                  n_q=n_q, schedule=schedule)
 
-    # --- dQ: grid (B, H, n_q, n_k), KV innermost --------------------------
-    def kv_index(b, h, i, j, w):
-        if skip:
-            lo, hi = _kv_band(i, w[0], n_k=n_k, **band)
-            j = jnp.clip(j, lo, hi)
-        return (b, h // group, j, 0)
-
+    # --- dQ: the forward's grid and band walk -----------------------------
+    plan = _plan("dq", q, k, block_q, block_k, causal, q_offset, schedule)
+    n_c = plan.grid[3]
     q_spec = pl.BlockSpec((1, 1, block_q, hd),
-                          lambda b, h, i, j, w: (b, h, i, 0))
-    row_spec = pl.BlockSpec((1, 1, block_q, 1),
-                            lambda b, h, i, j, w: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, hd), kv_index)
+                          lambda b, h, i, c, w: (b, h, i, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, Sq), lambda b, h, i, c, w: (b, h, 0, 0))
+    kv_spec = _kv_spec(plan, hd, group, n_k, band, skip)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, H, n_q, n_k),
+        grid=plan.grid,
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32),
+                        pltpu.VMEM((block_q, 1), jnp.float32)],
     )
-    kern = functools.partial(
-        _dq_kernel, scale=scale, causal=causal, softcap=softcap,
-        q_offset=q_offset, block_q=block_q, block_k=block_k, n_k=n_k,
-        schedule=schedule)
+    kern = functools.partial(_dq_kernel, n_k=n_k, n_c=n_c, **static)
     KERNEL_CALLS["attn_bwd_dq"] += 1
     dq = pl.pallas_call(
         kern, grid_spec=grid_spec,
@@ -352,40 +550,33 @@ def _backward(q, k, v, win, do, lse, delta, *, causal, scale, softcap,
         name=DQ_NAME,
     )(win, q, k, v, do, lse, delta)
 
-    # --- dK/dV: grid (B, Hkv, n_k, G * n_q), (group, q-block) innermost ---
-    n_inner = group * n_q
+    # --- dK/dV: grid (B, Hkv, n_k, n_c), the group's Q rows resident -------
+    plan = _plan("dkv", q, k, block_q, block_k, causal, q_offset, schedule)
+    n_c, bm = plan.grid[3], plan.block_major
+    per_chunk = bm // block_q
 
-    def q_index(b, kv, j, t, w):
-        i = t % n_q
-        if skip:
-            lo, hi = _q_band(j, w[0], n_q=n_q, **band)
-            i = jnp.clip(i, lo, hi)
-        return (b, kv * group + t // n_q, i, 0)
+    def chunk(j, c, w):
+        if skip and n_c > 1:
+            c = _clamp_chunk(c, _q_band(j, w[0], n_q=n_q, **band),
+                             per_chunk, n_c)
+        return c
 
-    def row_index(b, kv, j, t, w):
-        i = t % n_q
-        if skip:
-            lo, hi = _q_band(j, w[0], n_q=n_q, **band)
-            i = jnp.clip(i, lo, hi)
-        return (b, kv * group + t // n_q, i, 0)
-
-    qg_spec = pl.BlockSpec((1, 1, block_q, hd), q_index)
-    rowg_spec = pl.BlockSpec((1, 1, block_q, 1), row_index)
+    qg_spec = pl.BlockSpec((1, group, bm, hd),
+                           lambda b, kv, j, c, w: (b, kv, chunk(j, c, w), 0))
+    rowg_spec = pl.BlockSpec((1, group, 1, bm),
+                             lambda b, kv, j, c, w: (b, kv, 0, chunk(j, c, w)))
     kvb_spec = pl.BlockSpec((1, 1, block_k, hd),
-                            lambda b, kv, j, t, w: (b, kv, j, 0))
+                            lambda b, kv, j, c, w: (b, kv, j, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hkv, n_k, n_inner),
+        grid=plan.grid,
         in_specs=[qg_spec, kvb_spec, kvb_spec, qg_spec, rowg_spec,
                   rowg_spec],
         out_specs=[kvb_spec, kvb_spec],
         scratch_shapes=[pltpu.VMEM((block_k, hd), jnp.float32),
                         pltpu.VMEM((block_k, hd), jnp.float32)],
     )
-    kern = functools.partial(
-        _dkv_kernel, scale=scale, causal=causal, softcap=softcap,
-        q_offset=q_offset, block_q=block_q, block_k=block_k, n_q=n_q,
-        n_inner=n_inner, schedule=schedule)
+    kern = functools.partial(_dkv_kernel, n_c=n_c, **static)
     KERNEL_CALLS["attn_bwd_dkv"] += 1
     dk, dv = pl.pallas_call(
         kern, grid_spec=grid_spec,
@@ -431,8 +622,8 @@ def _flash_fwd(q, k, v, win, causal, scale, softcap, q_offset, block_q,
 def _flash_bwd(causal, scale, softcap, q_offset, block_q, block_k, schedule,
                interpret, res, g):
     q, k, v, win, o, lse = res
-    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(
-        -1, keepdims=True)
+    delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).sum(-1)
+    delta = delta[:, :, None, :]                    # lane-dense, like lse
     dq, dk, dv = _backward(
         q, k, v, win, g, lse, delta, causal=causal, scale=scale,
         softcap=softcap, q_offset=q_offset, block_q=block_q,
@@ -629,6 +820,10 @@ def flash_attention(q, k, v, *, causal=True, scale=None, window=None,
         schedule = schedule or t.schedule
     block_q = _fit_block(Sq, block_q)
     block_k = _fit_block(Sk, block_k)
+    quantum = block_quantum(Sq, interpret, q=True)
+    if not interpret and block_q % quantum:
+        block_q = max([m for m in range(quantum, block_q + 1, quantum)
+                       if Sq % m == 0] or [quantum])
     if interpret:
         block_q, block_k = _clamp_interpret_grid(Sq, Sk, block_q, block_k,
                                                  B * H)

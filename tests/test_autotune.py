@@ -321,6 +321,66 @@ def test_attn_predict_skip_beats_dense_when_causal(monkeypatch):
     assert skip < dense
 
 
+#: the benchmark cells' attention calls: gpt2-medium's batch, its refresh
+#: sub-batch, gpt2-small's batch
+CELL_ATTN = {"gpt2m": (8, 16, 16, 1024, 1024, 64),
+             "gpt2m_refresh": (4, 16, 16, 1024, 1024, 64),
+             "gpt2s": (16, 12, 12, 1024, 1024, 64)}
+
+
+@pytest.mark.parametrize("shape", CELL_ATTN.values(), ids=CELL_ATTN.keys())
+def test_attn_ranking_counts_grid_steps(monkeypatch, shape):
+    """With v5e peaks the cost model charges each grid step
+    (``ATTN_STEP_S``), so the ranking puts fewer grid steps first: the
+    pick has fewer than 256 x 256 (the pick before the step term), and
+    among candidates with the same in-band tile area the one with fewer
+    steps ranks first.  512 x 512 was the fastest of ten tilings timed on
+    a v5e at these shapes."""
+    _rank_as_v5e(monkeypatch)
+    kw = dict(bytes_el=2, causal=True)
+
+    def steps(bq, bk):
+        return sum(p.steps for p in autotune._attn_plans(
+            *shape, bq, bk, "skip", **kw))
+
+    def pred(bq, bk):
+        return autotune.attn_predict_seconds(*shape, bq, bk, "skip",
+                                             interpret=False, **kw)
+
+    t = autotune.get_tuned_attn(*shape, dtype="bfloat16", causal=True,
+                                softcap=None, interpret=False)
+    assert (t.bq, t.bk, t.schedule) == (512, 512, "skip")
+    assert steps(t.bq, t.bk) < steps(256, 256)
+
+    def area(bq, bk):
+        plan = autotune._attn_plans(*shape, bq, bk, "skip", **kw)[0]
+        return bq * bk * (plan.tiles_full + plan.tiles_masked)
+
+    # same q-blocks and the same walked area, dkv's grid twice as long:
+    # the candidate with fewer steps ranks first, by exactly their cost
+    for few, many in (((256, 256), (256, 128)), ((512, 512), (512, 256))):
+        assert area(*few) == area(*many)
+        extra = steps(*many) - steps(*few)
+        assert extra > 0
+        np.testing.assert_allclose(pred(*many) - pred(*few),
+                                   extra * autotune.ATTN_STEP_S, rtol=1e-9)
+    cands = autotune.attn_candidate_blocks(1024, 1024, 64, bytes_el=2,
+                                           interpret=False)
+    assert all(bq % 128 == 0 and bk % 128 == 0 for bq, bk, _ in cands)
+
+
+@pytest.mark.parametrize("S", [1024, 2000])
+def test_attn_q_blocks_slice_lse_lanes(S):
+    """On a TPU a q-block offsets the lse/delta rows along lanes: every
+    candidate's is a multiple of 128, or the whole sequence where 128 does
+    not divide it."""
+    cands = autotune.attn_candidate_blocks(S, S, 64, bytes_el=2,
+                                           interpret=False)
+    assert cands
+    assert all(bq % 128 == 0 if S % 128 == 0 else bq == S
+               for bq, _, _ in cands)
+
+
 def test_real_backend_ranking_needs_known_chip():
     """Peaks come from a table keyed by device_kind: an unknown chip is an
     error, never silently ranked with another chip's numbers."""

@@ -318,3 +318,158 @@ def test_train_attention_cross_kv_override():
                         impl="flash")
     np.testing.assert_allclose(np.asarray(b), np.asarray(a),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the band walk inside the kernel (grid plan, tile classes, chunks)
+
+
+def _grads(q, k, v, g, window, **kw):
+    """(o, dq, dk, dv) through the custom_vjp path, the window traced."""
+    def f(q, k, v, w):
+        o = flash_attention(q, k, v, window=w, **kw)
+        return (o.astype(jnp.float32) * g).sum(), o
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        f, argnums=(0, 1, 2), has_aux=True))(
+            q, k, v, jnp.asarray(window or (1 << 30), jnp.int32))
+    return (o, *grads)
+
+
+def _oracle(q, k, v, g, window, *, causal, q_offset, softcap=None):
+    kw = dict(causal=causal, window=window, q_offset=q_offset,
+              softcap=softcap)
+    o, _ = flash_attention_ref(q, k, v, **kw)
+    return (o, *flash_attention_grads_ref(q, k, v, g, **kw))
+
+
+BAND_CASES = {
+    # B, H, Hkv, Sq, Sk, hd, bq, bk, causal, window, q_offset
+    "window_mid_sequence": (1, 2, 1, 256, 256, 32, 32, 32, True, 70, 0),
+    "cross_sq_ne_sk_q_offset": (1, 2, 2, 64, 192, 32, 32, 64, True, None,
+                                128),
+    "bidirectional_sq_ne_sk_q_offset": (1, 2, 1, 96, 160, 32, 32, 32, False,
+                                        None, 32),
+    "gqa_group2": (1, 4, 2, 128, 128, 32, 32, 32, True, 48, 0),
+    "gqa_group4": (1, 8, 2, 128, 128, 32, 32, 64, True, None, 0),
+}
+
+
+@pytest.mark.parametrize("case", BAND_CASES.values(), ids=BAND_CASES.keys())
+def test_band_walk_parity(case):
+    """"skip" walks the band inside the kernel: a band that starts
+    mid-sequence (traced window, lo > 0), Sq != Sk with q_offset > 0, and
+    GQA groups through dkv's (group, q-block) loop all match the oracle,
+    and match "dense", which masks every tile."""
+    B, H, Hkv, Sq, Sk, hd, bq, bk, causal, window, qoff = case
+    q, k, v = _qkv(jax.random.PRNGKey(Sq + 3 * Sk), B, H, Hkv, Sq, Sk, hd)
+    g = jax.random.normal(jax.random.PRNGKey(1), q.shape) * 0.5
+    kw = dict(causal=causal, q_offset=qoff, block_q=bq, block_k=bk)
+    skip = _grads(q, k, v, g, window, schedule="skip", **kw)
+    dense = _grads(q, k, v, g, window, schedule="dense", **kw)
+    want = _oracle(q, k, v, g, window, causal=causal, q_offset=qoff)
+    for name, s, d, w in zip(("o", "dq", "dk", "dv"), skip, dense, want):
+        np.testing.assert_allclose(np.asarray(s), np.asarray(w),
+                                   atol=F32_TOL, err_msg=name)
+        np.testing.assert_allclose(np.asarray(s), np.asarray(d),
+                                   atol=F32_TOL, err_msg=name)
+
+
+def test_major_chunk_fallback(monkeypatch):
+    """When the resident head would not fit the VMEM budget, K/V (fwd, dq)
+    and Q/dO/lse/delta (dkv) are cut into major chunks that stay on the
+    grid; a traced window exercises the chunk index maps' band clamp."""
+    from repro.kernels import flash_attention as fa
+    B, H, Hkv, S, hd, bq, bk = 1, 4, 2, 256, 32, 32, 128
+    # fp32 rows of two operands, lane-padded: 1 KiB; two 128-row buffers
+    monkeypatch.setattr(fa, "RESIDENT_VMEM_BYTES", 2 * 128 * 1024)
+    plans = {kern: fa.attn_grid_plan(kern, B, H, Hkv, S, S, hd, block_q=bq,
+                                      block_k=bk, itemsize=4)
+             for kern in ("fwd", "dkv")}
+    assert plans["fwd"].grid[3] == 2 and plans["fwd"].block_major == 128
+    assert plans["dkv"].grid[3] == S // bq
+    q, k, v = _qkv(jax.random.PRNGKey(4), B, H, Hkv, S, S, hd)
+    g = jax.random.normal(jax.random.PRNGKey(2), q.shape) * 0.5
+    for window in (None, 100):
+        got = _grads(q, k, v, g, window, block_q=bq, block_k=bk,
+                     schedule="skip")
+        want = _oracle(q, k, v, g, window, causal=True, q_offset=0)
+        for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=F32_TOL,
+                                       err_msg=f"{name} w={window}")
+
+
+def test_grid_plan_at_gpt2_medium():
+    """gpt2m's (8, 16, 1024, 64) at 256 x 256: the parent's grid had
+    8 * 16 * 4 * 4 = 2048 steps per kernel call; the band walk leaves 512,
+    walking 1280 in-band tiles per call of which the 512 diagonal ones are
+    masked."""
+    from repro.kernels.flash_attention import attn_grid_plan
+    for kern in ("fwd", "dq", "dkv"):
+        plan = attn_grid_plan(kern, 8, 16, 16, 1024, 1024, 64, block_q=256,
+                              block_k=256, itemsize=2)
+        assert plan.steps == 512 < 2048, (kern, plan)
+        assert plan.block_major == 1024
+        assert plan.tiles_full + plan.tiles_masked == 1280
+        assert plan.tiles_masked == 512
+    dense = attn_grid_plan("fwd", 8, 16, 16, 1024, 1024, 64, block_q=256,
+                           block_k=256, itemsize=2, schedule="dense")
+    assert (dense.tiles_full, dense.tiles_masked) == (0, 2048)
+
+
+def test_grid_plan_counters():
+    """The wrappers add their plans to the trace-time counters."""
+    q, k, v = _qkv(jax.random.PRNGKey(0), 1, 2, 1, 128, 128, 32)
+    before = {k_: KERNEL_CALLS.get(k_, 0) for k_ in
+              ("attn_grid_steps", "attn_tiles_full", "attn_tiles_masked")}
+    jax.grad(lambda q: flash_attention(
+        q, k, v, block_q=32, block_k=32,
+        schedule="skip").astype(jnp.float32).sum())(q)
+    # three calls (fwd, dq, dkv) of 4 q-blocks x 2 heads, or 4 k-blocks
+    # x 1 KV head; 10 in-band tiles per head, 4 of them on the diagonal
+    assert KERNEL_CALLS["attn_grid_steps"] - before["attn_grid_steps"] \
+        == 8 + 8 + 4
+    assert KERNEL_CALLS["attn_tiles_full"] - before["attn_tiles_full"] \
+        == 3 * 2 * 6
+    assert KERNEL_CALLS["attn_tiles_masked"] - before["attn_tiles_masked"] \
+        == 3 * 2 * 4
+
+
+@settings(max_examples=40, deadline=None)
+@given(bq=st.sampled_from([8, 16, 24]), bk=st.sampled_from([8, 16, 24]),
+       n=st.integers(1, 4), m=st.integers(1, 4), causal=st.booleans(),
+       window=st.one_of(st.none(), st.integers(1, 80)),
+       q_offset=st.integers(0, 40))
+def test_walk_classifies_tiles(bq, bk, n, m, causal, window, q_offset):
+    """Against the mask itself: both walks (fwd/dq over KV tiles of a
+    q-block, dkv over q-tiles of a KV block) visit every tile holding an
+    attended pair, and call a tile unmasked iff all its pairs attend."""
+    from repro.kernels import flash_attention as fa
+    Sq, Sk = n * bq, m * bk
+    win = window or fa.WINDOW_NONE
+    band = dict(causal=causal, q_offset=q_offset, block_q=bq, block_k=bk)
+    qpos = q_offset + np.arange(Sq)[:, None]
+    kpos = np.arange(Sk)[None, :]
+    att = kpos > qpos - win
+    if causal:
+        att &= kpos <= qpos
+    tiles = att.reshape(n, bq, m, bk)
+    any_, all_ = tiles.any(axis=(1, 3)), tiles.all(axis=(1, 3))
+
+    def classes(ranges, size):
+        got = np.zeros(size, int)          # 0 skipped, 1 masked, 2 full
+        for start, stop, masked in ranges:
+            got[int(start):int(stop)] = 1 if masked else 2
+        return got
+
+    for i in range(n):
+        got = classes(fa._walk(fa._kv_band(i, win, n_k=m, xp=np, **band),
+                               fa._kv_full(i, win, n_k=m, **band), 0, m,
+                               "skip", xp=np), m)
+        assert np.all(got[any_[i]] > 0) and np.all((got == 2) == all_[i])
+    for j in range(m):
+        got = classes(fa._walk(fa._q_band(j, win, n_q=n, xp=np, **band),
+                               fa._q_full(j, win, n_q=n, **band), 0, n,
+                               "skip", xp=np), n)
+        assert np.all(got[any_[:, j]] > 0) and \
+            np.all((got == 2) == all_[:, j])

@@ -15,6 +15,7 @@ not be read back without a chip).
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -123,6 +124,63 @@ def test_flash_backward_dq_dkv(chip):
     calls = fused_ce.kernel_calls()
     assert calls["attn_fwd"] and calls["attn_bwd_dq"] and \
         calls["attn_bwd_dkv"]
+
+
+#: (B, H, S, hd) of the two benchmark cells' attention, and a sequence that
+#: is no multiple of 128 (its q-blocks cannot slice the rows along lanes)
+FLASH_SHAPES = {"gpt2-small": (B, H, S, HD), "gpt2-medium": (8, 16, 1024, 64),
+                "seq-2000": (2, 4, 2000, 64)}
+
+
+def _f32_rows(hlo_type):
+    """The rank-4 f32 arrays of an HLO type that hold one number per query
+    row (lse, delta), each as (dims, layout)."""
+    for dims, layout in re.findall(r"f32\[([\d,]+)\]\{([^}]*)\}", hlo_type):
+        dims = tuple(map(int, dims.split(",")))
+        if len(dims) == 4 and 1 in dims[2:]:
+            yield dims, layout
+
+
+def _hbm_bytes(dims, layout):
+    """Bytes of an f32 array in its layout: the tiling pads the two most
+    minor dimensions to multiples of the first tile's shape."""
+    order, _, tiling = layout.partition(":")
+    tile = re.match(r"T\((\d+),(\d+)\)", tiling)
+    padded = list(dims)
+    if tile:
+        minor = [int(d) for d in order.split(",")]
+        for d, t in ((minor[0], int(tile.group(2))),
+                     (minor[1], int(tile.group(1)))):
+            padded[d] = -(-padded[d] // t) * t
+    return 4 * math.prod(padded)
+
+
+@pytest.mark.parametrize("shape", FLASH_SHAPES.values(),
+                         ids=FLASH_SHAPES.keys())
+def test_flash_rows_lane_dense(chip, shape):
+    """Flash forward + backward at each cell's widths: the lse the forward
+    writes and the lse/delta the dq and dkv kernels read are laid out in
+    HBM within twice their data bytes (a (B, H, S, 1) column pads its unit
+    lane axis to 128)."""
+    def loss(q, k, v, g):
+        o = flash_attention.flash_attention(q, k, v, interpret=False)
+        return (o.astype(jnp.float32) * g).sum()
+    qkv = [_spec(shape, jnp.bfloat16, chip)] * 3
+    _, text = _compile(jax.grad(loss, argnums=(0, 1, 2)), *qkv,
+                       _spec(shape, jnp.float32, chip))
+    types = dict(re.findall(r"(%[\w.\-]+) = (\S+) ", text))
+    rows = []
+    for call, ty, operands in re.findall(
+            r"(%[\w.\-]+) = (\(.*?\)|\S+) custom-call\(([^)]*)\).*"
+            r"tpu_custom_call", text):
+        rows += [(call, r) for r in _f32_rows(ty)]
+        for op in re.findall(r"%[\w.\-]+", operands):
+            rows += [(call, r) for r in _f32_rows(types.get(op, ""))]
+    # the forward's lse; lse and delta into each of dq and dkv
+    assert len(rows) >= 5, rows
+    for call, (dims, layout) in rows:
+        assert _hbm_bytes(dims, layout) <= 2 * 4 * math.prod(dims), \
+            (call, dims, layout)
 
 
 def _ce_args(chip):
