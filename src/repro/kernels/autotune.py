@@ -8,19 +8,28 @@ combined backward schedule is only legal on single-axis grids, and in
 interpret mode per-grid-cell dispatch overhead dwarfs the arithmetic).
 This module resolves it in three stages:
 
-1. **candidates** — every (bn, bv) with bn | N (multiple of 8), bv | Vp
-   (multiple of 128), filtered by a VMEM working-set budget (real TPU) or
-   a tile-size sanity cap (interpret) and by the logits-residency cap
+1. **candidates** — every (bn, bv) with bn | N (multiple of 8) and bv a
+   multiple of 128 up to Vp — dividing Vp or not: the kernels sweep
+   ``cdiv(Vp, bv)`` chunks and mask a ragged last one — filtered, on a
+   real TPU, by the kernels' VMEM working set (``fused_ce.vmem_bytes``
+   against the scoped VMEM each CE kernel requests,
+   ``fused_ce.VMEM_LIMIT_BYTES``) and the measured logits-tile cap
+   ``CE_TILE_ELEMS``, in interpret mode by a tile-size sanity cap, and
+   always by the logits-residency cap
    ``bn * bv <= max(N * Vp / 2, 8 * 128)`` so the tuner can never pick the
    degenerate whole-[N, V]-tile config that the memory audit exists to
    forbid.  Each tiling carries its legal schedules ("fused" iff one grid
    axis is 1).
 2. **predict** — the analytic cost model (``predict_seconds``): per-pass
    ``max(flops/peak_flops, bytes/hbm_bw)`` with the peaks of the chip the
-   process runs on (``launch/roofline.device_peaks``) for a real backend;
-   for interpret mode a CPU model ``flops/CPU_FLOPS + cells * CELL_OVERHEAD_S`` (the
-   interpreter unrolls the grid, so cell count — not bandwidth — is the
-   first-order term).  Candidates are ranked by predicted time.
+   process runs on (``launch/roofline.device_peaks``), plus the cost of
+   each grid step of each kernel (``ce_step_seconds``: ``CE_STEP_S`` and
+   the (bn, D) hidden tile every step norms and casts again), for a real
+   backend; a ragged last chunk is charged its full width, so a dividing
+   chunk wins where one is about as wide.  For interpret mode a CPU model
+   ``flops/CPU_FLOPS + cells * CELL_OVERHEAD_S`` (the interpreter unrolls
+   the grid, so cell count — not bandwidth — is the first-order term).
+   Candidates are ranked by predicted time.
 3. **measure (optional refinement)** — ``measure=True`` times
    ``value_and_grad`` of the real kernel at the top ``MEASURE_TOP_K``
    predicted candidates and keeps the fastest.  Only *measured* winners are
@@ -46,7 +55,7 @@ import numpy as np
 
 from .. import CHECKOUT_DIR
 
-CACHE_VERSION = 1
+CACHE_VERSION = 2                 # 2: ragged vocabulary chunks, step cost
 MEASURE_TOP_K = 4
 MEASURE_REPS = 3
 
@@ -57,9 +66,24 @@ MEASURE_REPS = 3
 CPU_FLOPS = 5.0e10
 CELL_OVERHEAD_S = 2.5e-3
 
-# VMEM working-set budget for a real TPU backend (per-core VMEM is
-# ~16 MiB; leave headroom for pipelining double-buffers)
+# flash attention's VMEM working-set budget on a real TPU (the default
+# scoped VMEM is 16 MiB; leave headroom for pipelining double-buffers).
+# The CE kernels request their own scope (fused_ce.VMEM_LIMIT_BYTES).
 VMEM_BUDGET_BYTES = 12 << 20
+
+# the cost of one CE kernel grid step on a TPU beyond its products'
+# roofline, fitted to value_and_grad timings of 18 tilings at gpt2-small
+# and gpt2-medium shapes on a v5e: a fixed part (the pipeline's step, the
+# (bn, 1) running-statistic columns read and written) and a part per
+# element of the (bn, D) hidden tile, which every step norms and casts
+# again whatever its chunk's width
+CE_STEP_S = 0.35e-6
+CE_HIDDEN_ELEM_S = 2.3e-12
+# largest fp32 logits tile on a TPU: past 2^20 elements (4 MiB) the
+# backward kernels ran 30-55% slower per unit of work on a v5e, at
+# (1024, 1536) and (2048, 1024) tiles of gpt2-small and (1024, 1152) and
+# (2048, 512) of gpt2-medium, though within the requested VMEM
+CE_TILE_ELEMS = 1 << 20
 # interpret mode has no VMEM, but a tile of jnp intermediates still costs
 # host RAM — cap the fp32 logits tile at 2^24 elements (64 MiB)
 INTERPRET_TILE_ELEMS = 1 << 24
@@ -124,26 +148,29 @@ def _divisors(n: int, quantum: int, cap: int) -> list:
 def candidate_blocks(N: int, D: int, Vp: int, *, bytes_h: int,
                      interpret: bool) -> list:
     """All legal (bn, bv, schedule) triples for the shape, budget- and
-    residency-filtered.  ``schedule="fused"`` appears only for tilings
-    where one grid axis is 1 (the combined backward kernel's legality
-    condition — no non-consecutive output-block revisits)."""
+    residency-filtered.  ``bn`` divides N; ``bv`` is any whole number of
+    128-lane groups up to Vp, the last chunk ragged where it does not
+    divide Vp.  On a real backend the logits tile holds at most
+    ``CE_TILE_ELEMS`` and the kernels' working set
+    (``fused_ce.vmem_bytes``) must fit the VMEM they request
+    (``fused_ce.VMEM_LIMIT_BYTES``).  ``schedule="fused"`` appears only
+    for tilings where one grid axis is 1 (the combined backward kernel's
+    legality condition — no non-consecutive output-block revisits)."""
+    from .fused_ce import LANES, VMEM_LIMIT_BYTES, vmem_bytes
     cap = residency_cap(N, Vp)
     cands = []
     for bn in _divisors(N, 8, max(N, 8)):
-        for bv in _divisors(Vp, 128, Vp):
+        for bv in range(LANES, Vp + 1, LANES):
             if bn * bv > cap:
                 continue
             if interpret:
                 if bn * bv > INTERPRET_TILE_ELEMS:
                     continue
-            else:
-                # working set: h tile + w tile + fp32 logits tile + the
-                # larger backward scratch, double-buffered inputs
-                ws = 2 * (bn * D * bytes_h + bv * D * 4) \
-                    + bn * bv * 4 + max(bn, bv) * D * 4
-                if ws > VMEM_BUDGET_BYTES:
-                    continue
-            n_r, n_v = N // bn, Vp // bv
+            elif (bn * bv > CE_TILE_ELEMS
+                  or vmem_bytes(bn, bv, D, bytes_h=bytes_h)
+                  > VMEM_LIMIT_BYTES):
+                continue
+            n_r, n_v = N // bn, -(-Vp // bv)
             cands.append((bn, bv, "split"))
             if n_r == 1 or n_v == 1:
                 cands.append((bn, bv, "fused"))
@@ -156,17 +183,21 @@ def predict_seconds(N: int, D: int, Vp: int, bn: int, bv: int,
     """Analytic cost of one fwd+bwd of the fused NLL at this tiling.
 
     Real backend: per-pass ``max(compute, memory)`` against the
-    ``launch/roofline.py`` constants.  The memory terms are exact DMA
-    counts from the BlockSpecs: the forward re-reads the full W once per
-    row block (``n_r * w_bytes``), the split backward adds a second full
-    logits recompute plus an h re-stream per vocab chunk, and the fused
-    schedule reads each operand exactly once.  Interpret: grid cells are
-    unrolled by the interpreter, so cost = flops/CPU_FLOPS + cells *
-    CELL_OVERHEAD_S (memory ignored — everything is host RAM)."""
-    n_r, n_v = N // bn, Vp // bv
-    mm = 2.0 * N * D * Vp                    # one full-projection matmul
+    ``launch/roofline.py`` constants, plus ``ce_step_seconds`` for every
+    grid step of every kernel.  The memory terms are DMA counts from the
+    BlockSpecs: the forward re-reads the full W once per row block
+    (``n_r * w_bytes``), the split backward adds a second full logits
+    recompute plus an h re-stream per vocab chunk, and the fused schedule
+    reads each operand exactly once.  A ragged last chunk is charged in
+    full, so its columns past Vp cost their products and bytes: a chunk
+    that divides Vp wins where one is about as wide.  Interpret: grid
+    cells are unrolled by the interpreter, so cost = flops/CPU_FLOPS +
+    cells * CELL_OVERHEAD_S (memory ignored — everything is host RAM)."""
+    n_r, n_v = N // bn, -(-Vp // bv)
+    vc = n_v * bv                            # columns swept, tail included
+    mm = 2.0 * N * D * vc                    # one full-projection matmul
     h_b = N * D * bytes_h
-    w_b = Vp * D * bytes_w
+    w_b = vc * D * bytes_w
 
     if schedule == "fused":
         passes = [
@@ -185,7 +216,14 @@ def predict_seconds(N: int, D: int, Vp: int, bn: int, bv: int,
     if interpret:
         flops = sum(f for f, _ in passes)
         return flops / CPU_FLOPS + cells * CELL_OVERHEAD_S
-    return _roofline_seconds(passes)
+    return _roofline_seconds(passes) + cells * ce_step_seconds(bn, D)
+
+
+def ce_step_seconds(bn: int, D: int) -> float:
+    """Cost of one CE kernel grid step on a TPU beyond its products'
+    roofline: ``CE_STEP_S`` plus ``CE_HIDDEN_ELEM_S`` per element of the
+    (bn, D) hidden tile the step norms and casts."""
+    return CE_STEP_S + CE_HIDDEN_ELEM_S * bn * D
 
 
 def _roofline_seconds(passes) -> float:

@@ -46,7 +46,20 @@ Block sizes: ``block_n``/``block_v`` default to ``None``, which resolves
 through the shape-keyed autotuner (``kernels/autotune.py`` — roofline-model
 search with optional measured refinement and a persistent cache; the old
 hardcoded ``DEFAULT_BN``/``DEFAULT_BV`` survive only as cache-miss seeds).
-Explicit block sizes bypass the tuner (kernel unit tests).
+Explicit block sizes bypass the tuner (kernel unit tests, measured
+tuning).  A vocabulary chunk is any whole number of 128-lane groups: the
+grid sweeps ``n_v = cdiv(Vp, bv)`` chunks, and where ``bv`` does not
+divide Vp the last W block runs past the array.  Only chunks that hold a
+column at or past ``vocab`` take the masked path (``_by_chunk``): W's tail
+rows / columns are zeroed before any product (a partial block's tail is
+whatever lies past the array, NaN in interpret mode) and the tail's
+logits are forced to NEG_INF; the write-back drops a dW block's rows past
+Vp.  Full chunks pay nothing.  Each ``pallas_call`` requests
+``VMEM_LIMIT_BYTES`` of scoped VMEM, the budget the tuner fits the
+working set (``vmem_bytes``) to.  Trace-time counters in ``KERNEL_CALLS``:
+calls per kernel, ``ce_grid_steps`` (summed over calls),
+``ce_ragged_calls``, and one ``ce_tiling <N>x<D>x<Vp> bn.. bv.. <schedule>
+steps..`` key per resolved call shape, its grid steps per kernel call.
 
 Compute convention (matches ``models.layers.unembed``): W is cast to the
 hidden dtype, the projection accumulates in fp32
@@ -81,6 +94,11 @@ from ..distributed.sharding import per_device
 DEFAULT_BN = 256    # rows (B*T positions) per tile (autotuner seed)
 DEFAULT_BV = 1024   # vocab columns per chunk (multiple of 128; seed)
 NEG_INF = -1e30
+#: scoped VMEM each CE kernel may use (``vmem_limit_bytes``; a v5e core
+#: has 128 MiB, the compiler's default scope is 16 MiB), and the
+#: autotuner's budget for ``vmem_bytes``
+VMEM_LIMIT_BYTES = 64 << 20
+LANES = 128
 
 _f32 = jnp.float32
 _u32 = jnp.uint32
@@ -268,24 +286,52 @@ def _label_logit_tile(s, lab, j, bn, bv, interpret):
     return jnp.where(hit, s, 0.0).sum(-1)
 
 
+def _by_chunk(j, bv, vocab, n_v, body):
+    """Run ``body(masked)`` for vocabulary chunk ``j``: ``masked=False``
+    on chunks wholly inside the vocabulary, ``True`` on those that hold a
+    column at or past ``vocab`` — padded-vocab columns, and the columns a
+    ragged last chunk (``n_v * bv > Vp``) reads past the array.  With
+    chunks of whole 128-lane groups only the last chunk can hold such a
+    column.  The split is static where the grid allows, else a
+    ``pl.when`` pair, so full chunks pay nothing for the mask."""
+    first = vocab // bv
+    if first >= n_v:
+        body(False)
+    elif first == 0:
+        body(True)
+    else:
+        pl.when(j < first)(lambda: body(False))
+        pl.when(j >= first)(lambda: body(True))
+
+
+def _chunk(hn, w_ref, j, *, bn, bv, vocab, transpose_w, softcap, masked):
+    """One vocabulary chunk: ``(w, s, dcap, valid)``, the W tile and its
+    logits ``s`` in the unembed convention (``_tile_logits``).  On a
+    masked chunk W's rows (tied) or columns (untied) at or past ``vocab``
+    are zeroed BEFORE any product — a ragged last block reads whatever
+    lies past the array (NaN in interpret mode), and ``d`` = 0 there
+    would still make 0 * NaN = NaN in d_hidden — and those columns'
+    logits are forced to NEG_INF (``valid`` marks the rest; None on a
+    full chunk)."""
+    w = w_ref[...]
+    if masked:
+        axis = 1 if transpose_w else 0
+        rows = j * bv + jax.lax.broadcasted_iota(jnp.int32, w.shape, axis)
+        w = jnp.where(rows < vocab, w, jnp.zeros_like(w))
+    z, dcap = _tile_logits(hn, w, transpose_w, softcap)
+    if not masked:
+        return w, z, dcap, None
+    valid = _tile_cols(j, bn, bv) < vocab
+    return w, jnp.where(valid, z, NEG_INF), dcap, valid
+
+
 # ---------------------------------------------------------------------------
 # forward kernels
 
 
-def _masked_tile(z, j, bn, bv, vocab, vp):
-    """(s, valid): logits with padded-vocab columns forced to NEG_INF.
-    Static no-op when the vocab needs no padding (vocab == vp) — the mask
-    materializes two (bn, bv) temporaries, real money in interpret mode."""
-    if vocab == vp:
-        return z, None
-    cols = _tile_cols(j, bn, bv)
-    valid = cols < vocab
-    return jnp.where(valid, z, NEG_INF), valid
-
-
 def _ce_fwd_kernel(np_ref, lab_ref, h_ref, w_ref, lse_out, ll_out,
                    m_scr, l_scr, ll_scr, *,
-                   bn, bv, vocab, vp, n_v, transpose_w, softcap, norm, eps,
+                   bn, bv, vocab, n_v, transpose_w, softcap, norm, eps,
                    interpret):
     j = pl.program_id(1)
 
@@ -296,16 +342,19 @@ def _ce_fwd_kernel(np_ref, lab_ref, h_ref, w_ref, lse_out, ll_out,
         ll_scr[...] = jnp.zeros_like(ll_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    z, _ = _tile_logits(hn, w_ref[...], transpose_w, softcap)
-    s, valid = _masked_tile(z, j, bn, bv, vocab, vp)
 
-    m_new, l_new = online_lse_step(m_scr[...][:, 0], l_scr[...][:, 0], s,
-                                   valid)
-    m_scr[...] = m_new[:, None]
-    l_scr[...] = l_new[:, None]
+    def chunk(masked):
+        _, s, _, valid = _chunk(hn, w_ref, j, bn=bn, bv=bv, vocab=vocab,
+                                transpose_w=transpose_w, softcap=softcap,
+                                masked=masked)
+        m_new, l_new = online_lse_step(m_scr[...][:, 0], l_scr[...][:, 0],
+                                       s, valid)
+        m_scr[...] = m_new[:, None]
+        l_scr[...] = l_new[:, None]
+        ll_scr[...] += _label_logit_tile(s, lab_ref[...][:, 0], j, bn, bv,
+                                         interpret)[:, None]
 
-    ll_scr[...] += _label_logit_tile(s, lab_ref[...][:, 0], j, bn, bv,
-                                     interpret)[:, None]
+    _by_chunk(j, bv, vocab, n_v, chunk)
 
     @pl.when(j == n_v - 1)
     def _flush():
@@ -315,7 +364,7 @@ def _ce_fwd_kernel(np_ref, lab_ref, h_ref, w_ref, lse_out, ll_out,
 
 def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
                           yhat_out, m_scr, l_scr, zm_scr, zi_scr, zl_scr, *,
-                          bn, bv, vocab, vp, n_v, transpose_w, softcap, norm,
+                          bn, bv, vocab, n_v, transpose_w, softcap, norm,
                           eps):
     i = pl.program_id(0)
     j = pl.program_id(1)
@@ -329,30 +378,34 @@ def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
         zl_scr[...] = jnp.zeros_like(zl_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    z, _ = _tile_logits(hn, w_ref[...], transpose_w, softcap)
-    cols = _tile_cols(j, bn, bv)
-    valid = None if vocab == vp else cols < vocab
-    s = z if valid is None else jnp.where(valid, z, NEG_INF)
 
-    m_new, l_new = online_lse_step(m_scr[...][:, 0], l_scr[...][:, 0], s,
-                                   valid)
-    m_scr[...] = m_new[:, None]
-    l_scr[...] = l_new[:, None]
+    def chunk(masked):
+        _, s, _, valid = _chunk(hn, w_ref, j, bn=bn, bv=bv, vocab=vocab,
+                                transpose_w=transpose_w, softcap=softcap,
+                                masked=masked)
+        m_new, l_new = online_lse_step(m_scr[...][:, 0], l_scr[...][:, 0],
+                                       s, valid)
+        m_scr[...] = m_new[:, None]
+        l_scr[...] = l_new[:, None]
 
-    # online Gumbel-argmax: perturb this chunk, keep the running best,
-    # remembering the winning column's RAW logit so the sampled-label NLL
-    # needs no second pass
-    # global row index: the seed operand's third word is the first row's
-    rows = (i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, bv), 0)
-            ).astype(_u32) + seed_ref[2]
-    g = hash_gumbel((seed_ref[0], seed_ref[1]), rows, cols)
-    zp = s + g if valid is None else jnp.where(valid, s + g, NEG_INF)
-    zm, zi, zl = online_argmax_step(
-        (zm_scr[...][:, 0], zi_scr[...][:, 0], zl_scr[...][:, 0]),
-        s, zp, j * bv)
-    zm_scr[...] = zm[:, None]
-    zi_scr[...] = zi[:, None]
-    zl_scr[...] = zl[:, None]
+        # online Gumbel-argmax: perturb this chunk, keep the running best,
+        # remembering the winning column's RAW logit so the sampled-label
+        # NLL needs no second pass; the noise is keyed on global columns
+        # and rows (the seed operand's third word is the first row's), so
+        # any chunking draws the same samples
+        rows = (i * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, bv), 0)
+                ).astype(_u32) + seed_ref[2]
+        g = hash_gumbel((seed_ref[0], seed_ref[1]), rows,
+                        _tile_cols(j, bn, bv))
+        zp = s + g if valid is None else jnp.where(valid, s + g, NEG_INF)
+        zm, zi, zl = online_argmax_step(
+            (zm_scr[...][:, 0], zi_scr[...][:, 0], zl_scr[...][:, 0]),
+            s, zp, j * bv)
+        zm_scr[...] = zm[:, None]
+        zi_scr[...] = zi[:, None]
+        zl_scr[...] = zl[:, None]
+
+    _by_chunk(j, bv, vocab, n_v, chunk)
 
     @pl.when(j == n_v - 1)
     def _flush():
@@ -365,16 +418,13 @@ def _ce_fwd_sample_kernel(np_ref, seed_ref, h_ref, w_ref, lse_out, ll_out,
 # backward kernels (shared by the labeled and sampled paths)
 
 
-def _dlogits_tile(hn, w, lab, rs, lse, j, *, bn, bv, vocab, vp, transpose_w,
-                  softcap, interpret):
-    """Recompute one logits tile (from the already-normed hidden tile) and
-    return d_logits_raw (bn, bv) fp32: ``(softmax - onehot(lab)) *
-    rowscale``, softcap chain rule applied, exactly zero on padded columns
-    (p = 0 and onehot = 0 there).  Interpret mode subtracts the onehot
-    term with a (bn,)-sized scatter-add instead of materializing the
-    (bn, bv) compare (cheap on CPU, not Mosaic-lowerable on TPU)."""
-    z, dcap = _tile_logits(hn, w, transpose_w, softcap)
-    s, _ = _masked_tile(z, j, bn, bv, vocab, vp)
+def _dlogits_tile(s, dcap, lab, rs, lse, j, *, bn, bv, interpret):
+    """d_logits_raw (bn, bv) fp32 of one chunk from its logits ``s``
+    (``_chunk``): ``(softmax - onehot(lab)) * rowscale``, softcap chain
+    rule applied, exactly zero on columns past the vocabulary (p = 0 and
+    onehot = 0 there).  Interpret mode subtracts the onehot term with a
+    (bn,)-sized scatter-add instead of materializing the (bn, bv) compare
+    (cheap on CPU, not Mosaic-lowerable on TPU)."""
     p = jnp.exp(s - lse[:, None])
     if interpret:
         d = p * rs[:, None]
@@ -390,8 +440,38 @@ def _dlogits_tile(hn, w, lab, rs, lse, j, *, bn, bv, vocab, vp, transpose_w,
     return d
 
 
+def _chunk_dlogits(hn, w_ref, lab_ref, rs_ref, lse_ref, j, *, bn, bv, vocab,
+                   transpose_w, softcap, interpret, masked):
+    """(w, d): one chunk's W tile (tail zeroed on a masked chunk) and its
+    recomputed d_logits, shared by every backward kernel."""
+    w, s, dcap, _ = _chunk(hn, w_ref, j, bn=bn, bv=bv, vocab=vocab,
+                           transpose_w=transpose_w, softcap=softcap,
+                           masked=masked)
+    return w, _dlogits_tile(s, dcap, lab_ref[...][:, 0], rs_ref[...][:, 0],
+                            lse_ref[...][:, 0], j, bn=bn, bv=bv,
+                            interpret=interpret)
+
+
+def _dh_update(acc, d, w, transpose_w):
+    """acc += d @ W^T for one chunk, in fp32."""
+    w32 = w.astype(_f32)
+    if transpose_w:                       # w tile (D, bv): dh = d @ w^T
+        acc[...] += jnp.dot(d, w32.T, preferred_element_type=_f32)
+    else:                                 # w tile (bv, D): dh = d @ w
+        acc[...] += jnp.dot(d, w32, preferred_element_type=_f32)
+
+
+def _dw_update(acc, d, hn, transpose_w):
+    """acc += d^T @ h for one chunk's dW tile, in fp32."""
+    h32 = hn.astype(_f32)
+    if transpose_w:                       # dW tile (D, bv) = h^T @ d
+        acc[...] += jnp.dot(h32.T, d, preferred_element_type=_f32)
+    else:                                 # dW tile (bv, D) = d^T @ h
+        acc[...] += jnp.dot(d.T, h32, preferred_element_type=_f32)
+
+
 def _ce_bwd_dh_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dh_out,
-                      acc_scr, *, bn, bv, vocab, vp, n_v, transpose_w,
+                      acc_scr, *, bn, bv, vocab, n_v, transpose_w,
                       softcap, norm, eps, interpret):
     j = pl.program_id(1)
 
@@ -400,15 +480,15 @@ def _ce_bwd_dh_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dh_out,
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
-                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
-                      transpose_w=transpose_w, softcap=softcap,
-                      interpret=interpret)
-    w32 = w_ref[...].astype(_f32)
-    if transpose_w:                       # w tile (D, bv): dh = d @ w^T
-        acc_scr[...] += jnp.dot(d, w32.T, preferred_element_type=_f32)
-    else:                                 # w tile (bv, D): dh = d @ w
-        acc_scr[...] += jnp.dot(d, w32, preferred_element_type=_f32)
+
+    def chunk(masked):
+        w, d = _chunk_dlogits(hn, w_ref, lab_ref, rs_ref, lse_ref, j, bn=bn,
+                              bv=bv, vocab=vocab, transpose_w=transpose_w,
+                              softcap=softcap, interpret=interpret,
+                              masked=masked)
+        _dh_update(acc_scr, d, w, transpose_w)
+
+    _by_chunk(j, bv, vocab, n_v, chunk)
 
     @pl.when(j == n_v - 1)
     def _flush():
@@ -416,12 +496,13 @@ def _ce_bwd_dh_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dh_out,
 
 
 def _ce_bwd_dw_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dw_out,
-                      acc_scr, *, bn, bv, vocab, vp, n_r, transpose_w,
+                      acc_scr, *, bn, bv, vocab, n_v, n_r, transpose_w,
                       softcap, norm, eps, interpret):
     # grid (chunks, rows): the dW block for chunk j accumulates across the
     # inner row sweep in an fp32 VMEM scratch (accumulating in the output
     # dtype would round the partial sum per row tile — per-mille error for
-    # bf16 weights at real tile counts) and rounds ONCE at the flush.
+    # bf16 weights at real tile counts) and rounds ONCE at the flush; a
+    # ragged last block's rows past Vp are dropped by the write-back.
     j = pl.program_id(0)
     i = pl.program_id(1)
 
@@ -430,15 +511,15 @@ def _ce_bwd_dw_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dw_out,
         acc_scr[...] = jnp.zeros_like(acc_scr[...])
 
     hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
-                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
-                      transpose_w=transpose_w, softcap=softcap,
-                      interpret=interpret)
-    h32 = hn.astype(_f32)
-    if transpose_w:                       # dW tile (D, bv) = h^T @ d
-        acc_scr[...] += jnp.dot(h32.T, d, preferred_element_type=_f32)
-    else:                                 # dW tile (bv, D) = d^T @ h
-        acc_scr[...] += jnp.dot(d.T, h32, preferred_element_type=_f32)
+
+    def chunk(masked):
+        _, d = _chunk_dlogits(hn, w_ref, lab_ref, rs_ref, lse_ref, j, bn=bn,
+                              bv=bv, vocab=vocab, transpose_w=transpose_w,
+                              softcap=softcap, interpret=interpret,
+                              masked=masked)
+        _dw_update(acc_scr, d, hn, transpose_w)
+
+    _by_chunk(j, bv, vocab, n_v, chunk)
 
     @pl.when(i == n_r - 1)
     def _flush():
@@ -447,7 +528,7 @@ def _ce_bwd_dw_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref, dw_out,
 
 def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
                          dh_out, dw_out, dh_scr, dw_scr, *,
-                         bn, bv, vocab, vp, n_r, n_v, transpose_w, softcap,
+                         bn, bv, vocab, n_r, n_v, transpose_w, softcap,
                          norm, eps, interpret):
     """Combined d_hidden + d_W in ONE sweep: the logits tile is recomputed
     once per grid step and feeds both products (the split schedule
@@ -460,14 +541,6 @@ def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
     i = pl.program_id(0)
     j = pl.program_id(1)
 
-    hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
-    d = _dlogits_tile(hn, w_ref[...], lab_ref[...][:, 0], rs_ref[...][:, 0],
-                      lse_ref[...][:, 0], j, bn=bn, bv=bv, vocab=vocab, vp=vp,
-                      transpose_w=transpose_w, softcap=softcap,
-                      interpret=interpret)
-    w32 = w_ref[...].astype(_f32)
-    h32 = hn.astype(_f32)
-
     @pl.when(j == 0)
     def _init_dh():
         dh_scr[...] = jnp.zeros_like(dh_scr[...])
@@ -476,12 +549,17 @@ def _ce_bwd_fused_kernel(np_ref, lab_ref, rs_ref, lse_ref, h_ref, w_ref,
     def _init_dw():
         dw_scr[...] = jnp.zeros_like(dw_scr[...])
 
-    if transpose_w:                       # w tile (D, bv)
-        dh_scr[...] += jnp.dot(d, w32.T, preferred_element_type=_f32)
-        dw_scr[...] += jnp.dot(h32.T, d, preferred_element_type=_f32)
-    else:                                 # w tile (bv, D)
-        dh_scr[...] += jnp.dot(d, w32, preferred_element_type=_f32)
-        dw_scr[...] += jnp.dot(d.T, h32, preferred_element_type=_f32)
+    hn = apply_norm(h_ref[...], np_ref[...], norm, eps)
+
+    def chunk(masked):
+        w, d = _chunk_dlogits(hn, w_ref, lab_ref, rs_ref, lse_ref, j, bn=bn,
+                              bv=bv, vocab=vocab, transpose_w=transpose_w,
+                              softcap=softcap, interpret=interpret,
+                              masked=masked)
+        _dh_update(dh_scr, d, w, transpose_w)
+        _dw_update(dw_scr, d, hn, transpose_w)
+
+    _by_chunk(j, bv, vocab, n_v, chunk)
 
     @pl.when(j == n_v - 1)
     def _flush_dh():
@@ -526,6 +604,19 @@ def _vp_of(w, transpose_w):
     return w.shape[1] if transpose_w else w.shape[0]
 
 
+def _call(kernel, *, grid, ragged, name, **kw):
+    """``pl.pallas_call`` with the CE kernels' scoped VMEM limit.  The
+    call's grid steps go to the trace-time counters (``ce_grid_steps``),
+    and so does a call whose last vocabulary chunk runs past Vp
+    (``ce_ragged_calls``)."""
+    KERNEL_CALLS["ce_grid_steps"] += grid[0] * grid[1]
+    KERNEL_CALLS["ce_ragged_calls"] += int(ragged)
+    return pl.pallas_call(
+        kernel, grid=grid, name=name,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES), **kw)
+
+
 def _no_normp(D, normp=None):
     return jnp.zeros((2, D), _f32) if normp is None else normp
 
@@ -534,15 +625,17 @@ def _ce_forward(h2, w, normp, labels, *, vocab, transpose_w, softcap, norm,
                 eps, bn, bv, interpret):
     KERNEL_CALLS["fwd"] += 1
     N, D = h2.shape
-    n_r, n_v = N // bn, _vp_of(w, transpose_w) // bv
+    Vp = _vp_of(w, transpose_w)
+    n_r, n_v = N // bn, pl.cdiv(Vp, bv)
     h_spec, w_spec, vec_spec, np_spec = _specs(bn, bv, D, transpose_w)
     kern = functools.partial(_ce_fwd_kernel, bn=bn, bv=bv, vocab=vocab,
-                             vp=n_v * bv, n_v=n_v, transpose_w=transpose_w,
+                             n_v=n_v, transpose_w=transpose_w,
                              softcap=softcap, norm=norm, eps=eps,
                              interpret=interpret)
-    lse, ll = pl.pallas_call(
+    lse, ll = _call(
         kern,
         grid=(n_r, n_v),
+        ragged=n_v * bv != Vp,
         in_specs=[np_spec, vec_spec, h_spec, w_spec],
         out_specs=[vec_spec, vec_spec],
         out_shape=[jax.ShapeDtypeStruct((N, 1), _f32),
@@ -558,16 +651,17 @@ def _ce_forward_sampled(h2, w, normp, seed, *, vocab, transpose_w, softcap,
                         norm, eps, bn, bv, interpret):
     KERNEL_CALLS["fwd_sample"] += 1
     N, D = h2.shape
-    n_r, n_v = N // bn, _vp_of(w, transpose_w) // bv
+    Vp = _vp_of(w, transpose_w)
+    n_r, n_v = N // bn, pl.cdiv(Vp, bv)
     h_spec, w_spec, vec_spec, np_spec = _specs(bn, bv, D, transpose_w)
     seed_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     kern = functools.partial(_ce_fwd_sample_kernel, bn=bn, bv=bv,
-                             vocab=vocab, vp=n_v * bv, n_v=n_v,
-                             transpose_w=transpose_w, softcap=softcap,
-                             norm=norm, eps=eps)
-    outs = pl.pallas_call(
+                             vocab=vocab, n_v=n_v, transpose_w=transpose_w,
+                             softcap=softcap, norm=norm, eps=eps)
+    outs = _call(
         kern,
         grid=(n_r, n_v),
+        ragged=n_v * bv != Vp,
         in_specs=[np_spec, seed_spec, h_spec, w_spec],
         out_specs=[vec_spec, vec_spec, vec_spec],
         out_shape=[jax.ShapeDtypeStruct((N, 1), _f32),
@@ -593,7 +687,7 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
     ``jax.vjp(apply_norm, ...)``."""
     N, D = h2.shape
     Vp = _vp_of(w, transpose_w)
-    n_r, n_v = N // bn, Vp // bv
+    n_r, n_v = N // bn, pl.cdiv(Vp, bv)
     dh_dtype = _f32 if norm is not None else h2.dtype
     h_spec, w_spec, vec_spec, np_spec = _specs(bn, bv, D, transpose_w)
     normp = _no_normp(D, normp)
@@ -604,12 +698,13 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
         assert n_r == 1 or n_v == 1, (n_r, n_v)
         KERNEL_CALLS["bwd_fused"] += 1
         kern = functools.partial(
-            _ce_bwd_fused_kernel, bn=bn, bv=bv, vocab=vocab, vp=Vp, n_r=n_r,
+            _ce_bwd_fused_kernel, bn=bn, bv=bv, vocab=vocab, n_r=n_r,
             n_v=n_v, transpose_w=transpose_w, softcap=softcap, norm=norm,
             eps=eps, interpret=interpret)
-        dh, dw = pl.pallas_call(
+        dh, dw = _call(
             kern,
             grid=(n_r, n_v),
+            ragged=n_v * bv != Vp,
             in_specs=[np_spec, vec_spec, vec_spec, vec_spec, h_spec, w_spec],
             out_specs=[pl.BlockSpec((bn, D), lambda i, j: (i, 0)), w_spec],
             out_shape=[jax.ShapeDtypeStruct((N, D), dh_dtype),
@@ -623,12 +718,13 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
     assert schedule == "split", schedule
     KERNEL_CALLS["bwd_split"] += 1
     kern_h = functools.partial(_ce_bwd_dh_kernel, bn=bn, bv=bv, vocab=vocab,
-                               vp=Vp, n_v=n_v, transpose_w=transpose_w,
+                               n_v=n_v, transpose_w=transpose_w,
                                softcap=softcap, norm=norm, eps=eps,
                                interpret=interpret)
-    dh = pl.pallas_call(
+    dh = _call(
         kern_h,
         grid=(n_r, n_v),
+        ragged=n_v * bv != Vp,
         in_specs=[np_spec, vec_spec, vec_spec, vec_spec, h_spec, w_spec],
         out_specs=pl.BlockSpec((bn, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, D), dh_dtype),
@@ -644,12 +740,13 @@ def _ce_backward(h2, w, normp, labels, rs, lse, *, vocab, transpose_w,
     vT_spec = pl.BlockSpec((bn, 1), lambda j, i: (i, 0))
     npT_spec = pl.BlockSpec((2, D), lambda j, i: (0, 0))
     kern_w = functools.partial(_ce_bwd_dw_kernel, bn=bn, bv=bv, vocab=vocab,
-                               vp=Vp, n_r=n_r, transpose_w=transpose_w,
+                               n_v=n_v, n_r=n_r, transpose_w=transpose_w,
                                softcap=softcap, norm=norm, eps=eps,
                                interpret=interpret)
-    dw = pl.pallas_call(
+    dw = _call(
         kern_w,
         grid=(n_v, n_r),
+        ragged=n_v * bv != Vp,
         in_specs=[npT_spec, vT_spec, vT_spec, vT_spec, hT_spec, wT_spec],
         out_specs=wT_spec,
         out_shape=jax.ShapeDtypeStruct(w.shape, w.dtype),
@@ -871,6 +968,19 @@ def _fused_nll_jvp_rule(vocab, transpose_w, softcap, bn, bv, interpret,
 # public entry points
 
 
+def vmem_bytes(bn, bv, D, *, bytes_h, bytes_w=4) -> int:
+    """The largest VMEM working set of the CE kernels at a (bn, bv)
+    tiling, the autotuner's fit test against ``VMEM_LIMIT_BYTES``: the
+    double-buffered h and W tiles, W cast to the hidden dtype, the normed
+    hidden tile in fp32, the fp32 logits tile with three temporaries of its
+    size (p, d and d's transpose), the fp32 dh or dW accumulator with its
+    double-buffered output block, and nine ``(bn, 1)`` row columns (blocks
+    and scratch), each padded to 128 lanes."""
+    return (2 * (bn * D * bytes_h + bv * D * bytes_w)
+            + bv * D * bytes_h + bn * D * 4 + 4 * bn * bv * 4
+            + 3 * max(bn, bv) * D * 4 + 9 * bn * LANES * 4)
+
+
 def _pick_block(n, want, quantum):
     """Largest multiple of ``quantum`` <= want dividing n, else (quantum,
     pad) where pad rounds n up to a quantum multiple."""
@@ -917,13 +1027,15 @@ def _row_mapped(local, hidden, w, normp, aux, rs, *, aux_rows):
 
 
 def _pick_bv(Vp, block_v, interpret=False):
-    """Vocab chunk for an *explicit* request; interpret mode (CPU CI)
-    clamps to the whole padded vocab at small Vp so an over-chunked request
-    cannot unroll a pathological number of interpreter grid cells."""
-    assert Vp % 128 == 0, f"padded vocab {Vp} not a multiple of 128"
-    if interpret and Vp // vocab_chunk(Vp, block_v, 128) > 64:
-        return vocab_chunk(Vp, max(block_v, Vp // 64), 128)
-    return vocab_chunk(Vp, block_v, 128)
+    """Vocab chunk for an *explicit* request: ``block_v`` in whole 128-lane
+    groups, within [128, Vp]; where it does not divide Vp the last chunk is
+    ragged.  Interpret mode (CPU CI) widens it so that an over-chunked
+    request cannot unroll more than 64 interpreter grid cells per row."""
+    assert Vp % LANES == 0, f"padded vocab {Vp} not a multiple of 128"
+    bv = min(max(LANES, block_v - block_v % LANES), Vp)
+    if interpret and pl.cdiv(Vp, bv) > 64:
+        bv = pl.cdiv(Vp, 64 * LANES) * LANES
+    return bv
 
 
 def _resolve_blocks(hidden, Vp, *, transpose_w, softcap, norm, block_n,
@@ -946,11 +1058,14 @@ def _resolve_blocks(hidden, Vp, *, transpose_w, softcap, norm, block_n,
     else:
         bn, _ = _pick_block(N, block_n or DEFAULT_BN, 8)
         bv = _pick_bv(Vp, block_v or DEFAULT_BV, interpret)
-    n_r, n_v = n_pad // bn, Vp // bv
+    n_r, n_v = n_pad // bn, pl.cdiv(Vp, bv)
     if schedule is None:
         schedule = "fused" if (n_r == 1 or n_v == 1) else "split"
     if schedule == "fused" and not (n_r == 1 or n_v == 1):
         schedule = "split"
+    # the tiling of this call shape, and each of its kernels' grid steps
+    KERNEL_CALLS[f"ce_tiling {n_pad}x{D}x{Vp} bn{bn} bv{bv} {schedule} "
+                 f"steps{n_r * n_v}"] += 1
     return bn, bv, schedule
 
 

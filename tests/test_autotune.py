@@ -29,9 +29,10 @@ from repro.kernels import autotune
 from repro.kernels.autotune import (TunedCE, cache_key, candidate_blocks,
                                     clear_memory_cache, get_tuned,
                                     predict_seconds, residency_cap)
-from repro.kernels.fused_ce import (_pick_bv, fused_lm_loss,
-                                    fused_lm_loss_jvp, kernel_calls,
-                                    reset_kernel_calls)
+from repro.kernels.fused_ce import (VMEM_LIMIT_BYTES, _pick_bv,
+                                    fused_lm_loss, fused_lm_loss_jvp,
+                                    kernel_calls, reset_kernel_calls,
+                                    vmem_bytes)
 from repro.kernels.ref import lm_loss_grads_ref, lm_loss_ref
 
 N, D, VOCAB, VP = 64, 32, 200, 256
@@ -132,10 +133,11 @@ def test_candidates_respect_residency_cap():
         assert cands
         cap = residency_cap(1024, 32768)
         for bn, bv, schedule in cands:
-            assert 1024 % bn == 0 and 32768 % bv == 0
+            # a chunk is whole 128-lane groups; the last may be ragged
+            assert 1024 % bn == 0 and bv % 128 == 0 and bv <= 32768
             assert bn * bv <= cap
             if schedule == "fused":
-                assert 1024 // bn == 1 or 32768 // bv == 1
+                assert 1024 // bn == 1 or -(-32768 // bv) == 1
 
 
 def test_predict_prefers_fewer_cells_in_interpret():
@@ -156,6 +158,25 @@ def test_pick_bv_interpret_clamp():
     assert _pick_bv(1024, 128, interpret=True) == 128
     # and never clamps for a real backend
     assert _pick_bv(32768, 128, interpret=False) == 128
+
+
+def test_counters_report_grid_steps_of_tiling_used():
+    """The trace-time counters give each CE kernel call's grid steps
+    (``ce_grid_steps``, summed), the calls whose last chunk is ragged, and
+    the call shape's tiling with its steps per kernel call."""
+    h, w, labels = _data(vp=384)
+    for bv, n_v, ragged in ((256, 2, 3), (128, 3, 0)):
+        reset_kernel_calls()
+        jax.grad(lambda h_: fused_lm_loss(
+            h_, w, labels, vocab_size=VOCAB, block_n=16, block_v=bv,
+            interpret=True)[0])(h)
+        calls = kernel_calls()
+        n_r = N // 16
+        # forward, d_hidden and d_W sweeps, each n_r x n_v steps
+        assert calls["ce_grid_steps"] == 3 * n_r * n_v, calls
+        assert calls.get("ce_ragged_calls", 0) == ragged, calls
+        key = f"ce_tiling {N}x{D}x384 bn16 bv{bv} split steps{n_r * n_v}"
+        assert calls[key] == 1, calls
 
 
 def test_autotuned_defaults_keep_interpret_grid_small():
@@ -379,6 +400,69 @@ def test_attn_q_blocks_slice_lse_lanes(S):
     assert cands
     assert all(bq % 128 == 0 if S % 128 == 0 else bq == S
                for bq, _, _ in cands)
+
+
+#: the benchmark cells' fused-CE calls (N, D, Vp): gpt2-small's batch and
+#: its refresh sub-batch, gpt2-medium's batch and its refresh sub-batch
+CELL_CE = {"gpt2s": (16384, 768, 50304), "gpt2s_refresh": (8192, 768, 50304),
+           "gpt2m": (8192, 1024, 50304),
+           "gpt2m_refresh": (4096, 1024, 50304)}
+
+
+def _ce_tuned(N, D, Vp):
+    return get_tuned(N, D, Vp, dtype="bfloat16", transpose_w=False,
+                     softcap=None, norm="ln", interpret=False)
+
+
+@pytest.mark.parametrize("shape", CELL_CE.values(), ids=CELL_CE.keys())
+def test_ce_picks_wide_chunks_at_cell_shapes(monkeypatch, shape):
+    """With v5e peaks the CE tuner charges each grid step
+    (``ce_step_seconds``), so chunk widths no longer tie: it picks chunks
+    of 1024 columns or more (ragged, since no such width divides 50304 =
+    3 x 131 x 128) where the roofline alone made 128 the pick, within the
+    VMEM the kernels request and the logits-tile cap.  The fwd/dh/dw calls
+    take at most a tenth of the 128-wide grid's steps."""
+    _rank_as_v5e(monkeypatch)
+    N, D, Vp = shape
+    t = _ce_tuned(N, D, Vp)
+    assert t.bv >= 1024 and t.bv % 128 == 0, t
+    assert vmem_bytes(t.bn, t.bv, D, bytes_h=2) <= VMEM_LIMIT_BYTES
+    assert t.bn * t.bv <= autotune.CE_TILE_ELEMS, t
+    steps = (N // t.bn) * -(-Vp // t.bv)
+    assert steps * 10 <= (N // 512) * (Vp // 128), (t, steps)
+
+
+def test_ce_ranking_charges_grid_steps(monkeypatch):
+    """Two tilings that sweep the same columns with the same row tiles,
+    compute-bound on every pass: the one with twice the chunks is priced
+    higher by exactly its extra grid steps times the cost of a step
+    (``ce_step_seconds``: ``CE_STEP_S`` and the hidden tile's norm)."""
+    _rank_as_v5e(monkeypatch)
+    N, D, Vp = 16384, 768, 4096
+    kw = dict(bytes_h=2, bytes_w=4, interpret=False)
+    few = predict_seconds(N, D, Vp, 1024, 2048, "split", **kw)
+    many = predict_seconds(N, D, Vp, 1024, 1024, "split", **kw)
+    extra = 3 * (N // 1024) * (4 - 2)          # fwd, dh, dw
+    np.testing.assert_allclose(many - few,
+                               extra * autotune.ce_step_seconds(1024, D),
+                               rtol=1e-9)
+
+
+@pytest.mark.parametrize("shape", [(16384, 768), (8192, 768)],
+                         ids=["gpt2s", "gpt2s_refresh"])
+def test_ce_pick_divides_vocab_with_good_divisors(monkeypatch, shape):
+    """A ragged last chunk is charged its full width: at Vp 32768 (= 2^15)
+    the pick divides Vp (2048 columns, the widest chunk the VMEM and the
+    logits-tile cap allow at d 768); at Vp 32000 a ragged chunk costs more than a
+    dividing one of the same chunk count (3328 against 3200 columns, ten
+    chunks each); at 50304 the pick runs past Vp."""
+    _rank_as_v5e(monkeypatch)
+    t = _ce_tuned(*shape, 32768)
+    assert 32768 % t.bv == 0 and t.bv >= 1024, t
+    kw = dict(bytes_h=2, bytes_w=4, interpret=False)
+    assert (predict_seconds(*shape, 32000, 256, 3328, "split", **kw)
+            > predict_seconds(*shape, 32000, 256, 3200, "split", **kw))
+    assert 50304 % _ce_tuned(*shape, 50304).bv
 
 
 def test_real_backend_ranking_needs_known_chip():

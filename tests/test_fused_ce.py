@@ -445,3 +445,136 @@ def test_per_device_kernels_match_unsharded():
     r = subprocess.run([sys.executable, "-c", prog], capture_output=True,
                        text=True, timeout=600, env=env)
     assert "PER_DEVICE_OK" in r.stdout, (r.stdout[-800:], r.stderr[-3000:])
+
+
+# ---------------------------------------------------------------------------
+# ragged vocabulary chunks: block_v need not divide the padded vocab; the
+# last chunk runs past Vp and its tail is masked inside the kernels
+
+RAGGED_VP = 384           # three 128-lane groups: block_v 256 leaves 128
+#                           columns of the second chunk past the array
+
+
+def _norm_ref(norm, h, scale, bias):
+    from repro.models.layers import layer_norm, rms_norm
+    if norm == "ln":
+        return layer_norm(h, scale, bias, 1e-6)
+    if norm == "rms":
+        return rms_norm(h, scale, 1e-6)
+    return h
+
+
+@pytest.mark.parametrize("vocab", [RAGGED_VP, 300], ids=["full", "padded"])
+@pytest.mark.parametrize("norm", [None, "ln", "rms"])
+@pytest.mark.parametrize("tied", [True, False])
+def test_ragged_chunks_match_dividing_chunks_and_oracle(tied, norm, vocab):
+    """block_v 256 at Vp 384 (a ragged last chunk, whose tail interpret
+    mode reads as NaN) == block_v 128 (chunks that divide Vp) == the
+    kernels/ref.py oracles, for the loss and the gradients of hidden, W and
+    the fused norm's scale and bias; W's columns past the vocabulary get
+    exactly zero gradient."""
+    hidden, w, labels, mask = _setup(jnp.float32, tied, V=vocab,
+                                     Vp=RAGGED_VP)
+    tw = not tied
+    D = hidden.shape[-1]
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    scale = jax.random.normal(ks[0], (D,), jnp.float32) * 0.1
+    bias = jax.random.normal(ks[1], (D,), jnp.float32) * 0.1
+
+    def fused(bv):
+        def loss(h, w_, sc, bi):
+            return fused_lm_loss(h, w_, labels, mask, vocab_size=vocab,
+                                 transpose_w=tw, block_n=16, block_v=bv,
+                                 norm_kind=norm, norm_scale=sc,
+                                 norm_bias=bi if norm == "ln" else None)[0]
+        return jax.value_and_grad(loss, argnums=(0, 1, 2, 3))(
+            hidden, w, scale, bias)
+
+    (lr_, gr), (ld, gd) = fused(256), fused(128)
+    np.testing.assert_allclose(float(lr_), float(ld), atol=TOL)
+    for x, y, name in zip(gr, gd, ("dh", "dw", "dscale", "dbias")):
+        assert np.all(np.isfinite(np.asarray(x))), name
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=TOL,
+                                   err_msg=name)
+    dw = gr[1]
+    np.testing.assert_array_equal(
+        np.asarray(dw[:, vocab:] if tw else dw[vocab:]), 0.0)
+
+    if norm is None:
+        loss_r, dh_r, dw_r = lm_loss_grads_ref(
+            hidden, w, labels, mask, vocab_size=vocab, transpose_w=tw)
+        np.testing.assert_allclose(float(lr_), float(loss_r), atol=TOL)
+        np.testing.assert_allclose(np.asarray(gr[0]), np.asarray(dh_r),
+                                   atol=TOL)
+        np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_r),
+                                   atol=TOL)
+        return
+
+    def oracle(h, w_, sc, bi):
+        return lm_loss_ref(_norm_ref(norm, h, sc, bi), w_, labels, mask,
+                           vocab_size=vocab, transpose_w=tw)
+
+    loss_r, g_r = jax.value_and_grad(oracle, argnums=(0, 1, 2, 3))(
+        hidden, w, scale, bias)
+    np.testing.assert_allclose(float(lr_), float(loss_r), atol=TOL)
+    for x, y, name in zip(gr, g_r, ("dh", "dw", "dscale", "dbias")):
+        if norm == "rms" and name == "dbias":
+            continue  # rms has no bias; the fused arg gets zero cotangent
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5,
+                                   err_msg=name)
+
+
+@pytest.mark.parametrize("tied", [True, False])
+def test_ragged_chunks_sample_as_dividing_chunks(tied):
+    """The in-sweep Gumbel draw is keyed on global columns: a ragged last
+    chunk draws bit-identical labels to chunks that divide Vp, never a
+    column past the vocabulary, and the sampled loss and its gradients
+    match the oracle."""
+    vocab = 300
+    hidden, w, _, mask = _setup(jnp.float32, tied, V=vocab, Vp=RAGGED_VP)
+    tw = not tied
+    rng = jax.random.PRNGKey(11)
+    yh = {bv: fused_lm_sample(hidden, w, rng, vocab_size=vocab,
+                              transpose_w=tw, block_n=16, block_v=bv)
+          for bv in (128, 256, RAGGED_VP)}
+    np.testing.assert_array_equal(np.asarray(yh[256]), np.asarray(yh[128]))
+    np.testing.assert_array_equal(np.asarray(yh[256]),
+                                  np.asarray(yh[RAGGED_VP]))
+    assert int(jnp.max(yh[256])) < vocab
+
+    def f(h, w_):
+        return fused_lm_loss_sampled(h, w_, rng, mask, vocab_size=vocab,
+                                     transpose_w=tw, block_n=16,
+                                     block_v=256)[0]
+
+    loss, (dh, dw) = jax.value_and_grad(f, argnums=(0, 1))(hidden, w)
+    loss_r, yhat_r, dh_r, dw_r = lm_loss_sampled_ref(
+        hidden, w, rng, mask, vocab_size=vocab, transpose_w=tw)
+    np.testing.assert_array_equal(
+        np.asarray(yh[256]).reshape(-1), np.asarray(yhat_r).reshape(-1))
+    np.testing.assert_allclose(float(loss), float(loss_r), atol=TOL)
+    np.testing.assert_allclose(np.asarray(dh), np.asarray(dh_r), atol=TOL)
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_r), atol=TOL)
+
+
+@pytest.mark.parametrize("norm", [None, "ln"])
+def test_ragged_backward_keeps_normed_cotangent_fp32(norm):
+    """With a fused norm the backward's d(normed hidden) stays fp32 at a
+    ragged tiling (it is pulled back through the norm afterwards); without
+    one it comes back in the hidden dtype."""
+    from repro.kernels.fused_ce import _ce_backward, _ce_forward
+    hidden, w, labels, _ = _setup(jnp.bfloat16, True, V=RAGGED_VP,
+                                  Vp=RAGGED_VP)
+    h2 = hidden.reshape(-1, hidden.shape[-1])
+    lab = labels.reshape(-1)
+    normp = jnp.zeros((2, h2.shape[1]), jnp.float32)
+    kw = dict(vocab=RAGGED_VP, transpose_w=False, softcap=None, norm=norm,
+              eps=1e-6, bn=16, bv=256, interpret=True)
+    lse, _ = _ce_forward(h2, w, normp, lab, **kw)
+    rs = jnp.full(lab.shape, 1.0 / lab.size, jnp.float32)
+    dhn, dw = _ce_backward(h2, w, normp, lab, rs, lse, schedule="split",
+                           **kw)
+    assert dhn.dtype == (jnp.float32 if norm else jnp.bfloat16)
+    assert dw.dtype == w.dtype and dw.shape == w.shape
+    assert np.all(np.isfinite(np.asarray(dhn, np.float32)))
+    assert np.all(np.isfinite(np.asarray(dw)))

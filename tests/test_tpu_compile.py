@@ -223,6 +223,28 @@ def test_fused_ce_backward(chip):
     assert any(v for k, v in calls.items() if k.startswith("bwd_"))
 
 
+@pytest.mark.parametrize("tied", [True, False], ids=["tied", "untied"])
+def test_fused_ce_ragged_chunks(chip, tied):
+    """Chunks of 1024 columns over gpt2-small's Vp 50304: the last W block
+    runs past the array (rows when tied, lanes when untied) and the kernels
+    mask it; Mosaic takes the partial block for the forward, the sampling
+    forward and both backward sweeps, within the VMEM the kernels request."""
+    w = _spec((VP, D) if tied else (D, VP), jnp.float32, chip)
+    h = _spec((2, S, D), jnp.bfloat16, chip)
+    kw = dict(vocab_size=CFG.vocab_size, transpose_w=not tied, block_n=512,
+              block_v=1024, interpret=False)
+
+    def loss(h, w, lab, key):
+        return (fused_ce.fused_lm_loss(h, w, lab, **kw)[0]
+                + fused_ce.fused_lm_loss_sampled(h, w, key, **kw)[0])
+    fused_ce.reset_kernel_calls()
+    _compile(jax.grad(loss, argnums=(0, 1)), h, w,
+             _spec((2, S), jnp.int32, chip), _spec((2,), jnp.uint32, chip))
+    calls = fused_ce.kernel_calls()
+    assert VP % 1024 and calls["ce_ragged_calls"] == 6, calls
+    assert calls["bwd_split"] == 2 and calls["fwd_sample"] == 1, calls
+
+
 @pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
 def test_decode_attention(chip, kv_dtype):
     N, C = 4, 512
